@@ -25,6 +25,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from livekit_server_tpu.ops import scanops
+
 WINDOW = 8  # estimate samples per trend window (trenddetector RequiredSamples)
 
 
@@ -190,7 +192,7 @@ def _scatter_ring(ring: jax.Array, pos: jax.Array, value: jax.Array) -> jax.Arra
 class DelayBWEParams(NamedTuple):
     overuse_ms: float = 1.5        # EMA'd delay-variation above ⇒ overuse
     underuse_ms: float = -1.5      # below ⇒ draining; hold rate
-    ema_alpha: float = 0.3
+    ema_shift: int = 2             # EMA weight 2**-2 (scanops.ema_dyadic)
     beta: float = 0.85             # overuse: rate = beta × acked receive rate
     increase_per_s: float = 0.08   # multiplicative increase while clear
     min_rate_bps: float = 64_000.0
@@ -236,7 +238,7 @@ def delay_update_tick(
     """
     ema = jnp.where(
         fb_valid,
-        (1.0 - params.ema_alpha) * state.slope_ema + params.ema_alpha * fb_delay_ms,
+        scanops.ema_dyadic(state.slope_ema, fb_delay_ms, params.ema_shift),
         state.slope_ema,
     )
     overuse = ema > params.overuse_ms

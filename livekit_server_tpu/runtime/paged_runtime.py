@@ -59,6 +59,7 @@ from livekit_server_tpu.runtime.pager import RoomPager
 from livekit_server_tpu.runtime.plane_runtime import (
     PlaneRuntime,
     _build_ctrl_delta,
+    _build_row_write,
 )
 from livekit_server_tpu.runtime.slots import PagedSlotAllocator
 
@@ -271,6 +272,7 @@ class PagedPlaneRuntime(PlaneRuntime):
     def _init_step(self) -> None:
         self._paged_step = _build_paged_step(self._ap, self._bp, self.red_enabled)
         self._apply_delta = _build_ctrl_delta()
+        self._row_write = _build_row_write()
         self._table_delta = _build_table_delta()
         self._reinit = _build_reinit()
         self._move = _build_moves()
@@ -597,8 +599,6 @@ class PagedPlaneRuntime(PlaneRuntime):
         grid (re-establishing the duplicate-everywhere invariant). Page
         ids are fetched fresh under the lock after a page-lane flush —
         never held across an await (GC08)."""
-        import jax.numpy as jnp
-
         self._sync_pages()
         pages = self.pager.pages_of_room(row)
         if len(pages) == 0:
@@ -625,11 +625,12 @@ class PagedPlaneRuntime(PlaneRuntime):
                 )[tps, :, sps]
             return v.reshape((len(pages),) + pooled_leaf.shape[1:])
 
-        rows_tree = jax.tree.map(rowfun, kinds, row_tree, self.state)
-        pj = jnp.asarray(pages)
-        self.state = jax.tree.map(
-            lambda leaf, rws: leaf.at[pj].set(jnp.asarray(rws, leaf.dtype)),
-            self.state, rows_tree,
+        rows_tree = jax.tree.map(
+            lambda kind, lrow, leaf: rowfun(kind, lrow, leaf).astype(leaf.dtype),
+            kinds, row_tree, self.state,
+        )
+        self.state = self._row_write(
+            self.state, np.asarray(pages, np.int32), rows_tree
         )
 
     def snapshot(self) -> dict[str, Any]:

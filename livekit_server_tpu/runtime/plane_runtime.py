@@ -265,6 +265,21 @@ def _build_ctrl_delta(sharding=None):
     )
 
 
+def _set_rows(state, rows, row_tree):
+    return jax.tree.map(lambda leaf, r: leaf.at[rows].set(r), state, row_tree)
+
+
+@functools.lru_cache(maxsize=None)
+def _build_row_write(sharding=None):
+    """Whole-tree row scatter (restore_room / repair_room_row and the
+    paged page-row write): one program instead of an eager scatter per
+    leaf, so the server's warm-up can compile it and a migration's first
+    adoption stays inside its ACK timeout. jit turns host leaves into
+    device arrays, whatever form the state or the snapshot arrives in."""
+    kw = {} if sharding is None else {"out_shardings": sharding}
+    return jax.jit(_set_rows, donate_argnums=(0,), **kw)
+
+
 @dataclass
 class StagedTick:
     """One tick's host-staged inputs, carried through the three-stage
@@ -505,12 +520,14 @@ class PlaneRuntime:
                 red_enabled=self.red_enabled,
             )
             self._apply_delta = _build_ctrl_delta(room_sharding(self._mesh))
+            self._row_write = _build_row_write(room_sharding(self._mesh))
         else:
             # Shared across PlaneRuntime instances with identical params so
             # repeated construction (tests, restarts) reuses the XLA
             # compilation cache instead of re-tracing a fresh closure.
             self._step = _build_step(self._ap, self._bp, self.red_enabled)
             self._apply_delta = _build_ctrl_delta()
+            self._row_write = _build_row_write()
 
     def _pack_inputs(self, inp: plane.TickInputs) -> tuple:
         """Logical TickInputs → the device-step upload arrays."""
@@ -1517,6 +1534,18 @@ class PlaneRuntime:
             + [np.asarray(m[row]) for m in snap.get("munger", [])]
         }
 
+    def _write_row(self, row: int, flat: list, treedef, dev_arrays: list) -> None:
+        row_tree = jax.tree.unflatten(treedef, [
+            np.asarray(a, leaf.dtype) for leaf, a in zip(flat, dev_arrays)
+        ])
+        self.state = self._row_write(self.state, np.int32(row), row_tree)
+
+    def warm_row_write(self) -> None:
+        """Compile the row scatter inside the warm-up window by writing
+        row 0 back onto itself. Callers hold state_lock (GC01)."""
+        flat, treedef = jax.tree.flatten(self.state)
+        self._write_row(0, flat, treedef, [np.asarray(x[0]) for x in flat])
+
     def repair_room_row(self, row: int, snap: dict[str, Any]) -> None:
         """Integrity row repair: overwrite ONE corrupt room row from a
         verified checkpoint, in place, without disturbing any other row.
@@ -1527,21 +1556,11 @@ class PlaneRuntime:
         and the dirty-row upload re-asserts them over the checkpoint's
         older device copy at the next tick edge. Callers hold state_lock
         (GC01)."""
-        import jax.numpy as jnp
-
         flat, treedef = jax.tree.flatten(self.state)
         self._check_row_leaves(flat, snap["arrays"])
         dev_arrays = snap["arrays"][: len(flat)]
         self.munger.restore_room(row, snap["arrays"][len(flat):])
-        new_flat = [
-            leaf.at[row].set(jnp.asarray(a, leaf.dtype))
-            for leaf, a in zip(flat, dev_arrays)
-        ]
-        self.state = jax.tree.unflatten(treedef, new_flat)
-        if self._mesh is not None:
-            from livekit_server_tpu.parallel import shard_tree
-
-            self.state = shard_tree(self.state, self._mesh)
+        self._write_row(row, flat, treedef, dev_arrays)
         # The replay ring references pre-repair munger SN spaces; replaying
         # across the rewind would emit wrong-SN bytes. Clients re-NACK.
         self.host_seq.clear_room(row)
@@ -1561,8 +1580,6 @@ class PlaneRuntime:
         bit on a column later given to a different participant would leak
         media to someone who never subscribed. Rejoining subscribers
         re-subscribe; their (track, sub) munger lanes resume intact."""
-        import jax.numpy as jnp
-
         # The destination row's replay ring starts empty (see docstring) —
         # and must not retain entries from whatever used the row before.
         self.host_seq.clear_room(row)
@@ -1570,15 +1587,7 @@ class PlaneRuntime:
         self._check_row_leaves(flat, snap["arrays"])
         dev_arrays = snap["arrays"][: len(flat)]
         self.munger.restore_room(row, snap["arrays"][len(flat):])
-        new_flat = [
-            leaf.at[row].set(jnp.asarray(a, leaf.dtype))
-            for leaf, a in zip(flat, dev_arrays)
-        ]
-        self.state = jax.tree.unflatten(treedef, new_flat)
-        if self._mesh is not None:
-            from livekit_server_tpu.parallel import shard_tree
-
-            self.state = shard_tree(self.state, self._mesh)
+        self._write_row(row, flat, treedef, dev_arrays)
         # Mirror the migrated row's track metadata back to the host copies
         # (other rows' possibly-dirty host state stays untouched)…
         snap_tree = jax.tree.unflatten(treedef, dev_arrays)
