@@ -21,6 +21,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from livekit_server_tpu.ops.backend import want_pallas
+
 MAX_SPATIAL = 4
 MAX_TEMPORAL = 4
 NUM_LAYERS = MAX_SPATIAL * MAX_TEMPORAL  # 16 flat layers
@@ -230,8 +232,9 @@ def allocate_budget_rooms(bitrates, max_spatial, max_temporal, muted, budget,
     Returns (target [R, S, T] int32, used [R, S] float32,
     deficient [R, S, T] bool).
     """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+    use_pallas = want_pallas(
+        use_pallas, interpret, "allocation.allocate_budget_rooms"
+    )
     if not (use_pallas or interpret):
         return jax.vmap(allocate_budget_batch)(
             bitrates, max_spatial, max_temporal, muted, budget

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 
 from livekit_server_tpu.analysis.registry import device_entry
 from livekit_server_tpu.ops import selector
+from livekit_server_tpu.ops.backend import want_pallas
 
 NUM_LAYERS = 3   # spatial routing lanes (models/plane.py MAX_LAYERS)
 
@@ -73,10 +74,6 @@ class LiveDecide(NamedTuple):
     tr: Any                  # [NL, 3, TP*L] int32 | None
 
 
-def _resolve_pallas(use_pallas: bool | None) -> bool:
-    if use_pallas is None:
-        return jax.default_backend() == "tpu"
-    return use_pallas
 
 
 def _page_kernel(*refs, TP: int, K: int, SP: int, L: int,
@@ -110,8 +107,8 @@ def _page_kernel(*refs, TP: int, K: int, SP: int, L: int,
         # algebra with the room-block lane axis replaced by this page's
         # [TP, SP] plane (int domain throughout — Mosaic cannot lower i1
         # vector truncations).
-        is_svc = svc_ref[0][:, None] != 0                       # [TP, 1]
-        is_vid = vid_ref[0][:, None] != 0                       # [TP, 1]
+        is_svc = svc_ref[0, 0][:, None] != 0                    # [TP, 1]
+        is_vid = vid_ref[0, 0][:, None] != 0                    # [TP, 1]
         base = base_ref[0] != 0                                 # [TP, SP]
         tgt_sp = tgt_sp_ref[0]                                  # [TP, SP]
         tgt_tp = tgt_tp_ref[0]
@@ -199,10 +196,10 @@ def _page_kernel(*refs, TP: int, K: int, SP: int, L: int,
         nkf = jnp.where(is_svc, jnp.where(nkf_svc, 1, 0),
                         jnp.where(nkf_sim, 1, 0))
         nkf_ref[0] = nkf * jnp.where(base & is_vid, 1, 0)
-        pkts_ref[0] = pkts_acc
-        bytes_ref[0] = bytes_acc
-        fp_ref[0, 0] = fp_acc
-        fb_ref[0, 0] = fb_acc
+        pkts_ref[0, 0] = pkts_acc
+        bytes_ref[0, 0] = bytes_acc
+        fp_ref[0] = jnp.full((1, 1), fp_acc, jnp.int32)
+        fb_ref[0] = jnp.full((1, 1), fb_acc, jnp.int32)
 
         # ---- stats/tracker routing (models/plane.py `_room_tick`
         # sections 1–2, verbatim int algebra at page shapes) -------------
@@ -219,7 +216,7 @@ def _page_kernel(*refs, TP: int, K: int, SP: int, L: int,
         st_routed = jnp.where(
             (eff_layer[:, :, None] == lanes)[None], st_vals[:, :, :, None], 0
         )                                                       # [5,TP,K,L]
-        st_ref[0] = st_routed.transpose(0, 1, 3, 2).reshape(5, TP * L, K)
+        st_ref[0] = st_routed
         true_layer = jnp.clip(layer, 0, L - 1)
         t_lane = true_layer[:, :, None] == lanes                # [TP,K,L]
         ones_k = jnp.ones((TP, K), jnp.int32)
@@ -231,7 +228,7 @@ def _page_kernel(*refs, TP: int, K: int, SP: int, L: int,
             t_lane[None] & (tr_pred[:, :, :, None] != 0),
             tr_vals[:, :, :, None], 0,
         )                                                       # [3,TP,K,L]
-        tr_ref[0] = jnp.sum(routed, axis=2).reshape(3, TP * L)
+        tr_ref[0] = jnp.sum(routed, axis=2)                     # [3,TP,L]
 
     if with_mix:
         # ---- page-local active-speaker mix (ops/mix.py mix_tick math;
@@ -239,8 +236,8 @@ def _page_kernel(*refs, TP: int, K: int, SP: int, L: int,
         # top-K threshold is the multiset k-th largest via pairwise
         # compares (no sort in-kernel): min{v : #{v' > v} < k}, which
         # equals sort(lv)[TP - k] including tie semantics.
-        level = level_ref[0]                                    # [TP] f32
-        act = active_ref[0] != 0                                # [TP]
+        level = level_ref[0, 0]                                 # [TP] f32
+        act = active_ref[0, 0] != 0                             # [TP]
         lv = jnp.where(act, level, -1.0)
         k_eff = min(top_k, TP)
         cnt_gt = jnp.sum(
@@ -248,11 +245,11 @@ def _page_kernel(*refs, TP: int, K: int, SP: int, L: int,
         )                                                       # [TP]
         thr = jnp.min(jnp.where(cnt_gt < k_eff, lv, jnp.inf))
         speak = act & (lv >= jnp.maximum(thr, 0.0))             # [TP]
-        sub_tr = subtrack_ref[0]                                # [SP]
+        sub_tr = subtrack_ref[0, 0]                             # [SP]
         w = speak[None, :] & (
             jnp.arange(TP, dtype=jnp.int32)[None, :] != sub_tr[:, None]
         )                                                       # [SP, TP]
-        weights = w.astype(jnp.float32) * gain_ref[0][None, :]
+        weights = w.astype(jnp.float32) * gain_ref[0, 0][None, :]
         mixed_ref[0] = jnp.dot(weights, pcm_ref[0])             # [SP, N]
 
 
@@ -277,7 +274,10 @@ def _pallas_live_call(live_rows, decide_ops, mix_ops, *, TP, K, SP, N, L,
     vm = pltpu.VMEM
     st3 = pl.BlockSpec((1, TP, SP), lambda i, lr: (live(i, lr), 0, 0),
                        memory_space=vm)
-    t2 = pl.BlockSpec((1, TP), lambda i, lr: (live(i, lr), 0),
+    # Per-page vectors carry a unit axis ([P, 1, x] blocked (1, 1, x)): the
+    # last two block dims must equal the array's, and P stays leading so
+    # `page_sharding` still holds.
+    t2 = pl.BlockSpec((1, 1, TP), lambda i, lr: (live(i, lr), 0, 0),
                       memory_space=vm)
     pk = pl.BlockSpec((1, TP, K), lambda i, lr: (live(i, lr), 0, 0),
                       memory_space=vm)
@@ -285,23 +285,27 @@ def _pallas_live_call(live_rows, decide_ops, mix_ops, *, TP, K, SP, N, L,
     inputs: list = []
     if with_decide:
         in_specs += [st3] * 4 + [t2] * 2 + [st3] + [pk] * 11
-        inputs += list(decide_ops)
+        d = list(decide_ops)
+        inputs += d[:4] + [x[:, None, :] for x in d[4:6]] + d[6:]
     if with_mix:
         pcm_spec = pl.BlockSpec((1, TP, N), lambda i, lr: (live(i, lr), 0, 0),
                                 memory_space=vm)
-        s2 = pl.BlockSpec((1, SP), lambda i, lr: (live(i, lr), 0),
+        s2 = pl.BlockSpec((1, 1, SP), lambda i, lr: (live(i, lr), 0, 0),
                           memory_space=vm)
         in_specs += [pcm_spec, t2, t2, t2, s2]
-        inputs += list(mix_ops)
+        inputs += [mix_ops[0]] + [x[:, None, :] for x in mix_ops[1:]]
 
     # Compact outputs: block i of the [NL]-leading result arrays.
     c3 = pl.BlockSpec((1, TP, SP), lambda i, lr: (i, 0, 0), memory_space=vm)
     cw = pl.BlockSpec((1, TP, K), lambda i, lr: (i, 0, 0), memory_space=vm)
-    cs = pl.BlockSpec((1, SP), lambda i, lr: (i, 0), memory_space=vm)
-    ct = pl.BlockSpec((1, 1), lambda i, lr: (i, 0), memory_space=vm)
-    cst = pl.BlockSpec((1, 5, TP * L, K), lambda i, lr: (i, 0, 0, 0),
+    cs = pl.BlockSpec((1, 1, SP), lambda i, lr: (i, 0, 0), memory_space=vm)
+    ct = pl.BlockSpec((1, 1, 1), lambda i, lr: (i, 0, 0), memory_space=vm)
+    # Routed stats leave the kernel as computed ([5,TP,K,L] / [3,TP,L]);
+    # the [.., TP*L, ..] row form the core wants is a reshape of the
+    # result outside (Mosaic has no such shape cast in-kernel).
+    cst = pl.BlockSpec((1, 5, TP, K, L), lambda i, lr: (i, 0, 0, 0, 0),
                        memory_space=vm)
-    ctr = pl.BlockSpec((1, 3, TP * L), lambda i, lr: (i, 0, 0),
+    ctr = pl.BlockSpec((1, 3, TP, L), lambda i, lr: (i, 0, 0, 0),
                        memory_space=vm)
     out_specs: list = []
     out_shape: list = []
@@ -315,12 +319,12 @@ def _pallas_live_call(live_rows, decide_ops, mix_ops, *, TP, K, SP, N, L,
             jax.ShapeDtypeStruct((NL, TP, SP), i32),     # out_sp
             jax.ShapeDtypeStruct((NL, TP, SP), i32),     # out_tp
             jax.ShapeDtypeStruct((NL, TP, SP), i32),     # need_kf
-            jax.ShapeDtypeStruct((NL, SP), i32),         # pkts_sent
-            jax.ShapeDtypeStruct((NL, SP), i32),         # sent_bytes
-            jax.ShapeDtypeStruct((NL, 1), i32),          # fwd_packets
-            jax.ShapeDtypeStruct((NL, 1), i32),          # fwd_bytes
-            jax.ShapeDtypeStruct((NL, 5, TP * L, K), i32),
-            jax.ShapeDtypeStruct((NL, 3, TP * L), i32),
+            jax.ShapeDtypeStruct((NL, 1, SP), i32),      # pkts_sent
+            jax.ShapeDtypeStruct((NL, 1, SP), i32),      # sent_bytes
+            jax.ShapeDtypeStruct((NL, 1, 1), i32),       # fwd_packets
+            jax.ShapeDtypeStruct((NL, 1, 1), i32),       # fwd_bytes
+            jax.ShapeDtypeStruct((NL, 5, TP, K, L), i32),
+            jax.ShapeDtypeStruct((NL, 3, TP, L), i32),
         ]
     if with_mix:
         cm = pl.BlockSpec((1, SP, N), lambda i, lr: (i, 0, 0),
@@ -365,6 +369,7 @@ def _decide_inputs(sel_state, is_svc, is_video, base, inp):
 def _decide_from_call(res, sel_state, live_rows):
     (send_w, drop_w, sw_w, out_sp, out_tp, nkf, pkts, byts, fp, fb,
      st, tr) = res[:12]
+    NL, _, TP, K, L = st.shape
     sel_new = selector.SelectorState(
         current_spatial=out_sp,
         current_temporal=out_tp,
@@ -377,9 +382,10 @@ def _decide_from_call(res, sel_state, live_rows):
         drop_bits=drop_w[:, :, :, None],
         switch_bits=sw_w[:, :, :, None],
         need_kf=nkf.astype(bool),
-        pkts_sent=pkts, sent_bytes=byts,
-        fwd_packets=fp[:, 0], fwd_bytes=fb[:, 0],
-        st=st, tr=tr,
+        pkts_sent=pkts[:, 0], sent_bytes=byts[:, 0],
+        fwd_packets=fp[:, 0, 0], fwd_bytes=fb[:, 0, 0],
+        st=st.transpose(0, 1, 2, 4, 3).reshape(NL, 5, TP * L, K),
+        tr=tr.reshape(NL, 3, TP * L),
     )
 
 
@@ -411,7 +417,7 @@ def decide_pages(sel_state, is_svc, is_video, base, inp, live_rows, *,
     ids). Operands stay at POOLED shapes — the kernel's index maps read
     only the live blocks; the fallback gathers them. Returns LiveDecide
     (leading axis NL = live_rows.shape[0])."""
-    if not (_resolve_pallas(use_pallas) or interpret):
+    if not (want_pallas(use_pallas, interpret, "paged_kernel") or interpret):
         return _decide_fallback(sel_state, is_svc, is_video, base, inp,
                                 live_rows, wire_overhead)
     P, TP, SP = base.shape
@@ -432,7 +438,7 @@ def mix_pages(pcm, level, active, sub_track, gain, live_rows, *,
     """Active-speaker mix for the live pages only: [NL, SP, N] PCM.
     Page-local speaker gate — exact vs ops/mix.mix_tick when a room's
     tracks fit one track page (module doc)."""
-    if not (_resolve_pallas(use_pallas) or interpret):
+    if not (want_pallas(use_pallas, interpret, "paged_kernel") or interpret):
         from livekit_server_tpu.ops import mix
 
         def g(a):
@@ -462,7 +468,7 @@ def decide_mix_pages(sel_state, is_svc, is_video, base, inp,
                      interpret: bool = False):
     """Decide AND mix in a single pass per live page — one pallas_call,
     one grid, both output sets. Returns (LiveDecide, mixed [NL, SP, N])."""
-    if not (_resolve_pallas(use_pallas) or interpret):
+    if not (want_pallas(use_pallas, interpret, "paged_kernel") or interpret):
         dec = _decide_fallback(sel_state, is_svc, is_video, base, inp,
                                live_rows, wire_overhead)
         mixed = mix_pages(pcm, level, active, sub_track, gain, live_rows,

@@ -23,6 +23,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from livekit_server_tpu.ops.backend import want_pallas
+
 INVALID_LAYER = -1  # plain int: module import must not init a jax backend
 
 
@@ -355,8 +357,7 @@ def decide_rooms(state: SelectorState, is_svc, is_video, base, pkt_spatial,
     """
     from livekit_server_tpu.ops import bits
 
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+    use_pallas = want_pallas(use_pallas, interpret, "selector.decide_rooms")
     S = state.current_spatial.shape[-1]
     if not (use_pallas or interpret):
         sel_state, v_fwd, v_drop, v_switch, nkf_sel = select_both_rooms(

@@ -237,8 +237,7 @@ class TickResult:
         return self._egress_cache
 
 
-@functools.lru_cache(maxsize=None)
-def _build_step(audio_params, bwe_params, red_enabled=True):
+def _packed_tick(audio_params, bwe_params, red_enabled=True):
     """Packed-wire step: ONE input upload, ONE output fetch per tick
     (plane.pack_tick_inputs / pack_tick_outputs)."""
 
@@ -249,7 +248,15 @@ def _build_step(audio_params, bwe_params, red_enabled=True):
         )
         return state, plane.pack_tick_outputs(out)
 
-    return jax.jit(tick, donate_argnums=(0,))
+    return tick
+
+
+@functools.lru_cache(maxsize=None)
+def _build_step(audio_params, bwe_params, red_enabled=True):
+    return jax.jit(
+        _packed_tick(audio_params, bwe_params, red_enabled),
+        donate_argnums=(0,),
+    )
 
 
 @functools.lru_cache(maxsize=None)
