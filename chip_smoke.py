@@ -1,0 +1,688 @@
+#!/usr/bin/env python3
+"""Chip smoke: the served path, once, on the TPU — through the entry
+points a user calls.
+
+    python chip_smoke.py              # one chip: dense phase, then paged
+    python chip_smoke.py --chips 4    # the room-sharded tick on 4 chips, alone
+    python chip_smoke.py --rehearse   # toy sizes, any backend (tests, CPU)
+
+One process: it imports JAX itself, refuses to start unless the first
+device is a TPU, builds the real server (`create_server`, as `serve`
+does) on loopback ports and plays the clients too — JWT → `/rtc`
+WebSocket join → sealed UDP media — then checks what came out against a
+plain reckoning of what was sent. `--rehearse` changes the sizes and
+drops the platform assertion; the path is the same.
+
+Every phase raises on failure; nothing is caught and carried on. No
+time printed here is a result: this is a smoke, not a load test. The
+last line of stdout is the JSON the driver reads.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import base64
+import json
+import shutil
+import socket
+import sys
+import threading
+import time
+
+API_KEY, API_SECRET = "smokekey", "smokesecret-smokesecret-smokesecret"
+VP8_PT, OPUS_PT = 96, 111
+
+# Dense phase: BASELINE.json cfg4 width. Paged phase: the `serve`
+# defaults with `plane.pager_enabled: true` (page 4×8, pool 1024).
+CFG4 = dict(rooms=1024, tracks_per_room=10, pkts_per_track=8, subs_per_room=10)
+SERVE_DEFAULT = dict(rooms=64, tracks_per_room=16, pkts_per_track=16,
+                     subs_per_room=32)
+TOY = dict(rooms=8, tracks_per_room=4, pkts_per_track=4, subs_per_room=4)
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def free_port(kind=socket.SOCK_STREAM) -> int:
+    s = socket.socket(socket.AF_INET, kind)
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    return port
+
+
+# -- the client side -----------------------------------------------------------
+
+class SignalClient:
+    """One participant's signal connection (JSON over the /rtc WebSocket)."""
+
+    def __init__(self, session, port: int, room: str, identity: str):
+        self.session, self.port = session, port
+        self.room, self.identity = room, identity
+        self.ws = None
+        self.inbox: list[dict] = []
+        self._reader = None
+        self.crypto = None          # MediaCryptoClient after join
+        self.subscribed: list[str] = []
+
+    async def join(self) -> None:
+        from livekit_server_tpu.auth import AccessToken, VideoGrant
+        from livekit_server_tpu.runtime.crypto import MediaCryptoClient
+
+        t = AccessToken(API_KEY, API_SECRET)
+        t.identity = self.identity
+        t.grant = VideoGrant(room_join=True, room=self.room)
+        self.ws = await self.session.ws_connect(
+            f"ws://127.0.0.1:{self.port}/rtc?access_token={t.to_jwt()}"
+        )
+        self._reader = asyncio.ensure_future(self._read())
+        join = await self.take("join")
+        mc = join["media_crypto"]
+        self.crypto = MediaCryptoClient(mc["key_id"], base64.b64decode(mc["key"]))
+
+    async def _read(self) -> None:
+        import aiohttp
+
+        async for msg in self.ws:
+            if msg.type == aiohttp.WSMsgType.TEXT:
+                self.inbox.append(json.loads(msg.data))
+
+    async def take(self, kind: str, key: str | None = None, timeout: float = 20.0):
+        """Pop the first `kind` message (holding `key`, if given)."""
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            for i, m in enumerate(self.inbox):
+                if kind in m and (key is None or key in m[kind]):
+                    return self.inbox.pop(i)[kind]
+            await asyncio.sleep(0.005)
+        raise TimeoutError(f"{self.identity}: no {kind!r}/{key!r} signal")
+
+    async def send(self, kind: str, data: dict) -> None:
+        await self.ws.send_str(json.dumps({kind: data}))
+
+    async def publish(self, cid: str, video: bool) -> dict:
+        await self.send("add_track", {
+            "cid": cid, "type": 1 if video else 0, "name": cid,
+            "transport": "udp",
+        })
+        return (await self.take("request_response", "udp_media"))["udp_media"]
+
+    async def close(self) -> None:
+        if self._reader is not None:
+            self._reader.cancel()
+        if self.ws is not None:
+            await self.ws.close()
+
+
+def rtp_packet(pt: int, sn: int, ts: int, ssrc: int, video: bool) -> bytes:
+    hdr = bytearray(12)
+    hdr[0] = 0x80
+    hdr[1] = 0x80 | pt                       # one packet per frame: marker set
+    hdr[2:4] = (sn & 0xFFFF).to_bytes(2, "big")
+    hdr[4:8] = (ts & 0xFFFFFFFF).to_bytes(4, "big")
+    hdr[8:12] = ssrc.to_bytes(4, "big")
+    if video:
+        # VP8 descriptor (X, I 15-bit pid, L, T), S bit set, and a first
+        # payload byte with P=0: every packet is a whole key frame, so a
+        # subscriber can lock on at any packet.
+        pid = sn & 0x7FFF
+        payload = bytes([0x90, 0xE0, 0x80 | (pid >> 8), pid & 0xFF,
+                         sn & 0xFF, 0x20, 0x00]) + bytes(900)
+    else:
+        payload = bytes(80)                  # a 20 ms Opus frame's worth
+    return bytes(hdr) + payload
+
+
+class MediaDrive:
+    """Publisher and subscriber sockets, each on a thread of its own so the
+    server's event loop is not the clients' clock."""
+
+    def __init__(self, udp_port: int):
+        self.dst = ("127.0.0.1", udp_port)
+        self.pub = self._sock()
+        self.sub = self._sock()
+        self.sub.settimeout(0.05)
+        self.frames: list[tuple] = []        # (key_id, opened frame), as received
+        self.clients: dict[int, object] = {}     # key_id → MediaCryptoClient
+        self.fb_ssrc: dict[int, int] = {}        # key_id → an egress SSRC
+        self._pending: dict[int, list] = {}      # key_id → [(ctr, recv_us)]
+        self._stop = threading.Event()
+        self._rx = threading.Thread(target=self._recv_loop, daemon=True)
+        self.sent = 0
+        self.slipped_ms = 0.0
+
+    @staticmethod
+    def _sock() -> socket.socket:
+        s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+        s.bind(("127.0.0.1", 0))
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 8 << 20)
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 8 << 20)
+        return s
+
+    def start(self) -> None:
+        self._rx.start()
+
+    def _recv_loop(self) -> None:
+        """Drain egress; ack sealed-frame counters as transport-wide
+        feedback every 50 ms, as a real client's congestion control does
+        (without it the server's send-side BWE starves the video)."""
+        from livekit_server_tpu.runtime.udp import build_twcc_feedback
+
+        last_fb = time.monotonic()
+        while not self._stop.is_set():
+            try:
+                f = self.sub.recv(4096)
+            except socket.timeout:
+                f = None
+            if f is not None and len(f) > 14 and f[0] == 0x01:
+                kid = int.from_bytes(f[1:5], "big")
+                # opened here, once: the replay window refuses a second open
+                inner = self.clients[kid].open(f)
+                self.frames.append((kid, inner))
+                self._pending.setdefault(kid, []).append(
+                    (int.from_bytes(f[6:14], "big"), time.monotonic_ns() // 1000)
+                )
+                if (kid not in self.fb_ssrc and inner is not None
+                        and len(inner) >= 12 and not 192 <= inner[1] <= 223):
+                    self.fb_ssrc[kid] = int.from_bytes(inner[8:12], "big")
+            now = time.monotonic()
+            if now - last_fb >= 0.05:
+                last_fb = now
+                for kid, ents in self._pending.items():
+                    if ents and kid in self.fb_ssrc:
+                        fb = build_twcc_feedback(0x42, self.fb_ssrc[kid], ents)
+                        self.sub.sendto(self.clients[kid].seal(fb), self.dst)
+                        ents.clear()
+
+    def send_schedule(self, schedule: list[list[bytes]], tick_s: float) -> None:
+        """Send one list of sealed datagrams per tick, paced on this
+        thread's own clock (blocking — call via asyncio.to_thread)."""
+        t0 = time.monotonic()
+        for i, batch in enumerate(schedule):
+            due = t0 + i * tick_s
+            delay = due - time.monotonic()
+            if delay > 0:
+                time.sleep(delay)
+            else:
+                self.slipped_ms = max(self.slipped_ms, -delay * 1e3)
+            for d in batch:
+                self.pub.sendto(d, self.dst)
+            self.sent += len(batch)
+
+    def stop(self) -> None:
+        self._stop.set()
+        self._rx.join(timeout=5)
+        self.pub.close()
+        self.sub.close()
+
+
+# -- one served phase ----------------------------------------------------------
+
+def make_config(plane: dict, tick_ms: int):
+    from livekit_server_tpu.config import load_config
+
+    return load_config(yaml_text=json.dumps({
+        "keys": {API_KEY: API_SECRET},
+        "port": free_port(),
+        "bind_addresses": ["127.0.0.1"],
+        "plane": dict(plane, tick_ms=tick_ms),
+        "rtc": {
+            "udp_port": free_port(socket.SOCK_DGRAM),
+            "tcp_port": 0,
+            "require_encryption": True,      # AEAD on: the production wire
+        },
+    }))
+
+
+async def http_json(session, port: int, path: str) -> dict:
+    async with session.get(f"http://127.0.0.1:{port}{path}") as r:
+        assert r.status == 200, (path, r.status)
+        return await r.json()
+
+
+async def served_phase(name: str, plane: dict, *, live_rooms: int, lead_ticks: int,
+                       ticks: int, tick_ms: int = 10) -> None:
+    """Start the server as `serve` does, join `live_rooms` rooms of three
+    (a video publisher, an audio publisher, a listener; everyone
+    subscribed to everyone else), drive media over sealed UDP, and check
+    the egress against what was sent."""
+    import aiohttp
+    import jax
+
+    from livekit_server_tpu.runtime.udp import PUNCH_ACK, PUNCH_REQ
+    from livekit_server_tpu.service.server import create_server
+
+    cfg = make_config(plane, tick_ms)
+    say(f"[{name}] plane dims {cfg.plane.rooms}r x {cfg.plane.tracks_per_room}t x "
+        f"{cfg.plane.pkts_per_track}k x {cfg.plane.subs_per_room}s, tick "
+        f"{tick_ms} ms, pager {cfg.plane.pager_enabled}"
+        + (f" (page {cfg.plane.pager_tpage}x{cfg.plane.pager_spage}, kernel "
+           f"{cfg.plane.paged_kernel})" if cfg.plane.pager_enabled else ""))
+    t0 = time.monotonic()
+    server = create_server(cfg)
+    await server.start()            # warm-compiles the tick, then mark_warm()
+    runtime = server.room_manager.runtime
+    warm_s = time.monotonic() - t0
+    say(f"[{name}] warm-up {warm_s:.2f} s "
+        f"({runtime.compile_ledger.snapshot()['xla_compiles_total']} XLA compiles, "
+        f"{runtime.compile_ledger.warmup_ms / 1e3:.2f} s compiling)")
+    if cfg.plane.pager_enabled:
+        say(f"[{name}] runtime {type(runtime).__name__}, ragged kernel "
+            f"{'on' if runtime._pk_enabled else 'off'}")
+
+    drive = MediaDrive(cfg.rtc.udp_port)
+    async with aiohttp.ClientSession() as session:
+        # -- join: JWT → /rtc → tracks → subscriptions → UDP punch ---------
+        rooms = []
+        for r in range(live_rooms):
+            people = [SignalClient(session, cfg.port, f"smoke-{r}", who)
+                      for who in ("cam", "mic", "ear")]
+            for p in people:
+                await p.join()
+                drive.clients[p.crypto.key_id] = p.crypto
+            cam = await people[0].publish("cam", video=True)
+            mic = await people[1].publish("mic", video=False)
+            for p, want in zip(people, ([mic], [cam], [cam, mic])):
+                for _ in want:
+                    p.subscribed.append(
+                        (await p.take("track_subscribed"))["track_sid"])
+                assert sorted(p.subscribed) == sorted(t["track_sid"] for t in want)
+                await p.send("subscription", {
+                    "track_sids": p.subscribed, "subscribe": True, "udp": True})
+                punch = (await p.take("request_response", "udp_punch"))["udp_punch"]
+                drive.sub.sendto(
+                    p.crypto.seal(PUNCH_REQ + int(punch["punch_id"]).to_bytes(4, "big")),
+                    drive.dst)
+            rooms.append((people, cam["ssrc"], mic["ssrc"]))
+        # every punch is acknowledged, sealed, on the subscriber socket
+        acks, deadline = set(), time.monotonic() + 10
+        while len(acks) < 3 * live_rooms and time.monotonic() < deadline:
+            try:
+                f = drive.sub.recv(4096)
+            except socket.timeout:
+                await asyncio.sleep(0.01)
+                continue
+            kid = int.from_bytes(f[1:5], "big")
+            inner = drive.clients[kid].open(f)
+            if inner is not None and inner[:8] == PUNCH_ACK:
+                acks.add(kid)
+        assert len(acks) == 3 * live_rooms, f"{len(acks)} punch acks"
+        say(f"[{name}] joined {live_rooms} rooms x 3 participants over /rtc; "
+            f"{2 * live_rooms} tracks published, {len(acks)} UDP subscribers latched")
+
+        # -- media: sealed ahead of time, sent on the publisher thread -----
+        # video 50 pkt/s of 907 B, audio 50 pkt/s of 80 B per track: a rate
+        # one Python process can seal, send, receive and open beside the
+        # server it is driving.
+        every = max(1, 20 // tick_ms)
+        n_total = lead_ticks + ticks
+        schedule: list[list[bytes]] = [[] for _ in range(n_total)]
+        sent_sns: dict[int, list[int]] = {}      # publisher ssrc → SNs, in order
+        for r, (people, v_ssrc, a_ssrc) in enumerate(rooms):
+            for who, ssrc, video in ((0, v_ssrc, True), (1, a_ssrc, False)):
+                seal = people[who].crypto.seal
+                sns = sent_sns[ssrc] = []
+                for i in range(0, n_total, every):
+                    sn = (1000 * r + 7 + len(sns)) & 0xFFFF
+                    ts = (90 if video else 48) * tick_ms * i
+                    schedule[i].append(seal(rtp_packet(
+                        VP8_PT if video else OPUS_PT, sn, ts, ssrc, video)))
+                    sns.append(sn)
+        per_track_lead = len(range(0, lead_ticks, every))
+        per_track = len(range(0, n_total, every)) - per_track_lead
+        pps_in = 2 * live_rooms * 1000 // (tick_ms * every)
+        say(f"[{name}] offered {pps_in} pkt/s in, {2 * pps_in} pkt/s out expected; "
+            f"lead-in {lead_ticks} ticks, checked window {ticks} ticks")
+
+        before = await http_json(session, cfg.port, "/debug/rooms")
+        drive.start()
+        await asyncio.to_thread(drive.send_schedule, schedule, tick_ms / 1e3)
+        # let the pipeline drain: every packet of the window out, or 5 s
+        want_frames = 2 * 2 * live_rooms * per_track
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline:
+            n0 = len(drive.frames)
+            await asyncio.sleep(0.25)
+            if len(drive.frames) == n0 and n0 >= want_frames:
+                break
+        after = await http_json(session, cfg.port, "/debug/rooms")
+        compiles = await http_json(session, cfg.port, "/debug/compiles")
+        async with session.get(f"http://127.0.0.1:{cfg.port}/metrics") as resp:
+            metrics = await resp.text()
+        for people, _, _ in rooms:
+            for p in people:
+                await p.close()
+    drive.stop()
+
+    pb, pa = before["plane"], after["plane"]
+    d_ticks = pa["ticks"] - pb["ticks"]
+    say(f"[{name}] /debug/rooms: ticks {pb['ticks']} -> {pa['ticks']}, "
+        f"fwd_packets {pa['fwd_packets']}, late_ticks {pa.get('late_ticks', 0)}, "
+        f"ingest_dropped {after['ingest_dropped']}")
+
+    # -- reckoning -------------------------------------------------------------
+    # Group what arrived by (subscriber key, egress SSRC): one munged SN
+    # space each. Padding probes share a stream's SN space and are not media.
+    streams: dict[tuple[int, int], list[tuple[int, int, bool]]] = {}
+    for kid, inner in drive.frames:
+        assert inner is not None, "a received frame failed to open"
+        if 192 <= inner[1] <= 223 or inner[:8] == PUNCH_ACK:
+            continue                                           # RTCP / punch
+        streams.setdefault((kid, int.from_bytes(inner[8:12], "big")), []).append(
+            (int.from_bytes(inner[2:4], "big"), inner[1] & 0x7F,
+             bool(inner[0] & 0x20)))
+    assert len(streams) == 4 * live_rooms, (
+        f"{len(streams)} egress streams, expected {4 * live_rooms} "
+        "(each track to its two subscribed peers)")
+    media_rx = pad_rx = short = 0
+    for (kid, ssrc), pkts in streams.items():
+        sns = [sn for sn, _, _ in pkts]
+        # continuity of the subscriber's SN space: unwrap around the first,
+        # then every SN from first to last exactly once
+        base = sns[0]
+        un = sorted(((sn - base + 0x8000) & 0xFFFF) - 0x8000 for sn in sns)
+        assert un == list(range(un[0], un[0] + len(un))), (
+            f"sub {kid:#x} ssrc {ssrc:#x}: SN space has gaps or duplicates "
+            f"({len(un)} packets over a span of {un[-1] - un[0] + 1})")
+        media = [p for p in pkts if not p[2]]
+        pad_rx += len(pkts) - len(media)
+        media_rx += len(media)
+        # the window must be whole: lead-in packets may be missing at the
+        # head (video forwards from the first key frame after allocation
+        # has set the subscriber's target), nothing after it
+        assert len(media) >= per_track, (
+            f"sub {kid:#x} ssrc {ssrc:#x}: {len(media)} media packets, the "
+            f"checked window alone sent {per_track}")
+        short += per_track_lead + per_track - len(media)
+    sent_total = drive.sent
+    expected_window = 2 * 2 * live_rooms * per_track
+    say(f"[{name}] packets in {sent_total} ({2 * live_rooms} tracks x "
+        f"{per_track_lead + per_track}), out {media_rx} media + {pad_rx} padding; "
+        f"checked window: {expected_window} expected "
+        f"(= {2 * live_rooms} tracks x {per_track} packets x 2 peers), all received; "
+        f"lead-in shortfall {short} (video waits for its first key frame after "
+        f"the allocator sets a target; not loss)")
+    assert sent_total == 2 * live_rooms * (per_track_lead + per_track)
+    assert media_rx >= expected_window
+    say(f"[{name}] SN space continuous and gap-free on all {len(streams)} "
+        f"(subscriber, track) streams; publisher clock slipped at most "
+        f"{drive.slipped_ms:.1f} ms")
+
+    assert d_ticks >= ticks, f"only {d_ticks} ticks during the drive"
+    assert pa["fwd_packets"] > 0
+    assert len(after["rooms"]) == live_rooms
+    post = compiles["xla_compiles_post_warmup"]
+    line = next(ln for ln in metrics.splitlines()
+                if ln.startswith("livekit_xla_compiles_post_warmup"))
+    say(f"[{name}] compile ledger: {compiles['xla_compiles_total']} total, "
+        f"{post} after warm-up ({line.strip()}); recent {compiles['recent']}")
+    assert post == 0 and float(line.split()[-1]) == 0.0, (
+        "XLA compiled after warm-up", compiles["recent"])
+    if cfg.plane.pager_enabled:
+        ks = pa.get("paged_kernel_ticks", 0)
+        say(f"[{name}] paged kernel ticks {ks}, grid steps "
+            f"{pa.get('paged_kernel_steps', 0)}")
+        assert (ks > 0) == bool(runtime._pk_enabled)
+
+    stats = jax.devices()[0].memory_stats() or {}
+    say(f"[{name}] peak HBM "
+        + (f"{stats['peak_bytes_in_use']} bytes" if "peak_bytes_in_use" in stats
+           else "not reported by this backend"))
+    t0 = time.monotonic()
+    await asyncio.wait_for(server.stop(), 60)
+    say(f"[{name}] server stopped cleanly in {time.monotonic() - t0:.2f} s")
+
+
+# -- device comparisons --------------------------------------------------------
+
+def _assert_trees_match(what: str, a, b) -> None:
+    """Integer and boolean leaves exact; float leaves to 1e-5 relative
+    (two different programs need not round alike on the device)."""
+    import jax
+    import numpy as np
+
+    la, _ = jax.tree_util.tree_flatten_with_path(a)
+    lb = jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    n_int = n_flt = 0
+    for (path, x), y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        where = f"{what}{jax.tree_util.keystr(path)}"
+        assert x.shape == y.shape and x.dtype == y.dtype, where
+        assert np.all(np.isfinite(x)) if x.dtype.kind == "f" else True, where
+        if x.dtype.kind == "f":
+            n_flt += 1
+            np.testing.assert_allclose(x, y, rtol=1e-5, atol=1e-5, err_msg=where)
+        else:
+            n_int += 1
+            assert np.array_equal(x, y), f"{where}: integer field differs"
+    say(f"[{what}] {n_int} integer/bool leaves exact, {n_flt} float leaves "
+        f"within 1e-5")
+
+
+def _random_inputs(rng, shape_rtk, shape_rs, live=None):
+    """Seeded TickInputs; rows outside `live` stay empty."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from livekit_server_tpu.models import plane
+
+    R = shape_rtk[0]
+    mask = np.ones(R, bool) if live is None else np.isin(np.arange(R), live)
+    m3, m2 = mask[:, None, None], mask[:, None]
+    ii = lambda lo, hi: rng.integers(lo, hi, shape_rtk).astype(np.int32) * m3  # noqa: E731
+    bb = lambda p: (rng.random(shape_rtk) < p) & m3                     # noqa: E731
+    fs = lambda lo, hi: (rng.uniform(lo, hi, shape_rs) * m2).astype(np.float32)  # noqa: E731
+    bs = lambda p: (rng.random(shape_rs) < p) & m2                      # noqa: E731
+    rt = shape_rtk[:2]
+    kw = dict(
+        sn=ii(0, 65536), ts=ii(0, 1 << 30), layer=ii(0, 3), temporal=ii(0, 4),
+        keyframe=bb(0.2), layer_sync=bb(0.3), begin_pic=bb(0.4),
+        end_frame=bb(0.4), pid=ii(0, 100), tl0=ii(0, 100), keyidx=ii(0, 30),
+        size=ii(40, 1200), frame_ms=ii(0, 20), audio_level=ii(0, 127),
+        arrival_rtp=ii(0, 1 << 28), ts_jump=np.zeros(shape_rtk, np.int32),
+        valid=bb(0.8), estimate=fs(1e5, 5e6), estimate_valid=bs(0.5),
+        nacks=fs(0, 3),
+        pub_rtt_ms=(rng.uniform(0, 80, rt) * m2).astype(np.float32),
+        fb_delay_ms=fs(0, 30), fb_recv_bps=fs(1e5, 4e6), fb_valid=bs(0.6),
+        fb_enabled=bs(0.8), sub_reset=np.zeros(shape_rs, bool),
+        pad_num=np.zeros(shape_rs, np.int32),
+        pad_track=np.full(shape_rs, -1, np.int32),
+        tick_ms=np.int32(10), roll_quality=np.int32(0),
+    )
+    return plane.TickInputs(**{k: jnp.asarray(v) for k, v in kw.items()})
+
+
+def _random_ctrl(rng, state, live=None):
+    """Seeded publications and subscriptions on the rows in `live`."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    R, T, S = state.ctrl.subscribed.shape
+    mask = np.ones(R, bool) if live is None else np.isin(np.arange(R), live)
+    vid = (rng.random((R, T)) < 0.6) & mask[:, None]
+    return state._replace(
+        meta=state.meta._replace(
+            is_video=jnp.asarray(vid),
+            is_svc=jnp.asarray((rng.random((R, T)) < 0.3) & vid),
+            published=jnp.asarray((rng.random((R, T)) < 0.9) & mask[:, None])),
+        ctrl=state.ctrl._replace(
+            subscribed=jnp.asarray((rng.random((R, T, S)) < 0.7) & mask[:, None, None]),
+            sub_muted=jnp.asarray((rng.random((R, T, S)) < 0.1) & mask[:, None, None])),
+    )
+
+
+def paged_kernel_comparison(seed: int, toy: bool) -> None:
+    """`paged_plane_tick_fused` (the ragged Pallas kernel) against the stock
+    `paged_plane_tick` on the same seeded pool, table and inputs."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from livekit_server_tpu.models import paged, plane
+    from livekit_server_tpu.runtime.pager import RoomPager
+    from livekit_server_tpu.runtime.slots import CapacityError
+
+    if toy:
+        pd = paged.PagedDims(rooms=4, tracks=4, pkts=4, subs=8,
+                             tpage=2, spage=4, pool_pages=16)
+    else:
+        pd = paged.PagedDims(rooms=64, tracks=16, pkts=16, subs=32,
+                             tpage=4, spage=8, pool_pages=1024)
+    rng = np.random.default_rng(seed)
+    pager = RoomPager(rooms=pd.rooms, tracks=pd.tracks, subs=pd.subs,
+                      tpage=pd.tpage, spage=pd.spage, pool_pages=pd.pool_pages)
+    for row in range(pd.rooms):       # mixed room sizes; about half the pool live
+        n = int(rng.integers(1, max(2, pd.tracks // 2 + 1)))
+        try:
+            pager.alloc_room(row, tracks=n, subs=int(rng.integers(1, pd.subs // 2 + 1)))
+        except CapacityError:
+            break
+    table = paged.PageTable(
+        rooms_pages=jnp.asarray(pager.rooms_pages),
+        tmembers=jnp.asarray(pager.tmembers), pg_room=jnp.asarray(pager.pg_room),
+        pg_tp=jnp.asarray(pager.pg_tp), pg_sp=jnp.asarray(pager.pg_sp))
+    live = np.nonzero(pager.pg_room >= 0)[0].astype(np.int32)
+    nl = 1 << max(len(live) - 1, 1).bit_length()
+    live_rows = np.concatenate([live, np.repeat(live[:1], nl - len(live))]).astype(np.int32)
+    live_inv = np.zeros(pd.pool_pages, np.int32)
+    live_inv[live] = np.arange(len(live), dtype=np.int32)
+
+    state = _random_ctrl(rng, plane.init_state(pd.pooled()), live)
+    stock = jax.jit(lambda s, i: paged.paged_plane_tick(s, i, table))
+    fused = jax.jit(lambda s, i: paged.paged_plane_tick_fused(
+        s, i, table, live_rows, live_inv))
+    s_a = s_b = state
+    P = pd.pool_pages
+    for _ in range(3):
+        inp = _random_inputs(rng, (P, pd.tpage, pd.pkts), (P, pd.spage), live)
+        s_a, o_a = stock(s_a, inp)
+        s_b, o_b = fused(s_b, inp)
+    jax.block_until_ready((o_a, o_b))
+    say(f"[paged-compare] pool {P} pages of {pd.tpage}x{pd.spage}, "
+        f"{len(live)} live, {nl} grid steps, 3 ticks, seed {seed}")
+    assert int(np.asarray(o_a.fwd_packets).sum()) > 0
+    _assert_trees_match("paged-compare state", s_a, s_b)
+    _assert_trees_match("paged-compare outputs", o_a, o_b)
+
+
+def four_chip_phase(seed: int, toy: bool) -> None:
+    """`mesh.make_sharded_tick` over four devices at 4 x cfg4 rooms against
+    the single-device tick on the same seeded state and inputs."""
+    import jax
+    import numpy as np
+
+    from livekit_server_tpu.models import plane
+    from livekit_server_tpu.parallel.mesh import (
+        make_mesh, make_sharded_tick, shard_tree,
+    )
+
+    devs = jax.devices()[:4]
+    assert len(devs) == 4, f"--chips 4 needs four devices, JAX reports {len(jax.devices())}"
+    dims = plane.PlaneDims(32, 4, 4, 4) if toy else plane.PlaneDims(4096, 10, 8, 10)
+    rng = np.random.default_rng(seed)
+    state = _random_ctrl(rng, plane.init_state(dims))
+    inputs = [_random_inputs(rng, (dims.rooms, dims.tracks, dims.pkts),
+                             (dims.rooms, dims.subs)) for _ in range(3)]
+    say(f"[four-chip] dims {tuple(dims)}, 3 ticks, seed {seed}")
+
+    single = jax.jit(plane.media_plane_tick)
+    s1 = jax.device_put(state, devs[0])
+    for inp in inputs:
+        s1, o1 = single(s1, jax.device_put(inp, devs[0]))
+    jax.block_until_ready(o1)
+
+    mesh = make_mesh(devs)
+    sharded = make_sharded_tick(mesh)
+    s4 = shard_tree(state, mesh)
+    for inp in inputs:
+        s4, o4 = sharded(s4, shard_tree(inp, mesh))
+    jax.block_until_ready(o4)
+
+    # where the state lives: bytes of state shards on each device
+    per_dev = {d.id: 0 for d in devs}
+    for leaf in jax.tree.leaves(s4):
+        for sh in leaf.addressable_shards:
+            per_dev[sh.device.id] += sh.data.nbytes
+    total = sum(per_dev.values())
+    say(f"[four-chip] state bytes per device {per_dev} (total {total})")
+    assert min(per_dev.values()) > 0.2 * total, "state is not spread over four devices"
+    for d in devs:
+        ms = d.memory_stats() or {}
+        if "peak_bytes_in_use" in ms:
+            say(f"[four-chip] device {d.id} peak HBM {ms['peak_bytes_in_use']} bytes")
+    assert int(np.asarray(o1.fwd_packets).sum()) > 0
+    _assert_trees_match("four-chip state", s1, s4)
+    _assert_trees_match("four-chip outputs", o1, o4)
+
+
+# -- main ------------------------------------------------------------------------
+
+def native_report() -> None:
+    """Which implementation carries parse / munge / egress: the C++
+    libraries built from native/*.cpp, or their Python twins."""
+    from livekit_server_tpu import native
+
+    have = {
+        "parse": bool(getattr(native.rtp, "native", False)),
+        "munge": native.munge is not None,
+        "egress": native.egress is not None,
+    }
+    gxx = shutil.which("g++")
+    say("native libraries: " + ", ".join(
+        f"{k}={'C++' if v else 'Python twin'}" for k, v in have.items())
+        + f" (g++ {'at ' + gxx if gxx else 'absent'})")
+    if gxx and not all(have.values()):
+        raise RuntimeError(
+            "a compiler exists but a Python twin carried the run: " + str(have))
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="toy sizes, no platform assertion (CPU rehearsal)")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    if not args.rehearse:
+        if device["platform"] != "tpu":
+            print(f"chip_smoke: no TPU (JAX reports {device}); nothing was run",
+                  file=sys.stderr)
+            return 2
+        if device["count"] != args.chips:
+            print(f"chip_smoke: --chips {args.chips} but JAX reports "
+                  f"{device['count']} devices", file=sys.stderr)
+            return 2
+
+    from livekit_server_tpu.utils.compile_cache import setup_compile_cache
+
+    say(f"jax {jax.__version__}, device {device['kind']} x {device['count']} "
+        f"({device['platform']}), compile cache {setup_compile_cache()}")
+
+    if args.chips == 4:
+        four_chip_phase(args.seed, toy=args.rehearse)
+    else:
+        native_report()
+        toy = args.rehearse
+        size = dict(live_rooms=3, lead_ticks=60, ticks=60) if toy else dict(
+            live_rooms=32, lead_ticks=100, ticks=400)
+        asyncio.run(served_phase("dense", TOY if toy else CFG4, **size))
+        paged_plane = dict(TOY, pager_tpage=2, pager_spage=2) if toy else SERVE_DEFAULT
+        asyncio.run(served_phase(
+            "paged", dict(paged_plane, pager_enabled=True), **size))
+        paged_kernel_comparison(args.seed, toy)
+
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

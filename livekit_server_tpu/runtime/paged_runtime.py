@@ -59,6 +59,7 @@ from livekit_server_tpu.runtime.pager import RoomPager
 from livekit_server_tpu.runtime.plane_runtime import (
     PlaneRuntime,
     _build_ctrl_delta,
+    _build_row_read,
     _build_row_write,
 )
 from livekit_server_tpu.runtime.slots import PagedSlotAllocator
@@ -513,6 +514,67 @@ class PagedPlaneRuntime(PlaneRuntime):
             for c in ctrl
         ])
         return pr, meta_rows, ctrl_rows
+
+    def warm_compile(self) -> None:
+        """The paged form of the base warm-up. Every device program here
+        is compiled once per power-of-two bucket of its row count (pages
+        of a table delta, dirtied ctrl pages, re-inited or moved pages,
+        the live-page extent of the ragged tick); first use of a bucket
+        in steady state would stall the tick for the compile, and the
+        ingest ring overflows meanwhile. So run each at every bucket
+        now, writing back the values already there (page 0 and room 0 of
+        an empty pool: the scatters are no-ops), and the live-extent
+        tick on a scratch copy of the state. Callers hold state_lock."""
+        import jax.numpy as jnp
+
+        self._sync_pages()
+        self._logical_fill()
+        self._pooled_fill()
+        d, pg = self.pdims, self.pager
+        P, R = d.pool_pages, d.rooms
+        buckets = lambda top: [1 << i for i in range((top - 1).bit_length() + 1)]  # noqa: E731
+        page0 = jax.tree.map(
+            np.asarray, _build_row_read()(self.state, np.int32(0))
+        )
+        meta0 = np.stack([np.asarray(m, np.int32) for m in page0.meta])
+        ctrl0 = np.stack([np.asarray(c, np.int32) for c in page0.ctrl])
+        for n in buckets(P):
+            rows = np.zeros(n, np.int32)
+            self.state = self._apply_delta(
+                self.state, rows, np.repeat(meta0[:, None], n, axis=1),
+                np.repeat(ctrl0[:, None], n, axis=1),
+            )
+            self.state = self._reinit(
+                self.state, jnp.asarray(rows), self._page_template
+            )
+            self.state = self._move(
+                self.state, jnp.asarray(rows), jnp.asarray(rows)
+            )
+            for m in buckets(R):
+                rrows = np.zeros(m, np.int32)
+                self.table = self._table_delta(
+                    self.table, rows, pg.tmembers[rows], pg.pg_room[rows],
+                    pg.pg_tp[rows], pg.pg_sp[rows], rrows,
+                    pg.rooms_pages[rrows],
+                )
+        if self._pk_enabled:
+            pool = d.pooled()
+            packed = (
+                np.zeros((len(plane.PKT_FIELDS), P, pool.tracks, pool.pkts),
+                         np.int32),
+                np.zeros((8, P, pool.subs), np.float32),
+                np.zeros((1, P, pool.tracks), np.float32),
+                np.int32(self.tick_ms), np.int32(0),
+            )
+            keep = (self._live_rows, self._live_inv, self._kernel_s_scratch,
+                    self._kernel_steps_scratch)
+            self._live_inv = np.zeros(P, np.int32)
+            for n in buckets(P):
+                self._live_rows = np.zeros(n, np.int32)
+                scratch = jax.tree.map(jnp.copy, self.state)
+                jax.block_until_ready(self._live_step(scratch, *packed))
+            (self._live_rows, self._live_inv, self._kernel_s_scratch,
+             self._kernel_steps_scratch) = keep
 
     # -- kernel span accounting --------------------------------------------
 

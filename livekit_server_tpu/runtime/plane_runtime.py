@@ -287,6 +287,13 @@ def _build_row_write(sharding=None):
     return jax.jit(_set_rows, donate_argnums=(0,), **kw)
 
 
+@functools.lru_cache(maxsize=None)
+def _build_row_read():
+    """Whole-tree row gather (snapshot_room): the read half of
+    `_build_row_write`, one program for the same reason."""
+    return jax.jit(lambda state, row: jax.tree.map(lambda x: x[row], state))
+
+
 @dataclass
 class StagedTick:
     """One tick's host-staged inputs, carried through the three-stage
@@ -1431,9 +1438,9 @@ class PlaneRuntime:
         else slices on device first so only one row crosses HBM→host. The
         host munger's row (SN/TS/VP8 offsets — RTPMungerState seeding,
         rtpmunger.go:53-69) rides along after the device leaves."""
-        flat, treedef = jax.tree.flatten(self.state)
-        arrays = [np.asarray(x[row]) for x in flat]
-        tree = jax.tree.unflatten(treedef, arrays)
+        tree = jax.tree.map(
+            np.asarray, _build_row_read()(self.state, np.int32(row))
+        )
         tree = tree._replace(
             meta=plane.TrackMeta(*[np.array(m[row]) for m in self.meta]),
             ctrl=plane.SubControl(*[np.array(c[row]) for c in self.ctrl]),
@@ -1547,11 +1554,30 @@ class PlaneRuntime:
         ])
         self.state = self._row_write(self.state, np.int32(row), row_tree)
 
-    def warm_row_write(self) -> None:
-        """Compile the row scatter inside the warm-up window by writing
-        row 0 back onto itself. Callers hold state_lock (GC01)."""
+    def warm_compile(self) -> None:
+        """Compile, inside the warm-up window, the programs whose first
+        use would otherwise fall in steady state: the dirty-row control
+        scatter at every power-of-two bucket `_upload_ctrl` can ask for
+        (a join would compile one mid-session) and the row read / write
+        of room handoff and integrity repair (a migration's first
+        adoption would outlast its ACK timeout). Each runs on the live
+        state with the values already there, so the state is unchanged.
+        Callers hold state_lock (GC01)."""
+        row0 = jax.tree.map(
+            np.asarray, _build_row_read()(self.state, np.int32(0))
+        )
+        meta0 = np.stack([np.asarray(m, np.int32) for m in row0.meta])
+        ctrl0 = np.stack([np.asarray(c, np.int32) for c in row0.ctrl])
+        n = 1
+        while n < 2 * self.ctrl_delta_max_rows:
+            self.state = self._apply_delta(
+                self.state, np.zeros(n, np.int32),
+                np.repeat(meta0[:, None], n, axis=1),
+                np.repeat(ctrl0[:, None], n, axis=1),
+            )
+            n *= 2
         flat, treedef = jax.tree.flatten(self.state)
-        self._write_row(0, flat, treedef, [np.asarray(x[0]) for x in flat])
+        self._write_row(0, flat, treedef, jax.tree.leaves(row0))
 
     def repair_room_row(self, row: int, snap: dict[str, Any]) -> None:
         """Integrity row repair: overwrite ONE corrupt room row from a

@@ -480,10 +480,10 @@ class LivekitServer:
         # first tick doesn't stall the event loop mid-session (XLA compiles
         # once per (shapes, params); later ticks hit the cache).
         await self.room_manager.runtime.step_once()
-        # The row scatter of room adoption / integrity repair too: its
-        # first use is inside a migration's ACK timeout.
+        # ...and the programs a join, a migration or a repair would
+        # otherwise compile mid-session.
         async with self.room_manager.runtime.state_lock:
-            self.room_manager.runtime.warm_row_write()
+            self.room_manager.runtime.warm_compile()
         # Watermark for the recompile watchdog: anything XLA compiles
         # after this point is a steady-state retrace (surfaced at
         # /debug/compiles and livekit_xla_compiles_total).
