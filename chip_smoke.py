@@ -35,6 +35,12 @@ VP8_PT, OPUS_PT = 96, 111
 
 # Dense phase: BASELINE.json cfg4 width. Paged phase: the `serve`
 # defaults with `plane.pager_enabled: true` (page 4×8, pool 1024).
+# Both at the 20 ms tick the ladder runs cfg4 at (bench.py, tools/
+# profile_tick.py): at 10 ms the idle loop alone costs more than the tick
+# at cfg4 width on the chip's host (stage 2.9 + device call 4.8 + fan-out
+# 4.5 ms; my chip run, PR 25), and the overload governor, rightly, refuses
+# every join. PERF.md has the numbers; ROADMAP queue A has the item.
+TICK_MS = 20
 CFG4 = dict(rooms=1024, tracks_per_room=10, pkts_per_track=8, subs_per_room=10)
 SERVE_DEFAULT = dict(rooms=64, tracks_per_room=16, pkts_per_track=16,
                      subs_per_room=32)
@@ -243,7 +249,7 @@ async def http_json(session, port: int, path: str) -> dict:
 
 
 async def served_phase(name: str, plane: dict, *, live_rooms: int, lead_ticks: int,
-                       ticks: int, tick_ms: int = 10) -> None:
+                       ticks: int, tick_ms: int = TICK_MS) -> None:
     """Start the server as `serve` does, join `live_rooms` rooms of three
     (a video publisher, an audio publisher, a listener; everyone
     subscribed to everyone else), drive media over sealed UDP, and check
@@ -274,6 +280,17 @@ async def served_phase(name: str, plane: dict, *, live_rooms: int, lead_ticks: i
 
     drive = MediaDrive(cfg.rtc.udp_port)
     async with aiohttp.ClientSession() as session:
+        # -- the idle loop: what a tick costs before anyone has joined -----
+        await asyncio.sleep(1.0)
+        idle = (await http_json(session, cfg.port, "/debug/ticks"))["recent_ticks"][-40:]
+        med = lambda k: sorted(t[k] for t in idle)[len(idle) // 2]  # noqa: E731
+        level = (await http_json(session, cfg.port, "/debug/overload"))["governor"]["level"]
+        say(f"[{name}] idle tick (median of {len(idle)}, host clock, no result): "
+            f"stage {med('stage_ms')} + device call {med('device_ms')} + fan-out "
+            f"{med('fanout_ms')} = {med('total_ms')} ms of {tick_ms}; "
+            f"governor level {level}")
+        assert level == 0, "the governor is shedding before any client joined"
+
         # -- join: JWT → /rtc → tracks → subscriptions → UDP punch ---------
         rooms = []
         for r in range(live_rooms):
@@ -349,6 +366,7 @@ async def served_phase(name: str, plane: dict, *, live_rooms: int, lead_ticks: i
                 break
         after = await http_json(session, cfg.port, "/debug/rooms")
         compiles = await http_json(session, cfg.port, "/debug/compiles")
+        governor = (await http_json(session, cfg.port, "/debug/overload"))["governor"]
         async with session.get(f"http://127.0.0.1:{cfg.port}/metrics") as resp:
             metrics = await resp.text()
         for people, _, _ in rooms:
@@ -360,7 +378,8 @@ async def served_phase(name: str, plane: dict, *, live_rooms: int, lead_ticks: i
     d_ticks = pa["ticks"] - pb["ticks"]
     say(f"[{name}] /debug/rooms: ticks {pb['ticks']} -> {pa['ticks']}, "
         f"fwd_packets {pa['fwd_packets']}, late_ticks {pa.get('late_ticks', 0)}, "
-        f"ingest_dropped {after['ingest_dropped']}")
+        f"ingest_dropped {after['ingest_dropped']}, governor level "
+        f"{governor['level']} after {governor['transition_count']} transitions")
 
     # -- reckoning -------------------------------------------------------------
     # Group what arrived by (subscriber key, egress SSRC): one munged SN
@@ -417,7 +436,7 @@ async def served_phase(name: str, plane: dict, *, live_rooms: int, lead_ticks: i
     line = next(ln for ln in metrics.splitlines()
                 if ln.startswith("livekit_xla_compiles_post_warmup"))
     say(f"[{name}] compile ledger: {compiles['xla_compiles_total']} total, "
-        f"{post} after warm-up ({line.strip()}); recent {compiles['recent']}")
+        f"{post} after warm-up ({line.strip()})")
     assert post == 0 and float(line.split()[-1]) == 0.0, (
         "XLA compiled after warm-up", compiles["recent"])
     if cfg.plane.pager_enabled:
