@@ -35,12 +35,15 @@ VP8_PT, OPUS_PT = 96, 111
 
 # Dense phase: BASELINE.json cfg4 width. Paged phase: the `serve`
 # defaults with `plane.pager_enabled: true` (page 4×8, pool 1024).
-# Both at the 20 ms tick the ladder runs cfg4 at (bench.py, tools/
-# profile_tick.py): at 10 ms the idle loop alone costs more than the tick
-# at cfg4 width on the chip's host (stage 2.9 + device call 4.8 + fan-out
-# 4.5 ms; my chip run, PR 25), and the overload governor, rightly, refuses
-# every join. PERF.md has the numbers; ROADMAP queue A has the item.
-TICK_MS = 20
+#
+# The ticks are what one Python process can hold with its own clients
+# beside it. At cfg4 width the idle loop alone costs 12-16 ms a tick on the
+# chip's host (stage + device call + fan-out, every room row staged and
+# unpacked whether live or not; my chip runs, PR 25): at the default 10 ms
+# the overload governor, rightly, refuses every join, and at 20 ms it sheds
+# as soon as media flows. PERF.md has the numbers, ROADMAP queue A the item.
+CFG4_TICK_MS, PAGED_TICK_MS = 40, 20
+MEDIA_MS = 20      # one packet per track per 20 ms: 50 pkt/s, video and audio
 CFG4 = dict(rooms=1024, tracks_per_room=10, pkts_per_track=8, subs_per_room=10)
 SERVE_DEFAULT = dict(rooms=64, tracks_per_room=16, pkts_per_track=16,
                      subs_per_room=32)
@@ -150,7 +153,7 @@ class MediaDrive:
         self.pub = self._sock()
         self.sub = self._sock()
         self.sub.settimeout(0.05)
-        self.frames: list[tuple] = []        # (key_id, opened frame), as received
+        self.frames: list[tuple] = []        # (key_id, sealed | None, opened | None)
         self.clients: dict[int, object] = {}     # key_id → MediaCryptoClient
         self.fb_ssrc: dict[int, int] = {}        # key_id → an egress SSRC
         self._pending: dict[int, list] = {}      # key_id → [(ctr, recv_us)]
@@ -172,7 +175,7 @@ class MediaDrive:
 
     def _recv_loop(self) -> None:
         """Drain egress; ack sealed-frame counters as transport-wide
-        feedback every 50 ms, as a real client's congestion control does
+        feedback every 100 ms, as a real client's congestion control does
         (without it the server's send-side BWE starves the video)."""
         from livekit_server_tpu.runtime.udp import build_twcc_feedback
 
@@ -184,17 +187,23 @@ class MediaDrive:
                 f = None
             if f is not None and len(f) > 14 and f[0] == 0x01:
                 kid = int.from_bytes(f[1:5], "big")
-                # opened here, once: the replay window refuses a second open
-                inner = self.clients[kid].open(f)
-                self.frames.append((kid, inner))
                 self._pending.setdefault(kid, []).append(
                     (int.from_bytes(f[6:14], "big"), time.monotonic_ns() // 1000)
                 )
-                if (kid not in self.fb_ssrc and inner is not None
-                        and len(inner) >= 12 and not 192 <= inner[1] <= 223):
+                # Frames are opened after the drive (`opened()`), off the
+                # server's clock — all but each subscriber's first, whose
+                # SSRC the feedback needs. Each frame is opened once: the
+                # replay window refuses a second open.
+                if kid in self.fb_ssrc:
+                    self.frames.append((kid, f, None))
+                    continue
+                inner = self.clients[kid].open(f)
+                self.frames.append((kid, None, inner))
+                if (inner is not None and len(inner) >= 12
+                        and not 192 <= inner[1] <= 223):
                     self.fb_ssrc[kid] = int.from_bytes(inner[8:12], "big")
             now = time.monotonic()
-            if now - last_fb >= 0.05:
+            if now - last_fb >= 0.1:
                 last_fb = now
                 for kid, ents in self._pending.items():
                     if ents and kid in self.fb_ssrc:
@@ -203,8 +212,8 @@ class MediaDrive:
                         ents.clear()
 
     def send_schedule(self, schedule: list[list[bytes]], tick_s: float) -> None:
-        """Send one list of sealed datagrams per tick, paced on this
-        thread's own clock (blocking — call via asyncio.to_thread)."""
+        """Send one list of sealed datagrams per media interval, paced on
+        this thread's own clock (blocking — call via asyncio.to_thread)."""
         t0 = time.monotonic()
         for i, batch in enumerate(schedule):
             due = t0 + i * tick_s
@@ -222,6 +231,11 @@ class MediaDrive:
         self._rx.join(timeout=5)
         self.pub.close()
         self.sub.close()
+
+    def opened(self):
+        """(key_id, plaintext) of every frame received, in arrival order."""
+        for kid, sealed, inner in self.frames:
+            yield kid, (inner if sealed is None else self.clients[kid].open(sealed))
 
 
 # -- one served phase ----------------------------------------------------------
@@ -248,8 +262,8 @@ async def http_json(session, port: int, path: str) -> dict:
         return await r.json()
 
 
-async def served_phase(name: str, plane: dict, *, live_rooms: int, lead_ticks: int,
-                       ticks: int, tick_ms: int = TICK_MS) -> None:
+async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
+                       lead_ticks: int, ticks: int) -> None:
     """Start the server as `serve` does, join `live_rooms` rooms of three
     (a video publisher, an audio publisher, a listener; everyone
     subscribed to everyone else), drive media over sealed UDP, and check
@@ -331,31 +345,30 @@ async def served_phase(name: str, plane: dict, *, live_rooms: int, lead_ticks: i
 
         # -- media: sealed ahead of time, sent on the publisher thread -----
         # video 50 pkt/s of 907 B, audio 50 pkt/s of 80 B per track: a rate
-        # one Python process can seal, send, receive and open beside the
-        # server it is driving.
-        every = max(1, 20 // tick_ms)
-        n_total = lead_ticks + ticks
+        # one Python process can send and receive beside the server it is
+        # driving (sealed before the drive, opened after it).
+        per_track_lead = lead_ticks * tick_ms // MEDIA_MS
+        per_track = ticks * tick_ms // MEDIA_MS
+        n_total = per_track_lead + per_track
         schedule: list[list[bytes]] = [[] for _ in range(n_total)]
         sent_sns: dict[int, list[int]] = {}      # publisher ssrc → SNs, in order
         for r, (people, v_ssrc, a_ssrc) in enumerate(rooms):
             for who, ssrc, video in ((0, v_ssrc, True), (1, a_ssrc, False)):
                 seal = people[who].crypto.seal
                 sns = sent_sns[ssrc] = []
-                for i in range(0, n_total, every):
-                    sn = (1000 * r + 7 + len(sns)) & 0xFFFF
-                    ts = (90 if video else 48) * tick_ms * i
+                for i in range(n_total):
+                    sn = (1000 * r + 7 + i) & 0xFFFF
+                    ts = (90 if video else 48) * MEDIA_MS * i
                     schedule[i].append(seal(rtp_packet(
                         VP8_PT if video else OPUS_PT, sn, ts, ssrc, video)))
                     sns.append(sn)
-        per_track_lead = len(range(0, lead_ticks, every))
-        per_track = len(range(0, n_total, every)) - per_track_lead
-        pps_in = 2 * live_rooms * 1000 // (tick_ms * every)
+        pps_in = 2 * live_rooms * 1000 // MEDIA_MS
         say(f"[{name}] offered {pps_in} pkt/s in, {2 * pps_in} pkt/s out expected; "
             f"lead-in {lead_ticks} ticks, checked window {ticks} ticks")
 
         before = await http_json(session, cfg.port, "/debug/rooms")
         drive.start()
-        await asyncio.to_thread(drive.send_schedule, schedule, tick_ms / 1e3)
+        await asyncio.to_thread(drive.send_schedule, schedule, MEDIA_MS / 1e3)
         # let the pipeline drain: every packet of the window out, or 5 s
         want_frames = 2 * 2 * live_rooms * per_track
         deadline = time.monotonic() + 5
@@ -385,7 +398,7 @@ async def served_phase(name: str, plane: dict, *, live_rooms: int, lead_ticks: i
     # Group what arrived by (subscriber key, egress SSRC): one munged SN
     # space each. Padding probes share a stream's SN space and are not media.
     streams: dict[tuple[int, int], list[tuple[int, int, bool]]] = {}
-    for kid, inner in drive.frames:
+    for kid, inner in drive.opened():
         assert inner is not None, "a received frame failed to open"
         if 192 <= inner[1] <= 223 or inner[:8] == PUNCH_ACK:
             continue                                           # RTCP / punch
@@ -691,12 +704,14 @@ def main(argv: list[str] | None = None) -> int:
     else:
         native_report()
         toy = args.rehearse
-        size = dict(live_rooms=3, lead_ticks=60, ticks=60) if toy else dict(
-            live_rooms=32, lead_ticks=100, ticks=400)
-        asyncio.run(served_phase("dense", TOY if toy else CFG4, **size))
+        size = dict(live_rooms=3, lead_ticks=40, ticks=60) if toy else dict(
+            live_rooms=32, lead_ticks=50, ticks=320)
+        asyncio.run(served_phase(
+            "dense", TOY if toy else CFG4, tick_ms=CFG4_TICK_MS, **size))
         paged_plane = dict(TOY, pager_tpage=2, pager_spage=2) if toy else SERVE_DEFAULT
         asyncio.run(served_phase(
-            "paged", dict(paged_plane, pager_enabled=True), **size))
+            "paged", dict(paged_plane, pager_enabled=True),
+            tick_ms=PAGED_TICK_MS, **size))
         paged_kernel_comparison(args.seed, toy)
 
     print(json.dumps({"ok": True, "device": device}), flush=True)

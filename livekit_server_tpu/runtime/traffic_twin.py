@@ -1004,11 +1004,11 @@ def scenario_from_config(twin_cfg) -> Scenario:
 
 
 def main(argv=None) -> int:
-    """CLI used by `bench.py fleet_twin` and `tools/check --twin-smoke`.
+    """CLI (`tools/check --twin-smoke` runs `--smoke`; bench.py's
+    fleet_twin section calls `capacity_curve` in its own process).
 
     Prints progress to stderr and exactly one JSON object line to stdout
-    LAST — the contract `bench.absorb_twin_json` pins (the driver keeps
-    the final `{`-prefixed stdout line).
+    LAST.
     """
     import argparse
     import sys

@@ -3,7 +3,6 @@ byte-identical-timeline determinism contract, a full same-seed replay
 equivalence check, the twin.* config knobs, and the bench last-line-JSON
 absorption contract shared by the wire twin and fleet_twin sections."""
 
-import json
 
 import pytest
 
@@ -137,32 +136,3 @@ def test_twin_config_knobs_and_validation():
     ):
         with pytest.raises(ConfigError):
             load_config(yaml_text=BASE_YAML + frag)
-
-
-# -- bench absorption contract ----------------------------------------------
-
-def test_bench_absorb_twin_last_json_line_wins():
-    from bench import absorb_twin_json
-
-    out = "\n".join([
-        "warmup chatter",
-        json.dumps({"steps": [1]}),
-        "progress: load x2.0",
-        json.dumps({"steps": [1, 2], "partial": True}),
-        json.dumps({"steps": [1, 2, 3], "capacity_knee_load": 2.0}),
-    ])
-    got = absorb_twin_json(out)
-    assert got["capacity_knee_load"] == 2.0
-    assert got["steps"] == [1, 2, 3]
-
-    # A killed child that emitted only a partial curve still salvages it.
-    partial = absorb_twin_json(out.rsplit("\n", 1)[0])
-    assert partial["partial"] is True
-
-
-def test_bench_absorb_twin_raises_without_json():
-    from bench import absorb_twin_json
-
-    for stdout in ("", "no json here\nstill none", None):
-        with pytest.raises(ValueError, match="twin produced no JSON"):
-            absorb_twin_json(stdout)
