@@ -47,7 +47,7 @@ MEDIA_MS = 20      # one packet per track per 20 ms: 50 pkt/s, video and audio
 CFG4 = dict(rooms=1024, tracks_per_room=10, pkts_per_track=8, subs_per_room=10)
 SERVE_DEFAULT = dict(rooms=64, tracks_per_room=16, pkts_per_track=16,
                      subs_per_room=32)
-TOY = dict(rooms=8, tracks_per_room=4, pkts_per_track=4, subs_per_room=4)
+TOY = dict(rooms=8, tracks_per_room=4, pkts_per_track=16, subs_per_room=4)
 
 
 def say(msg: str) -> None:
@@ -286,6 +286,19 @@ async def http_json(session, port: int, path: str) -> dict:
         return await r.json()
 
 
+async def tick_report(session, port: int, name: str, when: str, tick_ms: int) -> int:
+    """Print the loop's own per-stage split of recent ticks (host clock; a
+    reading to diagnose with, not a result) and return the governor level."""
+    ticks = (await http_json(session, port, "/debug/ticks"))["recent_ticks"][-40:]
+    med = lambda k: sorted(t[k] for t in ticks)[len(ticks) // 2]  # noqa: E731
+    level = (await http_json(session, port, "/debug/overload"))["governor"]["level"]
+    say(f"[{name}] {when} tick (median of {len(ticks)}, host clock, no result): "
+        f"stage {med('stage_ms')} + device call {med('device_ms')} + fan-out "
+        f"{med('fanout_ms')} = {med('total_ms')} ms of {tick_ms}; "
+        f"governor level {level}")
+    return level
+
+
 async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
                        lead_ticks: int, ticks: int) -> None:
     """Start the server as `serve` does, join `live_rooms` rooms of three
@@ -320,13 +333,7 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
     async with aiohttp.ClientSession() as session:
         # -- the idle loop: what a tick costs before anyone has joined -----
         await asyncio.sleep(1.0)
-        idle = (await http_json(session, cfg.port, "/debug/ticks"))["recent_ticks"][-40:]
-        med = lambda k: sorted(t[k] for t in idle)[len(idle) // 2]  # noqa: E731
-        level = (await http_json(session, cfg.port, "/debug/overload"))["governor"]["level"]
-        say(f"[{name}] idle tick (median of {len(idle)}, host clock, no result): "
-            f"stage {med('stage_ms')} + device call {med('device_ms')} + fan-out "
-            f"{med('fanout_ms')} = {med('total_ms')} ms of {tick_ms}; "
-            f"governor level {level}")
+        level = await tick_report(session, cfg.port, name, "idle", tick_ms)
         assert level == 0, "the governor is shedding before any client joined"
 
         # -- join: JWT → /rtc → tracks → subscriptions → UDP punch ---------
@@ -390,6 +397,7 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
         before = await http_json(session, cfg.port, "/debug/rooms")
         drive.start()
         await asyncio.to_thread(drive.send_schedule, schedule, MEDIA_MS / 1e3)
+        await tick_report(session, cfg.port, name, "loaded", tick_ms)
         # let the pipeline drain: every packet of the window out, or 5 s
         want_frames = 2 * 2 * live_rooms * per_track
         deadline = time.monotonic() + 5
@@ -446,8 +454,11 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
         # head (video forwards from the first key frame after allocation
         # has set the subscriber's target), nothing after it
         assert len(media) >= per_track, (
-            f"sub {kid:#x} ssrc {ssrc:#x}: {len(media)} media packets, the "
-            f"checked window alone sent {per_track}")
+            f"sub {kid:#x} ssrc {ssrc:#x} ({'video' if pkts[0][1] == VP8_PT else 'audio'}): "
+            f"{len(media)} media packets, the checked window alone sent "
+            f"{per_track}; short streams: " + str(sorted(
+                (len(v), v[0][1]) for v in streams.values()
+                if len(v) < per_track)[:8]))
         short += per_track_lead + per_track - len(media)
     sent_total = drive.sent
     expected_window = 2 * 2 * live_rooms * per_track

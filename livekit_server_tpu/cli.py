@@ -170,7 +170,9 @@ def main(argv: list[str] | None = None) -> int:
 
 async def _serve(cfg: Config) -> int:
     from livekit_server_tpu.service.server import connect_bus, create_server
+    from livekit_server_tpu.utils.compile_cache import setup_compile_cache
 
+    setup_compile_cache()
     server = create_server(cfg, bus=await connect_bus(cfg))
     await server.start()
     print(

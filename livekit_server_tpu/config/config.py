@@ -158,7 +158,6 @@ class PlaneConfig:
     tracks_per_room: int = 16
     pkts_per_track: int = 16     # packet slots per track per tick
     subs_per_room: int = 32
-    mesh_devices: int = 0        # 0 = all local devices
     donate_state: bool = True
     # Complete each tick's egress before starting the next tick instead of
     # overlapping it with the next device step: ~1 tick lower forward

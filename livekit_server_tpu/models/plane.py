@@ -677,7 +677,7 @@ def media_plane_tick(
 # ---------------------------------------------------------------------------
 # Wire packing: one upload + one fetch per tick.
 #
-# A remote/tunneled device (and even PCIe) pays per-transfer latency, so the
+# Every host↔device transfer pays a fixed latency, so the
 # runtime ships TickInputs as ONE stacked int32 array (+ one float32 feedback
 # array) and receives TickOutputs as ONE flat int32 buffer, unpacked by known
 # offsets on host. The reference has no analog — its packets stay in host

@@ -26,7 +26,7 @@ plane level:
     utils/checksum frame; restore walks newest→oldest and falls back a
     generation (counter + warn) on a corrupt or shape-mismatched frame
     instead of committing garbage into donated device state.
-  - restart-cause taxonomy — `stall` (watchdog) vs `integrity`
+  - restart causes by kind — `stall` (watchdog) vs `integrity`
     (requested by the IntegrityMonitor's escalation ladder via
     request_restart), with separate counters.
 

@@ -152,7 +152,9 @@ def main(argv: list[str] | None = None) -> int:
 
     # Opt-in latency smoke: the slow-marked express-lane wire-p99 test
     # (excluded from tier-1 by the `slow` marker). Runs in a subprocess
-    # so a hung serving loop can't wedge the gate.
+    # so a hung serving loop can't wedge the gate. Every child of this
+    # gate is a CPU check and is held to the CPU: this process may hold
+    # the chip, and a chip belongs to one process.
     latency_failures: list[str] = []
     if args.latency:
         import os
@@ -163,8 +165,7 @@ def main(argv: list[str] | None = None) -> int:
              "tests/test_latency_smoke.py", "-q", "-m", "slow",
              "-p", "no:cacheprovider"],
             cwd=REPO_ROOT, capture_output=True, text=True, timeout=300,
-            env={**os.environ, "JAX_PLATFORMS": os.environ.get(
-                "JAX_PLATFORMS", "cpu")},
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         if proc.returncode != 0:
             tail = "\n".join((proc.stdout or "").splitlines()[-15:])
@@ -184,8 +185,7 @@ def main(argv: list[str] | None = None) -> int:
             [sys.executable, "-m",
              "livekit_server_tpu.telemetry.trace_export", "--selftest"],
             cwd=REPO_ROOT, capture_output=True, text=True, timeout=300,
-            env={**os.environ, "JAX_PLATFORMS": os.environ.get(
-                "JAX_PLATFORMS", "cpu")},
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         if proc.returncode != 0:
             tail = "\n".join((proc.stdout or "").splitlines()[-15:])
@@ -207,8 +207,7 @@ def main(argv: list[str] | None = None) -> int:
             [sys.executable, "-m",
              "livekit_server_tpu.runtime.traffic_twin", "--smoke"],
             cwd=REPO_ROOT, capture_output=True, text=True, timeout=120,
-            env={**os.environ, "JAX_PLATFORMS": os.environ.get(
-                "JAX_PLATFORMS", "cpu")},
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         if proc.returncode != 0:
             tail = "\n".join((proc.stdout or "").splitlines()[-15:])
