@@ -1484,6 +1484,12 @@ def main() -> None:
         summary["skipped"] = sorted(RESULT["skipped"])
     sys.stdout.write(json.dumps(summary) + "\n")
     sys.stdout.flush()
+    # A section that raised is in the record above and in the exit code:
+    # the sections after it still ran, but the run did not succeed.
+    failed = sorted(k for k in RESULT if k.endswith("_error"))
+    if failed:
+        print(f"bench.py: sections failed: {failed}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

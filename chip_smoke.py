@@ -24,8 +24,10 @@ import argparse
 import asyncio
 import base64
 import json
+import selectors
 import shutil
 import socket
+import struct
 import sys
 import threading
 import time
@@ -37,12 +39,18 @@ VP8_PT, OPUS_PT = 96, 111
 # defaults with `plane.pager_enabled: true` (page 4×8, pool 1024).
 #
 # The ticks are what one Python process can hold with its own clients
-# beside it. At cfg4 width the idle loop alone costs 12-16 ms a tick on the
-# chip's host (stage + device call + fan-out, every room row staged and
-# unpacked whether live or not; my chip runs, PR 25): at the default 10 ms
-# the overload governor, rightly, refuses every join, and at 20 ms it sheds
-# as soon as media flows. PERF.md has the numbers, ROADMAP queue A the item.
-CFG4_TICK_MS, PAGED_TICK_MS = 40, 20
+# beside it (host clock on the chip's host; my chip runs, PR 25). At cfg4
+# width the idle loop alone costs 12-17 ms a tick (stage + device call +
+# fan-out: every room row is staged and unpacked whether live or not), so at
+# the default 10 ms the overload governor, rightly, refuses every join, and
+# at 20 ms it sheds as soon as media flows. The paged server's loaded tick
+# is 19-34 ms at 32 live rooms (device call 12-21 ms: the kernel and the
+# rest phase are two dispatches, each fetched through the GIL the event
+# loop is also using), so at 20 ms it climbs to level 4 and at 40 ms to
+# level 1 (work >= 0.85 of the tick). PERF.md has the numbers, ROADMAP
+# queue A the item.
+CFG4_TICK_MS, PAGED_TICK_MS = 40, 80
+SO_TIMESTAMPNS = 35    # Linux: the kernel stamps each datagram on arrival
 MEDIA_MS = 20      # one packet per track per 20 ms: 50 pkt/s, video and audio
 CFG4 = dict(rooms=1024, tracks_per_room=10, pkts_per_track=8, subs_per_room=10)
 SERVE_DEFAULT = dict(rooms=64, tracks_per_room=16, pkts_per_track=16,
@@ -145,16 +153,27 @@ def rtp_packet(pt: int, sn: int, ts: int, ssrc: int, video: bool) -> bytes:
 
 
 class MediaDrive:
-    """Publisher and subscriber sockets, each on a thread of its own so the
-    server's event loop is not the clients' clock."""
+    """Publisher and subscriber sockets, each side on a thread of its own so
+    the server's event loop is not the clients' clock. Subscribers share one
+    socket per room, as separate clients would each have their own: a whole
+    tick's egress on one socket is a burst of several hundred datagrams, and
+    what the kernel's receive buffer cannot hold it drops (counted below as
+    RcvbufErrors) — loss made by the smoke, not by the server."""
 
-    def __init__(self, udp_port: int):
+    def __init__(self, udp_port: int, n_rooms: int):
         self.dst = ("127.0.0.1", udp_port)
         self.pub = self._sock()
-        self.sub = self._sock()
-        self.sub.settimeout(0.05)
+        self.subs = [self._sock() for _ in range(n_rooms)]
+        for s in self.subs:
+            s.setsockopt(socket.SOL_SOCKET, SO_TIMESTAMPNS, 1)
+        self.rcvbuf = self.subs[0].getsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF)
+        self.sel = selectors.DefaultSelector()
+        for s in self.subs:
+            s.setblocking(False)
+            self.sel.register(s, selectors.EVENT_READ)
         self.frames: list[tuple] = []        # (key_id, sealed | None, opened | None)
         self.clients: dict[int, object] = {}     # key_id → MediaCryptoClient
+        self.sock_of: dict[int, socket.socket] = {}   # key_id → its room's socket
         self.fb_ssrc: dict[int, int] = {}        # key_id → an egress SSRC
         self._pending: dict[int, list] = {}      # key_id → [(ctr, recv_us)]
         self._stop = threading.Event()
@@ -173,6 +192,22 @@ class MediaDrive:
     def start(self) -> None:
         self._rx.start()
 
+    def ready(self, timeout: float):
+        """(datagram, arrival in µs) for everything waiting on a subscriber
+        socket. The arrival time is the kernel's, not this thread's: the
+        thread shares the GIL with the server it is driving and reads a
+        tick's burst milliseconds after it landed, and feedback stamped
+        with those times reads to the server's delay-based estimator as a
+        queue building on the path — it then pauses the video."""
+        for key, _ in self.sel.select(timeout):
+            while True:
+                try:
+                    data, anc, _, _ = key.fileobj.recvmsg(4096, 64)
+                except BlockingIOError:
+                    break
+                sec, nsec = struct.unpack("ll", anc[0][2])
+                yield data, sec * 1_000_000 + nsec // 1000
+
     def _recv_loop(self) -> None:
         """Drain egress; ack sealed-frame counters as transport-wide
         feedback every 100 ms, as a real client's congestion control does
@@ -181,14 +216,12 @@ class MediaDrive:
 
         last_fb = time.monotonic()
         while not self._stop.is_set():
-            try:
-                f = self.sub.recv(4096)
-            except socket.timeout:
-                f = None
-            if f is not None and len(f) > 14 and f[0] == 0x01:
+            for f, at_us in self.ready(0.05):
+                if len(f) <= 14 or f[0] != 0x01:
+                    continue
                 kid = int.from_bytes(f[1:5], "big")
                 self._pending.setdefault(kid, []).append(
-                    (int.from_bytes(f[6:14], "big"), time.monotonic_ns() // 1000)
+                    (int.from_bytes(f[6:14], "big"), at_us)
                 )
                 # Frames are opened after the drive (`opened()`), off the
                 # server's clock — all but each subscriber's first, whose
@@ -208,7 +241,7 @@ class MediaDrive:
                 for kid, ents in self._pending.items():
                     if ents and kid in self.fb_ssrc:
                         fb = build_twcc_feedback(0x42, self.fb_ssrc[kid], ents)
-                        self.sub.sendto(self.clients[kid].seal(fb), self.dst)
+                        self.sock_of[kid].sendto(self.clients[kid].seal(fb), self.dst)
                         ents.clear()
 
     def send_schedule(self, schedule: list[list[bytes]], tick_s: float) -> None:
@@ -253,8 +286,9 @@ class MediaDrive:
     def stop(self) -> None:
         self._stop.set()
         self._rx.join(timeout=5)
-        self.pub.close()
-        self.sub.close()
+        self.sel.close()
+        for s in (self.pub, *self.subs):
+            s.close()
 
     def opened(self):
         """(key_id, plaintext) of every frame received, in arrival order."""
@@ -278,6 +312,17 @@ def make_config(plane: dict, tick_ms: int):
             "require_encryption": True,      # AEAD on: the production wire
         },
     }))
+
+
+def udp_counters() -> dict[str, int]:
+    """This host's UDP counters (/proc/net/snmp): what the kernel dropped
+    for want of socket buffer is loss the loopback made, not the server."""
+    try:
+        names, values = [ln.split()[1:] for ln in open("/proc/net/snmp")
+                         if ln.startswith("Udp:")]
+    except (OSError, ValueError):
+        return {}
+    return dict(zip(names, map(int, values)))
 
 
 async def http_json(session, port: int, path: str) -> dict:
@@ -329,7 +374,8 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
         say(f"[{name}] runtime {type(runtime).__name__}, ragged kernel "
             f"{'on' if runtime._pk_enabled else 'off'}")
 
-    drive = MediaDrive(cfg.rtc.udp_port)
+    drive = MediaDrive(cfg.rtc.udp_port, live_rooms)
+    udp_before = udp_counters()
     async with aiohttp.ClientSession() as session:
         # -- the idle loop: what a tick costs before anyone has joined -----
         await asyncio.sleep(1.0)
@@ -344,6 +390,7 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
             for p in people:
                 await p.join()
                 drive.clients[p.crypto.key_id] = p.crypto
+                drive.sock_of[p.crypto.key_id] = drive.subs[r]
             cam = await people[0].publish("cam", video=True)
             mic = await people[1].publish("mic", video=False)
             for p, want in zip(people, ([mic], [cam], [cam, mic])):
@@ -354,22 +401,19 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
                 await p.send("subscription", {
                     "track_sids": p.subscribed, "subscribe": True, "udp": True})
                 punch = (await p.take("request_response", "udp_punch"))["udp_punch"]
-                drive.sub.sendto(
+                drive.subs[r].sendto(
                     p.crypto.seal(PUNCH_REQ + int(punch["punch_id"]).to_bytes(4, "big")),
                     drive.dst)
             rooms.append((people, cam["ssrc"], mic["ssrc"]))
         # every punch is acknowledged, sealed, on the subscriber socket
         acks, deadline = set(), time.monotonic() + 10
         while len(acks) < 3 * live_rooms and time.monotonic() < deadline:
-            try:
-                f = drive.sub.recv(4096)
-            except socket.timeout:
-                await asyncio.sleep(0.01)
-                continue
-            kid = int.from_bytes(f[1:5], "big")
-            inner = drive.clients[kid].open(f)
-            if inner is not None and inner[:8] == PUNCH_ACK:
-                acks.add(kid)
+            for f, _ in drive.ready(0):
+                kid = int.from_bytes(f[1:5], "big")
+                inner = drive.clients[kid].open(f)
+                if inner is not None and inner[:8] == PUNCH_ACK:
+                    acks.add(kid)
+            await asyncio.sleep(0.01)
         assert len(acks) == 3 * live_rooms, f"{len(acks)} punch acks"
         say(f"[{name}] joined {live_rooms} rooms x 3 participants over /rtc; "
             f"{2 * live_rooms} tracks published, {len(acks)} UDP subscribers latched")
@@ -415,6 +459,14 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
             for p in people:
                 await p.close()
     drive.stop()
+    udp_after = udp_counters()
+    kernel_drops = {k: udp_after[k] - udp_before[k]
+                    for k in ("RcvbufErrors", "SndbufErrors", "InErrors")
+                    if k in udp_after}
+    say(f"[{name}] kernel UDP drops on this host during the phase: "
+        f"{kernel_drops or 'not readable'}; subscriber receive buffer "
+        f"{drive.rcvbuf} bytes "
+        f"on each of {len(drive.subs)} sockets")
 
     pb, pa = before["plane"], after["plane"]
     d_ticks = pa["ticks"] - pb["ticks"]
@@ -422,6 +474,10 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
         f"fwd_packets {pa['fwd_packets']}, late_ticks {pa.get('late_ticks', 0)}, "
         f"ingest_dropped {after['ingest_dropped']}, governor level "
         f"{governor['level']} after {governor['transition_count']} transitions")
+
+    assert governor["level"] == 0 and governor["transition_count"] == 0, (
+        "the overload governor shed load during the drive (the tick is too "
+        f"short for this host): {governor['transitions']}")
 
     # -- reckoning -------------------------------------------------------------
     # Group what arrived by (subscriber key, egress SSRC): one munged SN
@@ -446,7 +502,8 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
         un = sorted(((sn - base + 0x8000) & 0xFFFF) - 0x8000 for sn in sns)
         assert un == list(range(un[0], un[0] + len(un))), (
             f"sub {kid:#x} ssrc {ssrc:#x}: SN space has gaps or duplicates "
-            f"({len(un)} packets over a span of {un[-1] - un[0] + 1})")
+            f"({len(un)} packets over a span of {un[-1] - un[0] + 1}); "
+            f"kernel UDP drops {kernel_drops}")
         media = [p for p in pkts if not p[2]]
         pad_rx += len(pkts) - len(media)
         media_rx += len(media)

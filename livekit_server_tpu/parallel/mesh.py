@@ -23,16 +23,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from livekit_server_tpu.analysis.registry import device_entry
 from livekit_server_tpu.models import plane
 
-# jax.shard_map (with check_vma) landed after 0.4.x; older versions ship
-# it under jax.experimental with the check_rep spelling of the same knob.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # pragma: no cover — exercised on older jax only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
 ROOM_AXIS = "rooms"
 
 
@@ -123,7 +113,7 @@ def make_sharded_tick(
 
     def pspecs(tree):
         return jax.tree.map(
-            lambda x: P() if jnp.asarray(x).ndim == 0 else P(ROOM_AXIS), tree
+            lambda x: P() if np.ndim(x) == 0 else P(ROOM_AXIS), tree
         )
 
     # shard_map, not bare GSPMD jit: the tick's hot kernels are Pallas
@@ -135,21 +125,24 @@ def make_sharded_tick(
     # insight, mapped to chips).
     cache: dict[str, Any] = {}
 
-    @functools.wraps(tick)
-    def compiled(state, inp):
+    def build(state, inp):
+        """The jitted shard_map, built once from the first call's tree
+        structure (arrays or abstract shapes)."""
         if "fn" not in cache:
-            in_specs = (pspecs(state), pspecs(inp))
-            out_shapes = jax.eval_shape(tick, state, inp)
-            out_specs = jax.tree.map(
-                lambda x: P() if x.ndim == 0 else P(ROOM_AXIS), out_shapes
-            )
-            smapped = _shard_map(
-                tick, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **_SHARD_MAP_KW,
+            shapes = jax.eval_shape(lambda s, i: (s, i), state, inp)
+            out_shapes = jax.eval_shape(tick, *shapes)
+            smapped = jax.shard_map(
+                tick, mesh=mesh, in_specs=pspecs(shapes),
+                out_specs=pspecs(out_shapes), check_vma=False,
             )
             cache["fn"] = jax.jit(
                 smapped, donate_argnums=(0,) if donate else ()
             )
-        return cache["fn"](state, inp)
+        return cache["fn"]
 
+    @functools.wraps(tick)
+    def compiled(state, inp):
+        return build(state, inp)(state, inp)
+
+    compiled.lower = lambda state, inp: build(state, inp).lower(state, inp)
     return compiled

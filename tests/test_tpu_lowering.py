@@ -190,6 +190,37 @@ def test_device_mixer_lowers(one_chip):
     assert "convolution" in text or "dot(" in text or "fusion" in text
 
 
+def test_sharded_tick_lowers_for_four_chips(topo, as_tpu):
+    """`mesh.make_sharded_tick` over the four described chips at 4 x cfg4
+    rooms (`chip_smoke.py --chips 4`): one kernel pair per shard, and no
+    collective anywhere in the program."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from livekit_server_tpu.analysis.devicecheck import _zero_inputs
+    from livekit_server_tpu.parallel.mesh import ROOM_AXIS, make_mesh, make_sharded_tick
+
+    d = plane.PlaneDims(4 * CFG4[0], *CFG4[1:])
+    mesh = make_mesh(topo.devices)
+    assert mesh.devices.size == 4
+
+    def on_mesh(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=NamedSharding(mesh, P(ROOM_AXIS) if x.ndim else P())),
+            tree,
+        )
+
+    state = on_mesh(jax.eval_shape(lambda: plane.init_state(d)))
+    inp = on_mesh(jax.eval_shape(lambda: _zero_inputs(d)))
+    compiled = make_sharded_tick(mesh).lower(state, inp).compile()
+    text = compiled.as_text()
+    assert _custom_calls(compiled) == 2
+    for collective in ("all-reduce", "all-gather", "all-to-all",
+                       "collective-permute", "reduce-scatter"):
+        assert collective not in text, collective
+
+
 # -- the ragged paged kernel (ops/paged_kernel.py) ---------------------------
 
 def _paged_dims(which: str):
