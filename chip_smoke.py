@@ -38,20 +38,21 @@ VP8_PT, OPUS_PT = 96, 111
 # Dense phase: BASELINE.json cfg4 width. Paged phase: the `serve`
 # defaults with `plane.pager_enabled: true` (page 4×8, pool 1024).
 #
-# The ticks are what one Python process can hold with its own clients
-# beside it (host clock on the chip's host; my chip runs, PR 25). At cfg4
-# width the idle loop alone costs 12-17 ms a tick (stage + device call +
-# fan-out: every room row is staged and unpacked whether live or not), so at
-# the default 10 ms the overload governor, rightly, refuses every join, and
-# at 20 ms it sheds as soon as media flows. The paged server's loaded tick
-# is 19-34 ms at 32 live rooms (device call 12-21 ms: the kernel and the
-# rest phase are two dispatches, each fetched through the GIL the event
-# loop is also using), so at 20 ms it climbs to level 4 and at 40 ms to
-# level 1 (work >= 0.85 of the tick). PERF.md has the numbers, ROADMAP
-# queue A the item.
-CFG4_TICK_MS, PAGED_TICK_MS = 40, 80
-SO_TIMESTAMPNS = 35    # Linux: the kernel stamps each datagram on arrival
-MEDIA_MS = 20      # one packet per track per 20 ms: 50 pkt/s, video and audio
+# The tick and the packet rate are what one Python process can hold with
+# its own clients beside it, with room to spare: the smoke must not fail on
+# a slow stretch of a shared host (host clock on the chip's host; my chip
+# runs, PR 25). At cfg4 width the idle loop alone costs 12-17 ms a tick
+# (stage + device call + fan-out: every room row is staged and unpacked
+# whether live or not), and the supervisor's 2 s checkpoint holds the event
+# loop ~100 ms: at the default 10 ms the overload governor, rightly, refuses
+# every join, at 20 ms it sheds as soon as media flows, and at 40 ms each
+# checkpoint makes 8 ticks late, 20 in a row being the governor's trigger.
+# The paged server's loaded tick is 19-34 ms at 32 live rooms: level 4 at
+# 20 ms, level 1 at 40 ms. PERF.md has the numbers, ROADMAP queue A the item.
+TICK_MS = 80
+MEDIA_MS = 40      # one packet per track per 40 ms: 25 pkt/s, video and audio
+# Linux: the kernel stamps each datagram on arrival (ns, else µs resolution)
+ARRIVAL_STAMPS = {35: ("SO_TIMESTAMPNS", 1000), 29: ("SO_TIMESTAMP", 1)}
 CFG4 = dict(rooms=1024, tracks_per_room=10, pkts_per_track=8, subs_per_room=10)
 SERVE_DEFAULT = dict(rooms=64, tracks_per_room=16, pkts_per_track=16,
                      subs_per_room=32)
@@ -164,8 +165,10 @@ class MediaDrive:
         self.dst = ("127.0.0.1", udp_port)
         self.pub = self._sock()
         self.subs = [self._sock() for _ in range(n_rooms)]
+        self.stamp_opt = self._probe_arrival_stamps()
         for s in self.subs:
-            s.setsockopt(socket.SOL_SOCKET, SO_TIMESTAMPNS, 1)
+            if self.stamp_opt:
+                s.setsockopt(socket.SOL_SOCKET, self.stamp_opt, 1)
         self.rcvbuf = self.subs[0].getsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF)
         self.sel = selectors.DefaultSelector()
         for s in self.subs:
@@ -189,24 +192,48 @@ class MediaDrive:
         s.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 8 << 20)
         return s
 
+    @classmethod
+    def _probe_arrival_stamps(cls) -> int | None:
+        """The socket option under which this kernel hands back an arrival
+        time with a datagram; None where it offers neither."""
+        for opt in ARRIVAL_STAMPS:
+            s = cls._sock()
+            try:
+                s.setsockopt(socket.SOL_SOCKET, opt, 1)
+                s.sendto(b"x", s.getsockname())
+                s.settimeout(1.0)
+                anc = s.recvmsg(16, 64)[1]
+                if anc and anc[0][1] == opt:
+                    return opt
+            except OSError:
+                pass
+            finally:
+                s.close()
+        return None
+
     def start(self) -> None:
         self._rx.start()
 
     def ready(self, timeout: float):
         """(datagram, arrival in µs) for everything waiting on a subscriber
-        socket. The arrival time is the kernel's, not this thread's: the
-        thread shares the GIL with the server it is driving and reads a
-        tick's burst milliseconds after it landed, and feedback stamped
-        with those times reads to the server's delay-based estimator as a
-        queue building on the path — it then pauses the video."""
+        socket. The arrival time is the kernel's where it gives one, not
+        this thread's: the thread shares the GIL with the server it is
+        driving and reads a tick's burst milliseconds after it landed, and
+        feedback stamped with those times reads to the server's delay-based
+        estimator as a queue building on the path — it then pauses the
+        video (seen here on the CPU and on the chip; PERF.md, PR 25)."""
+        per_unit = ARRIVAL_STAMPS[self.stamp_opt][1] if self.stamp_opt else 0
         for key, _ in self.sel.select(timeout):
             while True:
                 try:
                     data, anc, _, _ = key.fileobj.recvmsg(4096, 64)
                 except BlockingIOError:
                     break
-                sec, nsec = struct.unpack("ll", anc[0][2])
-                yield data, sec * 1_000_000 + nsec // 1000
+                if anc:
+                    sec, frac = struct.unpack("ll", anc[0][2])
+                    yield data, sec * 1_000_000 + frac // per_unit
+                else:
+                    yield data, time.time_ns() // 1000
 
     def _recv_loop(self) -> None:
         """Drain egress; ack sealed-frame counters as transport-wide
@@ -250,7 +277,7 @@ class MediaDrive:
 
         An interval's datagrams leave in one sendmmsg (the native library's
         `send_raw`), so they reach the server as one receive batch. Sent one
-        by one they arrive as 3,200 wake-ups a second, and the server's
+        by one they arrive as thousands of wake-ups a second, and the server's
         receive path costs about as much for one datagram as for a batch
         (1.7 ms a call here; my CPU profile, PR 25): the rx path alone then
         takes more than the whole tick."""
@@ -419,7 +446,7 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
             f"{2 * live_rooms} tracks published, {len(acks)} UDP subscribers latched")
 
         # -- media: sealed ahead of time, sent on the publisher thread -----
-        # video 50 pkt/s of 907 B, audio 50 pkt/s of 80 B per track: a rate
+        # video and audio each 1000/MEDIA_MS pkt/s per track, 907 B and 80 B: a rate
         # one Python process can send and receive beside the server it is
         # driving (sealed before the drive, opened after it).
         per_track_lead = lead_ticks * tick_ms // MEDIA_MS
@@ -466,7 +493,9 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
     say(f"[{name}] kernel UDP drops on this host during the phase: "
         f"{kernel_drops or 'not readable'}; subscriber receive buffer "
         f"{drive.rcvbuf} bytes "
-        f"on each of {len(drive.subs)} sockets")
+        f"on each of {len(drive.subs)} sockets; arrivals stamped by "
+        + (f"the kernel ({ARRIVAL_STAMPS[drive.stamp_opt][0]})" if drive.stamp_opt
+           else "the receiving thread (this kernel offers no arrival stamp)"))
 
     pb, pa = before["plane"], after["plane"]
     d_ticks = pa["ticks"] - pb["ticks"]
@@ -796,11 +825,11 @@ def main(argv: list[str] | None = None) -> int:
         size = dict(live_rooms=3, lead_ticks=40, ticks=60) if toy else dict(
             live_rooms=32, lead_ticks=50, ticks=320)
         asyncio.run(served_phase(
-            "dense", TOY if toy else CFG4, tick_ms=CFG4_TICK_MS, **size))
+            "dense", TOY if toy else CFG4, tick_ms=TICK_MS, **size))
         paged_plane = dict(TOY, pager_tpage=2, pager_spage=2) if toy else SERVE_DEFAULT
         asyncio.run(served_phase(
             "paged", dict(paged_plane, pager_enabled=True),
-            tick_ms=PAGED_TICK_MS, **size))
+            tick_ms=TICK_MS, **size))
         paged_kernel_comparison(args.seed, toy)
 
     print(json.dumps({"ok": True, "device": device}), flush=True)

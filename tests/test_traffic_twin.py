@@ -1,8 +1,6 @@
 """Traffic twin (runtime/traffic_twin.py): scenario DSL validation, the
 byte-identical-timeline determinism contract, a full same-seed replay
-equivalence check, the twin.* config knobs, and the bench last-line-JSON
-absorption contract shared by the wire twin and fleet_twin sections."""
-
+equivalence check, and the twin.* config knobs."""
 
 import pytest
 
