@@ -74,8 +74,6 @@ class LiveDecide(NamedTuple):
     tr: Any                  # [NL, 3, TP*L] int32 | None
 
 
-
-
 def _page_kernel(*refs, TP: int, K: int, SP: int, L: int,
                  wire_overhead: int, top_k: int,
                  with_decide: bool, with_mix: bool):

@@ -54,4 +54,3 @@ def ema_dyadic(prev: jnp.ndarray, x: jnp.ndarray, shift: int) -> jnp.ndarray:
     dense/paged/fused bit-parity chain on every EMA'd state leaf.
     """
     return prev + (x - prev) * (2.0 ** -shift)
-

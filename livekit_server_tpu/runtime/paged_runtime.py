@@ -59,8 +59,8 @@ from livekit_server_tpu.runtime.pager import RoomPager
 from livekit_server_tpu.runtime.plane_runtime import (
     PlaneRuntime,
     _build_ctrl_delta,
-    _build_row_read,
     _build_row_write,
+    _pow2_buckets,
 )
 from livekit_server_tpu.runtime.slots import PagedSlotAllocator
 
@@ -532,25 +532,16 @@ class PagedPlaneRuntime(PlaneRuntime):
         self._pooled_fill()
         d, pg = self.pdims, self.pager
         P, R = d.pool_pages, d.rooms
-        buckets = lambda top: [1 << i for i in range((top - 1).bit_length() + 1)]  # noqa: E731
-        page0 = jax.tree.map(
-            np.asarray, _build_row_read()(self.state, np.int32(0))
-        )
-        meta0 = np.stack([np.asarray(m, np.int32) for m in page0.meta])
-        ctrl0 = np.stack([np.asarray(c, np.int32) for c in page0.ctrl])
-        for n in buckets(P):
+        self._warm_ctrl_delta(_pow2_buckets(P))
+        for n in _pow2_buckets(P):
             rows = np.zeros(n, np.int32)
-            self.state = self._apply_delta(
-                self.state, rows, np.repeat(meta0[:, None], n, axis=1),
-                np.repeat(ctrl0[:, None], n, axis=1),
-            )
             self.state = self._reinit(
                 self.state, jnp.asarray(rows), self._page_template
             )
             self.state = self._move(
                 self.state, jnp.asarray(rows), jnp.asarray(rows)
             )
-            for m in buckets(R):
+            for m in _pow2_buckets(R):
                 rrows = np.zeros(m, np.int32)
                 self.table = self._table_delta(
                     self.table, rows, pg.tmembers[rows], pg.pg_room[rows],
@@ -569,7 +560,7 @@ class PagedPlaneRuntime(PlaneRuntime):
             keep = (self._live_rows, self._live_inv, self._kernel_s_scratch,
                     self._kernel_steps_scratch)
             self._live_inv = np.zeros(P, np.int32)
-            for n in buckets(P):
+            for n in _pow2_buckets(P):
                 self._live_rows = np.zeros(n, np.int32)
                 scratch = jax.tree.map(jnp.copy, self.state)
                 jax.block_until_ready(self._live_step(scratch, *packed))

@@ -294,6 +294,12 @@ def _build_row_read():
     return jax.jit(lambda state, row: jax.tree.map(lambda x: x[row], state))
 
 
+def _pow2_buckets(top: int) -> list[int]:
+    """1, 2, 4, ... up to the first power of two >= top: the row counts
+    the bucketed scatters are compiled at."""
+    return [1 << i for i in range(max(top - 1, 0).bit_length() + 1)]
+
+
 @dataclass
 class StagedTick:
     """One tick's host-staged inputs, carried through the three-stage
@@ -1554,6 +1560,22 @@ class PlaneRuntime:
         ])
         self.state = self._row_write(self.state, np.int32(row), row_tree)
 
+    def _warm_ctrl_delta(self, buckets):
+        """Run the dirty-row control scatter once per row-count bucket,
+        writing row 0's own values back; returns row 0 as host arrays."""
+        row0 = jax.tree.map(
+            np.asarray, _build_row_read()(self.state, np.int32(0))
+        )
+        meta0 = np.stack([np.asarray(m, np.int32) for m in row0.meta])
+        ctrl0 = np.stack([np.asarray(c, np.int32) for c in row0.ctrl])
+        for n in buckets:
+            self.state = self._apply_delta(
+                self.state, np.zeros(n, np.int32),
+                np.repeat(meta0[:, None], n, axis=1),
+                np.repeat(ctrl0[:, None], n, axis=1),
+            )
+        return row0
+
     def warm_compile(self) -> None:
         """Compile, inside the warm-up window, the programs whose first
         use would otherwise fall in steady state: the dirty-row control
@@ -1563,19 +1585,7 @@ class PlaneRuntime:
         adoption would outlast its ACK timeout). Each runs on the live
         state with the values already there, so the state is unchanged.
         Callers hold state_lock (GC01)."""
-        row0 = jax.tree.map(
-            np.asarray, _build_row_read()(self.state, np.int32(0))
-        )
-        meta0 = np.stack([np.asarray(m, np.int32) for m in row0.meta])
-        ctrl0 = np.stack([np.asarray(c, np.int32) for c in row0.ctrl])
-        n = 1
-        while n < 2 * self.ctrl_delta_max_rows:
-            self.state = self._apply_delta(
-                self.state, np.zeros(n, np.int32),
-                np.repeat(meta0[:, None], n, axis=1),
-                np.repeat(ctrl0[:, None], n, axis=1),
-            )
-            n *= 2
+        row0 = self._warm_ctrl_delta(_pow2_buckets(self.ctrl_delta_max_rows))
         flat, treedef = jax.tree.flatten(self.state)
         self._write_row(0, flat, treedef, jax.tree.leaves(row0))
 
