@@ -340,7 +340,10 @@ async def test_two_phase_migration_under_load_over_bus():
                 f"lost={sorted(set(sent) - set(got))[:10]} "
                 f"dup={sorted(sn for sn in set(got) if got.count(sn) > 1)[:10]}"
             )
-            assert len(got) > 60, "pump never reached the plane"
+            # Media flowed on both sides of the cutover. How many packets
+            # the 4 ms pump gets in depends on the host's load alone (95
+            # alone, 45 beside five other workers), so the floor is low.
+            assert len(got) > 20, "pump never reached the plane"
             # The lane continued — target's last SN is the last one sent.
             row_b = rm_b.rooms["live"].slots.row
             assert int(rt_b.munger.last_sn[row_b, 0, 1]) == sent[-1]

@@ -107,7 +107,7 @@ def test_served_step_lowers(one_chip, as_tpu, dims):
 # 256 rooms make that two grid steps.
 ROOM_BLOCK_CASES = {
     "whole_array": dict(R=100, T=10, K=8, S=10),
-    "over_budget": dict(R=256, T=16, K=16, S=64),
+    "over_budget": dict(R=256, T=10, K=8, S=80),
 }
 
 
