@@ -1,7 +1,7 @@
 """Flight-recorder tracing plane: tick spans, sampled wire-latency
 attribution, and per-room black-box event rings.
 
-Every diagnosis so far (late-tick causes, the egress wall, the BENCH_r07
+Every diagnosis before it (late-tick causes, the egress wall, the
 wire-p99 floor analysis) was reconstructed by hand from scattered
 `recent_ticks` fields and bench printouts. This module turns that into a
 standing capability with a hard overhead budget — everything on the

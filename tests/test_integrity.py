@@ -328,8 +328,9 @@ async def test_repair_storm_escalates_to_supervisor_restart_once():
         assert mon.escalations == 1
         assert not mon.quarantined            # on_full_restore cleared it
         from livekit_server_tpu.ops import bwe
-        assert int(np.asarray(rt.state.bwe_state.ring_pos).max()) \
-            < bwe.WINDOW                      # restored state is clean
+        async with rt.state_lock:             # the running tick donates it
+            ring_max = int(np.asarray(rt.state.bwe_state.ring_pos).max())
+        assert ring_max < bwe.WINDOW          # restored state is clean
         assert not sup.gave_up
     finally:
         await sup.stop()
