@@ -542,7 +542,7 @@ class Pool {
   void run(PlaneJob& job) {
     std::unique_lock<std::mutex> lk(mu_);
     job_ = &job;
-    next_.store(0, std::memory_order_relaxed);
+    next_ = 0;
     done_ = 0;
     gen_++;
     cv_.notify_all();
@@ -571,8 +571,18 @@ class Pool {
         job = *job_;
       }
       for (;;) {
-        int s = next_.fetch_add(1, std::memory_order_relaxed);
-        if (s >= job.n_shards) break;
+        // Claim under the lock, and only from the generation this copy
+        // of the job belongs to: a worker that finished its last shard
+        // and was descheduled before coming back here would otherwise
+        // take shard 0 of the NEXT job (run() has reset next_) with the
+        // previous job's pointers — that shard is then never built and
+        // the previous caller's freed arrays are written.
+        int s;
+        {
+          std::unique_lock<std::mutex> lk(mu_);
+          if (gen_ != seen || next_ >= job.n_shards) break;
+          s = next_++;
+        }
         const int64_t t0 = now_ns();
         int64_t built = 0;
         int64_t sent = worker(*job.a, (int)job.shard_lo[s],
@@ -596,7 +606,7 @@ class Pool {
   bool stop_ = false;
   PlaneJob* job_ = nullptr;
   int done_ = 0;
-  std::atomic<int> next_{0};
+  int next_ = 0;   // next unclaimed shard of job_ (guarded by mu_)
 };
 
 Pool g_pool;
