@@ -51,6 +51,19 @@ VP8_PT, OPUS_PT = 96, 111
 # 20 ms, level 1 at 40 ms. PERF.md has the numbers, ROADMAP queue A the item.
 TICK_MS = 80
 MEDIA_MS = 40      # one packet per track per 40 ms: 25 pkt/s, video and audio
+# Video packets are small on purpose: 169 B at 25 pkt/s is 34 kbit/s, so a
+# subscriber's whole demand stays under the floor (64 kbit/s) of the server's
+# delay-based bandwidth estimator. That estimator stamps a packet's send time
+# before the fan-out's Python work and the native seal and send; when this
+# host stalls for 30 ms or more between the stamp and the wire, the feedback
+# reads as a queue building, the budget drops to 0.85 x the acked rate, and
+# a video that no longer fits is paused for seconds (call 21 on the chip:
+# 907 B video, a fifth of the video window not forwarded, every SN space
+# still gap-free; reproduced on the CPU with 30 ms added to the arrival
+# stamps for 0.3 s, and not with 150 B video even at 200 ms for 1 s). A
+# pause the server chooses is not loss, but it makes the packet count depend
+# on the host's jitter, and this check must not. PERF.md, PR 25.
+VIDEO_PAYLOAD = 150
 # Linux: the kernel stamps each datagram on arrival (ns, else µs resolution)
 ARRIVAL_STAMPS = {35: ("SO_TIMESTAMPNS", 1000), 29: ("SO_TIMESTAMP", 1)}
 CFG4 = dict(rooms=1024, tracks_per_room=10, pkts_per_track=8, subs_per_room=10)
@@ -147,7 +160,7 @@ def rtp_packet(pt: int, sn: int, ts: int, ssrc: int, video: bool) -> bytes:
         # subscriber can lock on at any packet.
         pid = sn & 0x7FFF
         payload = bytes([0x90, 0xE0, 0x80 | (pid >> 8), pid & 0xFF,
-                         sn & 0xFF, 0x20, 0x00]) + bytes(900)
+                         sn & 0xFF, 0x20, 0x00]) + bytes(VIDEO_PAYLOAD)
     else:
         payload = bytes(80)                  # a 20 ms Opus frame's worth
     return bytes(hdr) + payload
@@ -446,9 +459,9 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
             f"{2 * live_rooms} tracks published, {len(acks)} UDP subscribers latched")
 
         # -- media: sealed ahead of time, sent on the publisher thread -----
-        # video and audio each 1000/MEDIA_MS pkt/s per track, 907 B and 80 B: a rate
-        # one Python process can send and receive beside the server it is
-        # driving (sealed before the drive, opened after it).
+        # video and audio each 1000/MEDIA_MS pkt/s per track, 157 B and 80 B
+        # of payload: a rate one Python process can send and receive beside
+        # the server it is driving (sealed before the drive, opened after it).
         per_track_lead = lead_ticks * tick_ms // MEDIA_MS
         per_track = ticks * tick_ms // MEDIA_MS
         n_total = per_track_lead + per_track
@@ -462,7 +475,9 @@ async def served_phase(name: str, plane: dict, *, tick_ms: int, live_rooms: int,
                     schedule[i].append(seal(rtp_packet(
                         VP8_PT if video else OPUS_PT, sn, ts, ssrc, video)))
         pps_in = 2 * live_rooms * 1000 // MEDIA_MS
-        say(f"[{name}] offered {pps_in} pkt/s in, {2 * pps_in} pkt/s out expected; "
+        say(f"[{name}] offered {pps_in} pkt/s in (RTP packets of "
+            f"{12 + 7 + VIDEO_PAYLOAD} B video, {12 + 80} B audio), "
+            f"{2 * pps_in} pkt/s out expected; "
             f"lead-in {lead_ticks} ticks, checked window {ticks} ticks")
 
         before = await http_json(session, cfg.port, "/debug/rooms")
