@@ -386,7 +386,7 @@ def _room_tick(
     )
     vbytes = jnp.where(inp.valid, inp.size, 0).astype(jnp.float32)
     tick_bytes_lt = jnp.einsum("tk,tkl,tkm->tlm", vbytes, layer_oh, tm_oh)  # [T,L,4]
-    temporal_bytes = scanops.ema_dyadic(state.temporal_bytes, tick_bytes_lt, 3)
+    temporal_bytes = state.temporal_bytes * 0.9 + tick_bytes_lt * 0.1
     tick_s = jnp.maximum(inp.tick_ms.astype(jnp.float32), 1.0) / 1000.0
     # Layer bitrate: tracker cycles once committed; per-tick EMA bootstraps
     # the first cycle so allocation starts on the first packets. SVC tracks

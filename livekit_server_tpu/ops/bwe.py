@@ -25,8 +25,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from livekit_server_tpu.ops import scanops
-
 WINDOW = 8  # estimate samples per trend window (trenddetector RequiredSamples)
 
 
@@ -192,7 +190,7 @@ def _scatter_ring(ring: jax.Array, pos: jax.Array, value: jax.Array) -> jax.Arra
 class DelayBWEParams(NamedTuple):
     overuse_ms: float = 1.5        # EMA'd delay-variation above ⇒ overuse
     underuse_ms: float = -1.5      # below ⇒ draining; hold rate
-    ema_shift: int = 2             # EMA weight 2**-2 (scanops.ema_dyadic)
+    ema_alpha: float = 0.3
     beta: float = 0.85             # overuse: rate = beta × acked receive rate
     increase_per_s: float = 0.08   # multiplicative increase while clear
     min_rate_bps: float = 64_000.0
@@ -238,7 +236,7 @@ def delay_update_tick(
     """
     ema = jnp.where(
         fb_valid,
-        scanops.ema_dyadic(state.slope_ema, fb_delay_ms, params.ema_shift),
+        (1.0 - params.ema_alpha) * state.slope_ema + params.ema_alpha * fb_delay_ms,
         state.slope_ema,
     )
     overuse = ema > params.overuse_ms

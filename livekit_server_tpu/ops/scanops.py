@@ -42,15 +42,3 @@ def cumsum_small(x: jnp.ndarray, axis: int) -> jnp.ndarray:
         )
     return jnp.moveaxis(ym, -1, axis)
 
-
-def ema_dyadic(prev: jnp.ndarray, x: jnp.ndarray, shift: int) -> jnp.ndarray:
-    """EMA step with weight 2**-shift, in the difference form
-    prev + (x - prev) * 2**-shift.
-
-    The product is exact (a power-of-two scale), so the result is the
-    same whether or not a backend contracts the mul+add into an FMA.
-    `prev * (1 - a) + x * a` is not: XLA:CPU contracted it in the paged
-    graphs and not in the dense one, 1 ulp apart, which broke the
-    dense/paged/fused bit-parity chain on every EMA'd state leaf.
-    """
-    return prev + (x - prev) * (2.0 ** -shift)

@@ -212,6 +212,16 @@ def build_nack(sender_ssrc: int, media_ssrc: int, sns) -> bytes:
     )
 
 
+def _wire_ms() -> float:
+    """Send time for the transport-wide feedback matcher, read where the
+    datagrams are handed to the native seal-and-send, not where the tick's
+    fan-out began: the delay estimator compares gaps between these stamps
+    with gaps between the receiver's arrival times, so host work between a
+    stamp and the wire (sorting, counters, extensions, a GIL hand-off) reads
+    as a queue building on the path and pauses the subscriber's video."""
+    return time.monotonic() * 1000.0
+
+
 def build_twcc_feedback(
     sender_ssrc: int, media_ssrc: int, entries: list[tuple[int, int]]
 ) -> bytes:
@@ -2046,6 +2056,7 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
             seal = np.zeros(len(idx), bool)
         key_idx = np.where(seal, e_sess, -1).astype(np.int32)
         ctr = np.zeros(len(idx), np.uint64)
+        twcc_slots = None
         if seal.any():
             sealed_pos = np.nonzero(seal)[0]
             es = e_sess[sealed_pos]
@@ -2062,11 +2073,11 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
             ctr[sealed_pos] = base[es] + ranks.astype(np.uint64)
             sp_r, sp_s = rr_[sealed_pos], ss_[sealed_pos]
             sp_slot = (ctr[sealed_pos] & np.uint64(TWCC_RING - 1)).astype(np.int64)
-            self._twcc_ms[sp_r, sp_s, sp_slot] = now_ms
             self._twcc_ctr[sp_r, sp_s, sp_slot] = ctr[sealed_pos].astype(np.int64)
             self._twcc_len[sp_r, sp_s, sp_slot] = (
                 cols.pay_len[idx][sealed_pos] + WIRE_OVERHEAD_BYTES
             )
+            twcc_slots = (sp_r, sp_s, sp_slot)
         keys = self._sess_keys if n_sess else np.zeros((1, 16), np.uint8)
         key_ids = self._sess_keyids if n_sess else np.zeros(1, np.uint32)
         # Header extensions: playout-delay only (one shared 3-byte
@@ -2084,6 +2095,8 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
                 ext_off = np.zeros(len(idx), np.int64)
                 ext_len = np.where(is_vid, len(sec), 0).astype(np.int32)
         fd = self.transport.get_extra_info("socket").fileno()
+        if twcc_slots is not None:
+            self._twcc_ms[twcc_slots] = _wire_ms()
         _t_send0 = time.perf_counter()
         _, _, _, sent, _ = native_egress.send_express(
             fd=fd, slab=cols.slab,
@@ -2232,6 +2245,7 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
                 seal = np.zeros(len(idx), bool)
             key_idx = np.where(seal, e_sess, -1).astype(np.int32)
             ctr = np.zeros(len(idx), np.uint64)
+            twcc_slots = None
             if seal.any():
                 # Allocate each session a contiguous counter block for this
                 # batch, fully vectorized over the shared counter array
@@ -2251,15 +2265,16 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
                 ranks[order] = np.arange(len(es)) - np.repeat(grp_start, sizes)
                 ctr[sealed_pos] = base[es] + ranks.astype(np.uint64)
                 # TWCC send-time ring: every sealed datagram's counter is
-                # its transport-wide sequence number — record send time +
-                # wire size for the feedback matcher (_handle_twcc).
+                # its transport-wide sequence number — record wire size
+                # here and the send time in do_send, at the native call
+                # (_handle_twcc matches feedback against both).
                 sp_r, sp_s = rr_[sealed_pos], ss_[sealed_pos]
                 sp_slot = (ctr[sealed_pos] & np.uint64(TWCC_RING - 1)).astype(np.int64)
-                self._twcc_ms[sp_r, sp_s, sp_slot] = now_ms
                 self._twcc_ctr[sp_r, sp_s, sp_slot] = ctr[sealed_pos].astype(np.int64)
                 self._twcc_len[sp_r, sp_s, sp_slot] = (
                     pl[idx][sealed_pos] + WIRE_OVERHEAD_BYTES
                 )
+                twcc_slots = (sp_r, sp_s, sp_slot)
             keys = self._sess_keys if n_sess else np.zeros((1, 16), np.uint8)
             key_ids = self._sess_keyids if n_sess else np.zeros(1, np.uint32)
             ext_blob, ext_off, ext_len = b"", None, None
@@ -2332,7 +2347,10 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
             def do_send(args=send_args, n_entries=n_entries, t_arr=t_arr,
                         sn_s=batch.sn[idx], ws=self.wire_stages,
                         t_disp=getattr(batch, "t_dispatch", 0.0),
-                        t_dev=getattr(batch, "t_device_end", 0.0)):
+                        t_dev=getattr(batch, "t_device_end", 0.0),
+                        twcc_slots=twcc_slots):
+                if twcc_slots is not None:
+                    self._twcc_ms[twcc_slots] = _wire_ms()
                 if use_plane:
                     (_, _, _, sent, sh_sent, sh_built,
                      sh_ns) = native_egress.send_sharded(**args)

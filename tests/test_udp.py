@@ -7,6 +7,7 @@ Reference parity: the media half of test/singlenode_test.go TestSinglePublisher
 
 import asyncio
 import socket
+import time
 
 import numpy as np
 
@@ -1125,6 +1126,96 @@ async def test_twcc_feedback_caps_allocation_budget():
         # Default (no estimate, no feedback) budget is the 7 Mbps initial;
         # measured congestion must have collapsed it.
         assert committed < 1_000_000.0, committed
+        sub.close()
+    finally:
+        tr.close()
+        await runtime.stop()
+
+
+async def test_host_stall_before_the_wire_is_not_read_as_a_queue():
+    """The send time the feedback matcher keeps is read at the native
+    seal-and-send, not where the fan-out began: host work of 0 or 25 ms
+    between the two (here: inside the socket lookup that precedes the send)
+    with an honest, uncongested receiver must leave the budget alone. With
+    the stamp taken at the top of the fan-out the same run reads as a queue
+    and the delay estimator collapses the budget (chip call 21, PR 25)."""
+    from livekit_server_tpu.runtime.crypto import (
+        MediaCryptoClient,
+        MediaCryptoRegistry,
+        parse_counter,
+    )
+    from livekit_server_tpu.runtime.udp import (
+        UDPMediaTransport,
+        build_twcc_feedback,
+    )
+    from livekit_server_tpu.runtime.ingest import PacketIn
+    from tests.conftest import free_port
+
+    runtime = PlaneRuntime(DIMS, tick_ms=10)
+    reg = MediaCryptoRegistry()
+    port = free_port(socket.SOCK_DGRAM)
+    loop = asyncio.get_running_loop()
+    tr, transport = await loop.create_datagram_endpoint(
+        lambda: UDPMediaTransport(runtime.ingest, crypto=reg, require_encryption=True),
+        local_addr=("127.0.0.1", port),
+    )
+
+    class StallingSocketLookup:
+        """The asyncio transport, with a host stall in the last Python step
+        before the datagrams are handed to the native sender."""
+        stall_s = 0.0
+
+        def get_extra_info(self, name):
+            time.sleep(self.stall_s)
+            return tr.get_extra_info(name)
+
+        def __getattr__(self, name):
+            return getattr(tr, name)
+
+    try:
+        runtime.set_track(0, 0, published=True, is_video=False)
+        runtime.set_subscription(0, 0, 1, subscribed=True)
+        sub_sess = reg.mint()
+        transport.bind_sub_session(0, 1, sub_sess)
+        bob = MediaCryptoClient(sub_sess.key_id, sub_sess.key)
+        sub = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+        sub.bind(("127.0.0.1", 0))
+        sub.settimeout(1.0)
+        transport.register_subscriber(0, 1, sub.getsockname())
+        media_ssrc = transport.subscriber_ssrc(0, 1, 0)
+        stalling = transport.transport = StallingSocketLookup()
+
+        for i in range(40):
+            runtime.ingest.push(PacketIn(
+                room=0, track=0, sn=100 + i, ts=960 * i, size=120,
+                payload=b"y" * 120,
+            ))
+            res = await runtime.step_once()
+            stalling.stall_s = 0.025 * (i % 2)
+            transport.send_egress_batch(res.egress_batch)
+            # An honest receiver on an empty path: the arrival time is the
+            # moment the datagram can be read, right after the send.
+            # (A sender report may ride along; its counter is acked too and
+            # matches nothing in the ring.)
+            entries = []
+            sub.settimeout(1.0)
+            while True:
+                try:
+                    f = sub.recvfrom(4096)[0]
+                except (BlockingIOError, TimeoutError):
+                    break
+                at_us = time.monotonic_ns() // 1000
+                c = parse_counter(f)
+                assert c is not None and bob.open(f) is not None
+                entries.append((c, at_us))
+                sub.setblocking(False)
+            assert entries, i
+            fb = build_twcc_feedback(0xB0B, media_ssrc, entries)
+            sub.sendto(bob.seal(fb), ("127.0.0.1", port))
+            await asyncio.sleep(0.01)
+        assert transport.stats.get("twcc_rx", 0) >= 30
+        committed = float(runtime._last_committed[0, 1])
+        assert committed > 1_000_000.0, committed
         sub.close()
     finally:
         tr.close()
