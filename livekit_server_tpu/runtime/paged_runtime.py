@@ -60,7 +60,8 @@ from livekit_server_tpu.runtime.plane_runtime import (
     PlaneRuntime,
     _build_ctrl_delta,
     _build_row_write,
-    _pow2_buckets,
+    _row_bucket,
+    _row_buckets,
 )
 from livekit_server_tpu.runtime.slots import PagedSlotAllocator
 
@@ -160,11 +161,6 @@ def _build_moves():
     return jax.jit(paged.move_state_rows, donate_argnums=(0,))
 
 
-def _p2(n: int) -> int:
-    """Pow2 padding bucket so the row scatters compile once per bucket."""
-    return 1 << (n - 1).bit_length() if n > 1 else 1
-
-
 def _pad_rows(to: int, *arrays):
     """Pad each array's leading axis to `to` by repeating row 0
     (duplicate scatter indices carry identical values)."""
@@ -239,13 +235,26 @@ class PagedPlaneRuntime(PlaneRuntime):
         # Live-row cache for the kernel grid and the live-fraction gauge:
         # derived from `_dev_tables` (the device table as of the last
         # page sync), refreshed by `_sync_pages` — same epoch pinning as
-        # `_step_xlate` (GC08). `_live_rows` is the pow2-padded mapped
+        # `_step_xlate` (GC08). `_live_rows` is the bucket-padded mapped
         # pool ids (padding repeats a LIVE row — models/paged.py needs a
         # live representative, never a dead one); `_live_inv` maps pool
         # id → compact index (dead rows 0, read only clipped+masked).
         self._live_rows = np.empty(0, np.int32)
         self._live_inv = np.zeros(P, np.int32)
         self._live_n = 0
+        # Row counts the padded device programs are compiled at, all of
+        # them in warm_compile: page scatters (table delta, ctrl, re-init,
+        # moves) at a few coarse sizes; the table delta's room rows at the
+        # size paired with its page bucket; the live extent of the ragged
+        # tick at powers of two from a sixteenth of the pool up, so its
+        # grid stays within 2x of the live pages wherever that matters.
+        self._page_buckets = _row_buckets(P)
+        room_buckets = _row_buckets(dims.rooms)
+        self._table_buckets = [
+            (b, _row_bucket(min(b, dims.rooms), room_buckets))
+            for b in self._page_buckets
+        ]
+        self._live_buckets = _row_buckets(P, first=max(P // 16, 1), factor=2)
         super().__init__(dims.logical, mesh=None, **kwargs)
         # The base ctor wired a dense SlotAllocator; rooms actually claim
         # page grids, so admission/occupancy route through the pager.
@@ -408,23 +417,22 @@ class PagedPlaneRuntime(PlaneRuntime):
             (page_rows, tm, pgr, pgt, pgs, room_rows, rps) = (
                 paged.pack_table_delta(self.pager, delta)
             )
-            page_rows, tm, pgr, pgt, pgs = _pad_rows(
-                _p2(len(page_rows)), page_rows, tm, pgr, pgt, pgs
-            )
-            room_rows, rps = _pad_rows(_p2(len(room_rows)), room_rows, rps)
-            self.table = self._table_delta(
-                self.table, page_rows, tm, pgr, pgt, pgs, room_rows, rps
-            )
+            self._apply_table_rows(page_rows, tm, pgr, pgt, pgs, room_rows, rps)
             if len(delta.moves):
                 src, dst = delta.moves[:, 0], delta.moves[:, 1]
-                src, dst = _pad_rows(_p2(len(src)), src, dst)
+                src, dst = _pad_rows(
+                    _row_bucket(len(src), self._page_buckets), src, dst
+                )
                 self.state = self._move(
                     self.state, jnp.asarray(src), jnp.asarray(dst)
                 )
                 self.stats["page_moves"] += len(delta.moves)
             reinit = np.concatenate([delta.fresh_pages, delta.freed_pages])
             if len(reinit):
-                (reinit,) = _pad_rows(_p2(len(reinit)), reinit.astype(np.int32))
+                (reinit,) = _pad_rows(
+                    _row_bucket(len(reinit), self._page_buckets),
+                    reinit.astype(np.int32),
+                )
                 self.state = self._reinit(
                     self.state, jnp.asarray(reinit), self._page_template
                 )
@@ -445,9 +453,28 @@ class PagedPlaneRuntime(PlaneRuntime):
             self._refresh_live_rows()
         self._step_xlate = self._xlate_cached()
 
+    def _apply_table_rows(self, page_rows, tm, pgr, pgt, pgs,
+                          room_rows, rps) -> None:
+        """Scatter table rows into the device table, padded to the first
+        (pages, rooms) bucket pair that holds both. A room index past the
+        table is dropped by the scatter: that is the padding where no room
+        row changed."""
+        pb, rb = next(
+            (p, r) for p, r in self._table_buckets
+            if len(page_rows) <= p and len(room_rows) <= r
+        )
+        if not len(room_rows):
+            room_rows = np.full(1, self.pdims.rooms, np.int32)
+            rps = np.zeros((1,) + self.pager.rooms_pages.shape[1:], np.int32)
+        page_rows, tm, pgr, pgt, pgs = _pad_rows(pb, page_rows, tm, pgr, pgt, pgs)
+        room_rows, rps = _pad_rows(rb, room_rows, rps)
+        self.table = self._table_delta(
+            self.table, page_rows, tm, pgr, pgt, pgs, room_rows, rps
+        )
+
     def _refresh_live_rows(self) -> None:
         """Rebuild the live-row cache from the device-table mirror (see
-        __init__). Called whenever `_dev_tables` changes; the pow2 bucket
+        __init__). Called whenever `_dev_tables` changes; the bucket
         keeps the kernel grid compiling once per size class."""
         pg_room = self._dev_tables[0]
         rows = np.nonzero(pg_room >= 0)[0].astype(np.int32)
@@ -455,7 +482,7 @@ class PagedPlaneRuntime(PlaneRuntime):
         inv[rows] = np.arange(len(rows), dtype=np.int32)
         self._live_n = len(rows)
         if len(rows):
-            (rows,) = _pad_rows(_p2(len(rows)), rows)
+            (rows,) = _pad_rows(_row_bucket(len(rows), self._live_buckets), rows)
         self._live_rows = rows
         self._live_inv = inv
 
@@ -483,7 +510,7 @@ class PagedPlaneRuntime(PlaneRuntime):
         if len(page_rows):
             pr, meta_rows, ctrl_rows = self._pack_ctrl_pages(
                 self.meta, self._effective_ctrl(), page_rows,
-                pad_to=_p2(len(page_rows)),
+                pad_to=_row_bucket(len(page_rows), self._page_buckets),
             )
             self.state = self._apply_delta(self.state, pr, meta_rows, ctrl_rows)
             self.stats["ctrl_upload_bytes"] += meta_rows.nbytes + ctrl_rows.nbytes
@@ -517,37 +544,39 @@ class PagedPlaneRuntime(PlaneRuntime):
 
     def warm_compile(self) -> None:
         """The paged form of the base warm-up. Every device program here
-        is compiled once per power-of-two bucket of its row count (pages
-        of a table delta, dirtied ctrl pages, re-inited or moved pages,
-        the live-page extent of the ragged tick); first use of a bucket
-        in steady state would stall the tick for the compile, and the
-        ingest ring overflows meanwhile. So run each at every bucket
-        now, writing back the values already there (page 0 and room 0 of
-        an empty pool: the scatters are no-ops), and the live-extent
-        tick on a scratch copy of the state. Callers hold state_lock."""
+        is compiled once per bucket of its row count (`__init__` has the
+        lists): first use of a bucket in steady state would stall the
+        tick for the compile, and the ingest ring overflows meanwhile.
+        So run each at every bucket now, writing back the values already
+        there (page 0 and room 0 of an empty pool: the scatters are
+        no-ops), and the live-extent tick on a scratch copy of the
+        state. Callers hold state_lock."""
         import jax.numpy as jnp
 
         self._sync_pages()
         self._logical_fill()
         self._pooled_fill()
         d, pg = self.pdims, self.pager
-        P, R = d.pool_pages, d.rooms
-        self._warm_ctrl_delta(_pow2_buckets(P))
-        for n in _pow2_buckets(P):
-            rows = np.zeros(n, np.int32)
-            self.state = self._reinit(
-                self.state, jnp.asarray(rows), self._page_template
+        P = d.pool_pages
+        page0 = self._warm_ctrl_delta(self._page_buckets)
+        for n in self._page_buckets:
+            rows = jnp.zeros(n, jnp.int32)
+            self.state = self._reinit(self.state, rows, self._page_template)
+            self.state = self._move(self.state, rows, rows)
+        zero = np.zeros(1, np.int32)
+        for n, _ in self._table_buckets:
+            prow = np.zeros(n, np.int32)
+            self._apply_table_rows(
+                prow, pg.tmembers[prow], pg.pg_room[prow], pg.pg_tp[prow],
+                pg.pg_sp[prow], zero, pg.rooms_pages[zero],
             )
-            self.state = self._move(
-                self.state, jnp.asarray(rows), jnp.asarray(rows)
-            )
-            for m in _pow2_buckets(R):
-                rrows = np.zeros(m, np.int32)
-                self.table = self._table_delta(
-                    self.table, rows, pg.tmembers[rows], pg.pg_room[rows],
-                    pg.pg_tp[rows], pg.pg_sp[rows], rrows,
-                    pg.rooms_pages[rrows],
-                )
+        # the page-row write of restore_room / repair_room_row: one
+        # program, a room's whole page grid
+        n = d.max_tpages * d.max_spages
+        self.state = self._row_write(
+            self.state, np.zeros(n, np.int32),
+            jax.tree.map(lambda a: np.repeat(a[None], n, axis=0), page0),
+        )
         if self._pk_enabled:
             pool = d.pooled()
             packed = (
@@ -560,7 +589,7 @@ class PagedPlaneRuntime(PlaneRuntime):
             keep = (self._live_rows, self._live_inv, self._kernel_s_scratch,
                     self._kernel_steps_scratch)
             self._live_inv = np.zeros(P, np.int32)
-            for n in _pow2_buckets(P):
+            for n in self._live_buckets:
                 self._live_rows = np.zeros(n, np.int32)
                 scratch = jax.tree.map(jnp.copy, self.state)
                 jax.block_until_ready(self._live_step(scratch, *packed))
@@ -622,10 +651,9 @@ class PagedPlaneRuntime(PlaneRuntime):
             return None
         rows = np.nonzero(bad)[0].astype(np.int32)
         # Host canonical is authoritative: re-scatter the diverged rows.
-        self.table = self._table_delta(
-            self.table, rows, mtm[rows], mr[rows], mt[rows], ms[rows],
-            np.empty(0, np.int32),
-            np.empty((0, self.pager.rooms_pages.shape[1]), np.int32),
+        self._apply_table_rows(
+            rows, mtm[rows], mr[rows], mt[rows], ms[rows],
+            np.empty(0, np.int32), None,
         )
         self.table_repairs += len(rows)
         R = self.dims.rooms
@@ -682,8 +710,14 @@ class PagedPlaneRuntime(PlaneRuntime):
             lambda kind, lrow, leaf: rowfun(kind, lrow, leaf).astype(leaf.dtype),
             kinds, row_tree, self.state,
         )
+        # Padded to a room's whole grid (repeating the first page): one
+        # program for every room size, compiled in warm_compile.
+        n = d.max_tpages * d.max_spages
+        pages, *leaves = _pad_rows(
+            n, np.asarray(pages, np.int32), *jax.tree.leaves(rows_tree)
+        )
         self.state = self._row_write(
-            self.state, np.asarray(pages, np.int32), rows_tree
+            self.state, pages, jax.tree.unflatten(sdef, leaves)
         )
 
     def snapshot(self) -> dict[str, Any]:

@@ -294,10 +294,21 @@ def _build_row_read():
     return jax.jit(lambda state, row: jax.tree.map(lambda x: x[row], state))
 
 
-def _pow2_buckets(top: int) -> list[int]:
-    """1, 2, 4, ... up to the first power of two >= top: the row counts
-    the bucketed scatters are compiled at."""
-    return [1 << i for i in range(max(top - 1, 0).bit_length() + 1)]
+def _row_buckets(top: int, first: int = 16, factor: int = 8) -> list[int]:
+    """The few row counts a padded scatter is compiled at: `first`, then
+    steps of `factor`, then `top` itself. The usual churn (a join, a leave)
+    fits the first; a program per power of two would be a compile each at
+    every server start for sizes no deployment reaches."""
+    out, b = [], first
+    while b < top:
+        out.append(b)
+        b *= factor
+    return out + [top]
+
+
+def _row_bucket(n: int, buckets: list[int]) -> int:
+    """The smallest bucket that holds `n` rows."""
+    return next(b for b in buckets if n <= b)
 
 
 @dataclass
@@ -730,9 +741,11 @@ class PlaneRuntime:
             )
             self.stats["ctrl_full_uploads"] += 1
         else:
-            # Pad the row count to a power-of-two bucket so the scatter
-            # compiles once per bucket, not once per distinct count.
-            pad_to = 1 << (len(rows) - 1).bit_length() if len(rows) > 1 else 1
+            # Pad the row count to a bucket so the scatter compiles once
+            # per bucket (all of them in warm_compile), not once per count.
+            pad_to = _row_bucket(
+                len(rows), _row_buckets(self.ctrl_delta_max_rows)
+            )
             r, meta_rows, ctrl_rows = plane.pack_ctrl_rows(
                 self.meta, self._effective_ctrl(), rows, pad_to=pad_to
             )
@@ -1579,13 +1592,13 @@ class PlaneRuntime:
     def warm_compile(self) -> None:
         """Compile, inside the warm-up window, the programs whose first
         use would otherwise fall in steady state: the dirty-row control
-        scatter at every power-of-two bucket `_upload_ctrl` can ask for
+        scatter at every row bucket `_upload_ctrl` can ask for
         (a join would compile one mid-session) and the row read / write
         of room handoff and integrity repair (a migration's first
         adoption would outlast its ACK timeout). Each runs on the live
         state with the values already there, so the state is unchanged.
         Callers hold state_lock (GC01)."""
-        row0 = self._warm_ctrl_delta(_pow2_buckets(self.ctrl_delta_max_rows))
+        row0 = self._warm_ctrl_delta(_row_buckets(self.ctrl_delta_max_rows))
         flat, treedef = jax.tree.flatten(self.state)
         self._write_row(0, flat, treedef, jax.tree.leaves(row0))
 

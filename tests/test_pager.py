@@ -502,6 +502,45 @@ async def test_grow_on_join_across_page_boundary():
     assert prt.pager.stats()["grows"] == 1
 
 
+async def test_warm_compile_leaves_nothing_to_compile():
+    """After `warm_compile` (and one tick of each kind, which the server's
+    warm-up also runs) the churn a paged node sees compiles nothing: rooms
+    of every size joining, a grid growing on a join, rooms restored into
+    grids of different page counts, a release with compaction, and the
+    table audit's repair with no room row to write."""
+    prt = PagedPlaneRuntime(PD, tick_ms=10, paged_kernel="on")
+    await prt.step_once()                       # the empty-pool tick
+    async with prt.state_lock:
+        prt.warm_compile()
+    prt.mark_warm()
+
+    _setup_rooms(prt)
+    await _run_ticks(prt, 3)
+    s = prt.slots.alloc_room("d")               # a fourth room, one page
+    s.alloc_track("t0")
+    s.alloc_sub("p0")
+    prt.set_track(3, 0, published=True, is_video=False)
+    prt.set_subscription(1, 0, 7, subscribed=False)
+    await _run_ticks(prt, 2, start=3)
+    snaps = {row: prt.snapshot_room(row) for row in (0, 1, 2)}
+    assert len({len(prt.pager.pages_of_room(r)) for r in snaps}) == 3
+    for row, snap in snaps.items():             # 1, 4 and 2 pages
+        prt.restore_room(row, snap)
+    prt.repair_room_row(1, snaps[1])
+    await _run_ticks(prt, 2, start=5)
+    prt.slots.release_room("a")
+    prt.compact()
+    await _run_ticks(prt, 2, start=7)
+    victim = int(prt.pager.pages_of_room(1)[0])
+    prt.table = prt.table._replace(             # a flipped table entry
+        pg_room=np.asarray(prt.table.pg_room).copy())
+    prt.table.pg_room[victim] = -1
+    assert prt._audit_page_table() is not None and prt.table_repairs == 1
+    assert prt._audit_page_table() is None
+    await _run_ticks(prt, 2, start=9)
+    assert prt.compile_ledger.post_warmup == 0, prt.compile_ledger.snapshot()
+
+
 async def test_page_table_bitflip_detected_and_repaired():
     """SDC drill on the indirection layer itself: corrupt one mapped
     page's device pg_room entry. The next audit must spot the divergence
