@@ -21,7 +21,7 @@ def test_rehearsal_runs_every_phase_and_ends_in_the_json_line(capsys):
     assert set(last["device"]) == {"platform", "kind", "count"}
     assert last["device"]["platform"] == "cpu"
     text = "\n".join(out[:-1])
-    for phase in ("dense", "paged"):
+    for phase in ("default", "cfg4", "paged"):
         assert f"[{phase}] SN space continuous and gap-free" in text
         assert f"[{phase}] server stopped cleanly" in text
         assert "0 after warm-up" in text
@@ -33,7 +33,7 @@ def test_four_chip_rehearsal_runs_that_comparison_alone(capsys):
     out = capsys.readouterr().out.strip().splitlines()
     assert json.loads(out[-1])["ok"] is True
     assert "[four-chip outputs]" in "\n".join(out)
-    assert not any(ln.startswith(("[dense]", "[paged")) for ln in out)
+    assert not any(ln.startswith(("[default]", "[cfg4]", "[paged")) for ln in out)
 
 
 def test_without_a_tpu_nothing_runs_and_nothing_is_printed(capsys):

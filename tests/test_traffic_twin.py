@@ -1,6 +1,10 @@
 """Traffic twin (runtime/traffic_twin.py): scenario DSL validation, the
 byte-identical-timeline determinism contract, a full same-seed replay
-equivalence check, and the twin.* config knobs."""
+equivalence check, the twin.* config knobs, and the CLI's last-line-JSON
+contract (what `tools/check --twin-smoke` and any caller with a deadline
+read)."""
+
+import json
 
 import pytest
 
@@ -134,3 +138,50 @@ def test_twin_config_knobs_and_validation():
     ):
         with pytest.raises(ConfigError):
             load_config(yaml_text=BASE_YAML + frag)
+
+
+# -- the CLI's output contract ------------------------------------------------
+
+def _stdout_objects(capsys) -> list[dict]:
+    out, err = capsys.readouterr()
+    lines = out.strip().splitlines()
+    assert lines, "nothing on stdout"
+    return [json.loads(ln) for ln in lines]    # every stdout line is JSON
+
+
+def test_main_smoke_prints_one_json_line_last(capsys):
+    """`--smoke`: the micro-scenario's verdict is the one stdout line and
+    the exit code follows its `ok`."""
+    from livekit_server_tpu.runtime import traffic_twin
+
+    rc = traffic_twin.main(["--smoke", "--seed", "20"])
+    (obj,) = _stdout_objects(capsys)
+    assert rc == (0 if obj["ok"] else 1)
+    assert obj["audio_gaps"] == 0 and obj["ticks"] > 0
+
+
+def test_main_curve_last_json_line_wins(capsys, monkeypatch):
+    """Curve mode: each finished load step goes out as a line flagged
+    `partial`, so a caller killed at its deadline keeps the steps done;
+    the whole curve comes last, unflagged; progress goes to stderr."""
+    from livekit_server_tpu.runtime import traffic_twin
+
+    async def fake_curve(sc, loads, *, on_step, log, **kw):
+        steps = []
+        for load in loads:
+            log(f"progress: load x{load}")
+            steps.append({"offered_load": load})
+            on_step(list(steps))
+        return {"seed": sc.seed, "loads": list(loads), "steps": steps,
+                "capacity_knee_load": loads[-1]}
+
+    monkeypatch.setattr(traffic_twin, "capacity_curve", fake_curve)
+    assert traffic_twin.main(["--loads", "0.5,1.0,2.0"]) == 0
+    out, err = capsys.readouterr()
+    assert "progress: load x2.0" in err and "progress" not in out
+    objs = [json.loads(ln) for ln in out.strip().splitlines()]
+    *partials, last = objs
+    assert [len(p["steps"]) for p in partials] == [1, 2, 3]
+    assert all(p["partial"] is True for p in partials)
+    assert "partial" not in last and last["capacity_knee_load"] == 2.0
+    assert len(last["steps"]) == 3
