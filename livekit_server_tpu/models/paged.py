@@ -225,7 +225,8 @@ def paged_plane_tick(
     va_g = gtrack(video_active, False).reshape(P, MT * TP)
     alloc_muted = ~(sub_g & va_g[:, None, :] & ~mut_g)        # [P, SP, MT*TP]
     target_full, _used, deficient = allocation.allocate_budget_rooms(
-        bit_g, msp_g, mtp_g, alloc_muted, outputs.committed_bps
+        bit_g, msp_g, mtp_g, alloc_muted, outputs.committed_bps,
+        allow_pause=bwe_params.allow_pause,
     )                                                          # [P, SP, MT*TP]
     # Keep only this page's own tracks: every (tp, sp) block is computed
     # by exactly one page, so the logical [R, S, T] targets reassemble
@@ -432,7 +433,8 @@ def paged_plane_tick_live(
     va_g = gtrack(video_active, False).reshape(NL, MT * TP)
     alloc_muted = ~(sub_g & va_g[:, None, :] & ~mut_g)      # [NL, SP, MT*TP]
     target_full, _used, deficient = allocation.allocate_budget_rooms(
-        bit_g, msp_g, mtp_g, alloc_muted, outputs_c.committed_bps
+        bit_g, msp_g, mtp_g, alloc_muted, outputs_c.committed_bps,
+        allow_pause=bwe_params.allow_pause,
     )
     tgt4 = target_full.reshape(NL, SP, MT, TP)
     own_tp = jnp.clip(table.pg_tp[live_rows], 0, MT - 1)

@@ -651,6 +651,7 @@ def media_plane_tick(
         state.ctrl.max_temporal.transpose(0, 2, 1),
         alloc_muted,
         outputs.committed_bps,
+        allow_pause=bwe_params.allow_pause,
     )                                                           # [R, S, T]
     tgt_ts = target_flat.transpose(0, 2, 1)                     # [R, T, S]
     sel_state = selector.set_target(

@@ -18,6 +18,8 @@ Layer encoding: flat index l = spatial*MAX_T + temporal, -1 = paused.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -84,7 +86,8 @@ def layer_bitrate(bitrates, flat):
     return jnp.where(flat < 0, 0, val)
 
 
-def allocate_budget(bitrates, max_spatial, max_temporal, muted, budget):
+def allocate_budget(bitrates, max_spatial, max_temporal, muted, budget,
+                    allow_pause: bool = True):
     """Cooperative constrained allocation across one subscriber's tracks.
 
     Reference parity: streamallocator.go allocateAllTracks — two passes over
@@ -98,6 +101,13 @@ def allocate_budget(bitrates, max_spatial, max_temporal, muted, budget):
       max_spatial   [T] int32, max_temporal [T] int32 — subscriber caps
       muted         [T] bool — pub/sub muted (ProvisionalAllocateMute)
       budget        scalar float32 — available channel capacity (bps)
+      allow_pause   static — config rtc.congestion_control.allow_pause. False
+                    (the reference's default): pass 1 gives every audible/
+                    visible track its minimal layer whatever the budget
+                    (streamallocator.go: "allocate minimal to all tracks
+                    irrespective of channel capacity"), so a low estimate
+                    degrades video to its lowest layer and never pauses it;
+                    `used` may then exceed `budget`.
 
     Returns (target_flat [T] int32, used_bps scalar, deficient [T] bool).
     """
@@ -110,7 +120,7 @@ def allocate_budget(bitrates, max_spatial, max_temporal, muted, budget):
     # Pass 1: minimal layers, in track order, while budget lasts.
     def p1(budget_left, xs):
         cost, valid = xs
-        take = valid & (cost <= budget_left)
+        take = valid & (cost <= budget_left) if allow_pause else valid
         budget_left = jnp.where(take, budget_left - cost, budget_left)
         return budget_left, take
 
@@ -148,7 +158,8 @@ def allocate_budget(bitrates, max_spatial, max_temporal, muted, budget):
     return target, used, deficient
 
 
-def allocate_budget_batch(bitrates, max_spatial, max_temporal, muted, budget):
+def allocate_budget_batch(bitrates, max_spatial, max_temporal, muted, budget,
+                          allow_pause: bool = True):
     """One room's allocation for ALL subscribers at once — the scan
     formulation (the spec). The production TPU path is the room-batched
     `allocate_budget_rooms` kernel, pinned bit-identical to this by
@@ -162,7 +173,8 @@ def allocate_budget_batch(bitrates, max_spatial, max_temporal, muted, budget):
     Returns (target [S, T] int32, used [S] float32, deficient [S, T] bool).
     """
     return jax.vmap(
-        lambda m1, m2, m3, b: allocate_budget(bitrates, m1, m2, m3, b)
+        lambda m1, m2, m3, b: allocate_budget(
+            bitrates, m1, m2, m3, b, allow_pause)
     )(max_spatial, max_temporal, muted, budget)
 
 
@@ -174,7 +186,7 @@ def allocate_budget_batch(bitrates, max_spatial, max_temporal, muted, budget):
 
 
 def _budget_rooms_kernel(bit_ref, ms_ref, mt_ref, muted_ref, budget_ref,
-                         target_ref, used_ref, defc_ref):
+                         target_ref, used_ref, defc_ref, *, allow_pause):
     """Two-pass cooperative allocation for a ROOM BLOCK: bit_ref
     [T, L, RB]; ms/mt/muted [T, S, RB]; budget [1, S, RB]; outputs
     target/defc [T, S, RB], used [1, S, RB]."""
@@ -202,7 +214,9 @@ def _budget_rooms_kernel(bit_ref, ms_ref, mt_ref, muted_ref, budget_ref,
     bl = budget_ref[0, :, :]                                        # [S,RB]
     got = []
     for t in range(T):                                              # pass 1
-        take = (lo[t] >= 0) & (locost[t] <= bl)
+        take = lo[t] >= 0
+        if allow_pause:
+            take &= locost[t] <= bl
         bl = jnp.where(take, bl - locost[t], bl)
         got.append(take)
     for t in range(T):                                              # pass 2
@@ -221,7 +235,8 @@ def _budget_rooms_kernel(bit_ref, ms_ref, mt_ref, muted_ref, budget_ref,
 
 def allocate_budget_rooms(bitrates, max_spatial, max_temporal, muted, budget,
                           use_pallas: bool | None = None,
-                          interpret: bool = False):
+                          interpret: bool = False,
+                          allow_pause: bool = True):
     """All rooms' allocation at once.
 
     Args:
@@ -236,9 +251,9 @@ def allocate_budget_rooms(bitrates, max_spatial, max_temporal, muted, budget,
         use_pallas, interpret, "allocation.allocate_budget_rooms"
     )
     if not (use_pallas or interpret):
-        return jax.vmap(allocate_budget_batch)(
-            bitrates, max_spatial, max_temporal, muted, budget
-        )
+        return jax.vmap(
+            functools.partial(allocate_budget_batch, allow_pause=allow_pause)
+        )(bitrates, max_spatial, max_temporal, muted, budget)
 
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -264,7 +279,7 @@ def allocate_budget_rooms(bitrates, max_spatial, max_temporal, muted, budget,
     bud_spec = pl.BlockSpec((1, S, RB), lambda i: (0, 0, i),
                             memory_space=pltpu.VMEM)
     target, used, defc = pl.pallas_call(
-        _budget_rooms_kernel,
+        functools.partial(_budget_rooms_kernel, allow_pause=allow_pause),
         grid=(R // RB,),
         out_shape=(
             jax.ShapeDtypeStruct((T, S, R), jnp.int32),

@@ -40,6 +40,9 @@ class BWEParams(NamedTuple):
                            # (channelobserver windows age out; without this
                            # a client that stops reporting would freeze the
                            # congested state and starve the probe controller)
+    allow_pause: bool = True  # config allow_pause; False = the allocator keeps
+                              # every video at its lowest layer at least
+                              # (ops/allocation.allocate_budget)
 
 
 class BWEState(NamedTuple):

@@ -112,6 +112,7 @@ class RoomManager:
                 nack_window_min_packets=config.rtc.congestion_control.nack_window_min_packets,
                 estimate_required_downgrades=config.rtc.congestion_control.estimate_required_downgrades,
                 congested_min_estimate=config.rtc.congestion_control.min_channel_capacity,
+                allow_pause=config.rtc.congestion_control.allow_pause,
             ),
             egress_shards=config.egress.shards,
             egress_multicast=config.egress.multicast_seal,

@@ -65,6 +65,27 @@ def test_allocate_budget_starvation_pauses():
     assert bool(deficient[0])
 
 
+def test_allocate_budget_without_pause_keeps_the_lowest_layer():
+    """`allow_pause=False` (config rtc.congestion_control.allow_pause, the
+    reference's default): a budget that cannot pay for the minimal layer
+    degrades the video to it and does not pause it; a muted track is still
+    skipped, and a rich channel allocates as before."""
+    b = _bitrates()
+    caps, live = jnp.array([3, 3]), jnp.array([False, False])
+    target, used, deficient = al.allocate_budget(
+        b, caps, caps, live, 10e3, allow_pause=False)
+    assert [int(t) for t in target] == [0, 0]       # lowest layer of each
+    assert float(used) == 150e3 + 32e3              # over the 10 kbit/s budget
+    assert bool(deficient[0]) and not bool(deficient[1])
+    target, _, _ = al.allocate_budget(
+        b, caps, caps, jnp.array([True, False]), 10e3, allow_pause=False)
+    assert [int(t) for t in target] == [-1, 0]
+    rich = al.allocate_budget(b, caps, caps, live, 10e6, allow_pause=False)
+    want = al.allocate_budget(b, caps, caps, live, 10e6)
+    assert all(np.array_equal(np.asarray(x), np.asarray(y))
+               for x, y in zip(rich, want))
+
+
 def test_allocate_budget_mute_skips():
     b = _bitrates()
     target, used, deficient = al.allocate_budget(
@@ -107,11 +128,14 @@ def test_pallas_rooms_budget_matches_per_room():
         mu = rng.random((R, S, T)) < 0.2
         bud = (rng.random((R, S)) * 8e6).astype(np.float32)
         args = tuple(jnp.asarray(x) for x in (bit, ms, mt, mu, bud))
-        t0, u0, d0 = al.allocate_budget_rooms(*args, use_pallas=False)
-        t1, u1, d1 = al.allocate_budget_rooms(*args, interpret=True)
-        assert np.array_equal(np.asarray(t0), np.asarray(t1))
-        assert np.allclose(np.asarray(u0), np.asarray(u1), rtol=1e-5)
-        assert np.array_equal(np.asarray(d0), np.asarray(d1))
+        for allow_pause in (True, False):
+            t0, u0, d0 = al.allocate_budget_rooms(
+                *args, use_pallas=False, allow_pause=allow_pause)
+            t1, u1, d1 = al.allocate_budget_rooms(
+                *args, interpret=True, allow_pause=allow_pause)
+            assert np.array_equal(np.asarray(t0), np.asarray(t1))
+            assert np.allclose(np.asarray(u0), np.asarray(u1), rtol=1e-5)
+            assert np.array_equal(np.asarray(d0), np.asarray(d1))
 
 
 def test_pallas_rooms_budget_edge_cases_match():
@@ -132,8 +156,11 @@ def test_pallas_rooms_budget_edge_cases_match():
     ]
     for mu, bud in cases:
         args = tuple(jnp.asarray(x) for x in (bit, ms, mt, mu, bud))
-        t0, u0, d0 = al.allocate_budget_rooms(*args, use_pallas=False)
-        t1, u1, d1 = al.allocate_budget_rooms(*args, interpret=True)
-        assert np.array_equal(np.asarray(t0), np.asarray(t1))
-        assert np.allclose(np.asarray(u0), np.asarray(u1), rtol=1e-5)
-        assert np.array_equal(np.asarray(d0), np.asarray(d1))
+        for allow_pause in (True, False):
+            t0, u0, d0 = al.allocate_budget_rooms(
+                *args, use_pallas=False, allow_pause=allow_pause)
+            t1, u1, d1 = al.allocate_budget_rooms(
+                *args, interpret=True, allow_pause=allow_pause)
+            assert np.array_equal(np.asarray(t0), np.asarray(t1))
+            assert np.allclose(np.asarray(u0), np.asarray(u1), rtol=1e-5)
+            assert np.array_equal(np.asarray(d0), np.asarray(d1))

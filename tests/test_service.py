@@ -146,6 +146,19 @@ async def running_server(configure=None, **plane_overrides):
         await srv.stop(force=True)
 
 
+@pytest.mark.parametrize("configured", [None, True, False])
+def test_allow_pause_config_reaches_the_allocator(configured):
+    """rtc.congestion_control.allow_pause (default false, as the reference)
+    is the allocator's static flag in the served step: a low bandwidth
+    estimate degrades video to its lowest layer and pauses it only where
+    the operator said it may."""
+    cfg = make_config(0)
+    if configured is not None:
+        cfg.rtc.congestion_control.allow_pause = configured
+    rt = create_server(cfg).room_manager.runtime
+    assert rt._bp.allow_pause is bool(configured)
+
+
 async def test_health_and_validate():
     async with running_server() as server:
         async with aiohttp.ClientSession() as s:

@@ -93,7 +93,8 @@ def test_served_step_lowers(one_chip, as_tpu, dims):
     # A fresh jit, not the runtime's shared one: that may already hold a
     # CPU-branch trace of this shape from another test in this process.
     step = jax.jit(
-        _packed_tick(audio.AudioLevelParams(), bwe.BWEParams()),
+        # allow_pause False: what `serve` builds from the default config
+        _packed_tick(audio.AudioLevelParams(), bwe.BWEParams(allow_pause=False)),
         donate_argnums=(0,),
     )
     compiled = step.lower(state, *packed).compile()
