@@ -374,7 +374,8 @@ async def tick_report(session, port: int, name: str, when: str, tick_ms: int) ->
     level = (await http_json(session, port, "/debug/overload"))["governor"]["level"]
     say(f"[{name}] {when} tick (median of {len(ticks)}, host clock, no result): "
         f"stage {med('stage_ms')} + device call {med('device_ms')} + fan-out "
-        f"{med('fanout_ms')} = {med('total_ms')} ms of {tick_ms}; "
+        f"{med('fanout_ms')} = {med('total_ms')} ms, of which "
+        f"{med('work_ms')} not overlapped, in a window of {tick_ms}; "
         f"governor level {level}")
     return level
 

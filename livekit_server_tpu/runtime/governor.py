@@ -23,7 +23,9 @@ degradation levels, each mapped to an existing actuator:
       signal responses (RoomManager admission consults should_admit)
 
 Sensors are evaluated once per completed tick (PlaneRuntime._complete →
-on_tick): deadline lateness, work ratio (tick work time / tick period),
+on_tick): deadline lateness, work ratio (what the tick asks of its window /
+tick period: with the pipelined loop the device step overlaps staging and
+fan-out, so that is the longer of the two, not their sum),
 new pipeline stalls, and new ingest *capacity* drops. Policed drops are
 deliberately excluded — intentional shedding must not read as pressure,
 which is the point of the dropped_capacity / dropped_policed split.
@@ -127,7 +129,11 @@ class OverloadGovernor:
         d_caps = cap_drops - self._cap_drops_seen
         self._stalls_seen = stalls
         self._cap_drops_seen = cap_drops
-        work = rec.get("total_ms", 0.0) / max(float(rt.tick_ms), 1e-3)
+        # The tick's share of its window: `work_ms` where the record has
+        # it (the pipelined loop's own budget, max(device, stage + fan-out)),
+        # else the plain sum of the stages.
+        work = rec.get("work_ms", rec.get("total_ms", 0.0)) / max(
+            float(rt.tick_ms), 1e-3)
         late = bool(rec.get("late"))
         self.ticks += 1
         pressured = (

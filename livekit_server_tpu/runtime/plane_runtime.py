@@ -938,6 +938,12 @@ class PlaneRuntime:
             "device_ms": round(st.device_s * 1000.0, 3),
             "fanout_ms": round(fanout_s * 1000.0, 3),
             "total_ms": round(result.tick_s * 1000.0, 3),
+            # What the tick asks of its window (`_run`'s docstring): the
+            # device step overlaps the event loop's staging and fan-out
+            # when pipelined, and follows them when not.
+            "work_ms": round(1000.0 * (
+                max(st.device_s, st.stage_s + fanout_s) if st.depth
+                else result.tick_s), 3),
             "late": late,
             "edge_overshoot_us": round(st.edge_over_us, 1),
         }

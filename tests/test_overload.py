@@ -71,6 +71,45 @@ def test_ladder_escalates_and_recovers_one_step_per_streak():
     assert gov.transition_count == 8
 
 
+def test_overlapped_stages_are_not_summed_into_pressure():
+    """The work sensor reads what a tick asks of its window. Pipelined,
+    the device step runs beside staging and fan-out (4.4 ms beside 4.2 ms
+    in a 10 ms window: the `serve` defaults idle on the chip's host), so
+    the window is half used although the stages sum to 0.86 of it; a
+    device step of 9 ms does fill it."""
+    rt = make_rt()
+    gov = OverloadGovernor(rt, escalate_ticks=3, dwell_ticks=5)
+    rt.governor = gov
+    half = {"total_ms": 8.6, "work_ms": 4.4, "late": False}
+    for _ in range(30):
+        gov.on_tick(half)
+    assert gov.level == 0 and gov.transition_count == 0
+    full = {"total_ms": 13.2, "work_ms": 9.0, "late": False}
+    for _ in range(3):
+        gov.on_tick(full)
+    assert gov.level == 1
+
+
+async def test_tick_record_work_is_the_longer_of_the_overlapped_halves():
+    """`work_ms` in a served tick's record: max(device, stage + fan-out)
+    when the loop pipelines, the plain sum when it does not."""
+    for low_latency in (False, True):
+        rt = PlaneRuntime(DIMS, tick_ms=10, low_latency=low_latency)
+        rt.start()
+        try:
+            deadline = asyncio.get_event_loop().time() + 20.0
+            while (len(rt.recent_ticks) < 4
+                   and asyncio.get_event_loop().time() < deadline):
+                await asyncio.sleep(0.01)
+        finally:
+            await rt.stop()
+        rec = list(rt.recent_ticks)[-1]
+        parts = (rec["device_ms"], rec["stage_ms"] + rec["fanout_ms"])
+        want = sum(parts) if low_latency else max(parts)
+        assert rec["depth"] == (0 if low_latency else 1)
+        assert abs(rec["work_ms"] - want) < 0.01, rec
+
+
 def test_oscillating_load_does_not_flap():
     rt = make_rt()
     gov = OverloadGovernor(rt, escalate_ticks=5, dwell_ticks=5)
