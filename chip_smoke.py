@@ -37,21 +37,24 @@ VP8_PT, OPUS_PT = 96, 111
 
 # Three served phases, each the real server behind its loopback ports:
 #   default  the `serve` defaults as a user gets them: 64 x 16 x 16 x 32, dense,
-#            tick_ms 10;
+#            tick_ms 10, with the 8 rooms this process can also play the
+#            clients for at that tick (below);
 #   cfg4     BASELINE.json cfg4 width, 1024 x 10 x 8 x 10, dense, at the tick
 #            this host loop needs for that width (below);
 #   paged    the `serve` defaults with `plane.pager_enabled: true` (page 4x8,
 #            pool 1024), ragged kernel on.
 #
-# cfg4 and the paged server do not run at the default tick, and the smoke says
-# so rather than hide it (host clock on the chip's host; my chip runs, PR 25):
-# at cfg4 width an idle tick costs 12-17 ms on the host (stage + device call +
-# fan-out: every one of 1,024 room rows is staged and unpacked whether live or
-# not) and the supervisor's 2 s checkpoint holds the event loop ~100 ms, so at
-# 10 ms the overload governor, rightly, refuses every join, at 20 ms it sheds
-# as soon as media flows, and at 40 ms each checkpoint makes 8 ticks late, 20
-# in a row being its trigger. The paged server's loaded tick is 19-34 ms at 32
-# live rooms. PERF.md has the numbers, ROADMAP queue A the item.
+# Ticks and live rooms are what the chip's host held in my chip runs (PR 25,
+# host clock; PERF.md has the numbers, ROADMAP queue A the item), and the smoke
+# says so rather than hide it. At the default width an idle tick asks 4.6 ms
+# of its 10 ms window, and with 8 live rooms 7 % of the ticks are late and the
+# governor stays at 0; with 32 rooms, their 96 clients in this interpreter, it
+# asks 8 ms and more, two thirds of the ticks are late and the governor sheds.
+# At cfg4 width an idle tick costs 12-17 ms (every one of 1,024 room rows is
+# staged and unpacked whether live or not) and the supervisor's 2 s checkpoint
+# holds the event loop ~100 ms: the governor refuses every join at 10 ms, sheds
+# at 20 ms, and reaches level 1 at 40 ms. The paged server's loaded tick is
+# 19-34 ms at 32 live rooms.
 DEFAULT_TICK_MS = 10
 WIDE_TICK_MS = 80
 MEDIA_MS = 40      # one packet per track per 40 ms: 25 pkt/s, video and audio
@@ -840,19 +843,19 @@ def main(argv: list[str] | None = None) -> int:
     else:
         native_report()
         toy = args.rehearse
-        rooms = 3 if toy else 32
         paged_plane = dict(TOY, pager_tpage=2, pager_spage=2) if toy else SERVE_DEFAULT
-        # (name, plane, tick_ms, lead-in ticks, checked ticks): every window
-        # is at least 300 ticks on the chip; the rehearsal's are short, and
-        # its first tick is 40 ms, which XLA:CPU holds on a loaded host
-        for name, plane, tick_ms, lead, ticks in (
+        # (name, plane, tick_ms, live rooms, lead-in ticks, checked ticks):
+        # every window is at least 300 ticks on the chip; the rehearsal's are
+        # short, and its first tick is 40 ms, which XLA:CPU holds on a loaded
+        # host
+        for name, plane, tick_ms, rooms, lead, ticks in (
             ("default", TOY if toy else SERVE_DEFAULT,
              4 * DEFAULT_TICK_MS if toy else DEFAULT_TICK_MS,
-             40 if toy else 200, 60 if toy else 1280),
+             3 if toy else 8, 40 if toy else 200, 60 if toy else 1280),
             ("cfg4", TOY if toy else CFG4, WIDE_TICK_MS,
-             20 if toy else 50, 30 if toy else 320),
+             3 if toy else 32, 20 if toy else 50, 30 if toy else 320),
             ("paged", dict(paged_plane, pager_enabled=True), WIDE_TICK_MS,
-             20 if toy else 50, 30 if toy else 320),
+             3 if toy else 32, 20 if toy else 50, 30 if toy else 320),
         ):
             asyncio.run(served_phase(
                 name, plane, tick_ms=tick_ms, live_rooms=rooms,
