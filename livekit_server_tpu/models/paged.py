@@ -575,9 +575,11 @@ def apply_table_delta(
     room_rows, rooms_pages_rows,
 ) -> PageTable:
     """Device half (traced; jit with `table` donated): scatter the
-    dirtied rows into the device table."""
+    dirtied rows into the device table. A room row past the table is
+    dropped: the caller's padding when no room row changed."""
     return PageTable(
-        rooms_pages=table.rooms_pages.at[room_rows].set(rooms_pages_rows),
+        rooms_pages=table.rooms_pages.at[room_rows].set(
+            rooms_pages_rows, mode="drop"),
         tmembers=table.tmembers.at[page_rows].set(tmember_rows),
         pg_room=table.pg_room.at[page_rows].set(pg_room_rows),
         pg_tp=table.pg_tp.at[page_rows].set(pg_tp_rows),
