@@ -134,14 +134,10 @@ class PlaneSupervisor:
         callback. Taken under state_lock so the donated device step never
         has the arrays mid-flight. The snapshot is encoded + checksummed
         into the generation ring; the corrupt_ckpt fault seam damages the
-        encoded bytes here, exactly where real bit rot would land. The
-        encoding (compress + checksum of host copies: 20-100 ms at served
-        widths) runs on a worker thread: on the event loop it held every
-        tick edge that long, and a 10 ms tick spent the next dozen ticks
-        late catching up, every 2 s."""
+        encoded bytes here, exactly where real bit rot would land."""
         async with self.runtime.state_lock:
-            self.last_snapshot = snap = self.runtime.snapshot()
-        blob = await asyncio.to_thread(self.runtime.encode_snapshot, snap)
+            self.last_snapshot = self.runtime.snapshot()
+        blob = self.runtime.encode_snapshot(self.last_snapshot)
         fault = getattr(self.runtime, "fault", None)
         if fault is not None:
             blob = fault.corrupt_ckpt(blob)

@@ -309,46 +309,6 @@ async def test_flood_sheds_video_keeps_audio_and_recovers():
     assert rt.compile_ledger.post_warmup == 0
 
 
-# -- the checkpoint's encoding stays off the tick's thread -------------------
-
-async def test_checkpoint_encoding_does_not_hold_the_event_loop():
-    """The supervisor's 2 s checkpoint compresses and checksums the whole
-    plane: tens of ms at served widths. On the event loop that was a stall
-    at every tick edge it covered (at a 10 ms tick a dozen late ticks each
-    time, enough to walk the governor up on a slow host); it runs on a
-    worker thread, and the generation still lands before `checkpoint_now`
-    returns."""
-    import time
-
-    rt = make_rt()
-    sup = PlaneSupervisor(rt, checkpoint_interval_s=60.0)
-    encode = rt.encode_snapshot
-
-    def slow_encode(snap):
-        time.sleep(0.3)                     # a wide plane's compress + CRC
-        return encode(snap)
-
-    rt.encode_snapshot = slow_encode
-    gaps, stop = [], asyncio.Event()
-
-    async def heartbeat():
-        last = time.perf_counter()
-        while not stop.is_set():
-            await asyncio.sleep(0.005)
-            now = time.perf_counter()
-            gaps.append(now - last)
-            last = now
-
-    beat = asyncio.ensure_future(heartbeat())
-    await asyncio.sleep(0.02)
-    await sup.checkpoint_now()
-    stop.set()
-    await beat
-    assert len(sup._gens) == 1 and sup.last_good_snapshot() is not None
-    assert max(gaps) < 0.15, f"event loop held {max(gaps):.3f} s"
-    await rt.stop()
-
-
 # -- supervisor interaction: governed lateness is not a stall ---------------
 
 async def test_supervisor_spares_governed_plane_restarts_wedged_one():
