@@ -47,9 +47,10 @@ VP8_PT, OPUS_PT = 96, 111
 # Ticks and live rooms are what the chip's host held in my chip runs (PR 25,
 # host clock; PERF.md has the numbers, ROADMAP queue A the item), and the smoke
 # says so rather than hide it. At the default width an idle tick asks 4.6 ms
-# of its 10 ms window, and with 8 live rooms 7 % of the ticks are late and the
-# governor stays at 0; with 32 rooms, their 96 clients in this interpreter, it
-# asks 8 ms and more, two thirds of the ticks are late and the governor sheds.
+# of its 10 ms window, and with 8 live rooms 7 % of the ticks are late (a dozen
+# after each 2 s checkpoint of the supervisor) and the governor stayed at 0 in
+# 2 runs of 3; with 32 rooms, their 96 clients in this interpreter, it asks
+# 8 ms and more, two thirds of the ticks are late and the governor sheds.
 # At cfg4 width an idle tick costs 12-17 ms (every one of 1,024 room rows is
 # staged and unpacked whether live or not) and the supervisor's 2 s checkpoint
 # holds the event loop ~100 ms: the governor refuses every join at 10 ms, sheds
