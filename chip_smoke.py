@@ -49,7 +49,7 @@ VP8_PT, OPUS_PT = 96, 111
 # says so rather than hide it. At the default width an idle tick asks 4.6 ms
 # of its 10 ms window, and with 8 live rooms 7 % of the ticks are late (a dozen
 # after each 2 s checkpoint of the supervisor) and the governor stayed at 0 in
-# 2 runs of 3; with 32 rooms, their 96 clients in this interpreter, it asks
+# 2 runs of 5; with 32 rooms, their 96 clients in this interpreter, it asks
 # 8 ms and more, two thirds of the ticks are late and the governor sheds.
 # At cfg4 width an idle tick costs 12-17 ms (every one of 1,024 room rows is
 # staged and unpacked whether live or not) and the supervisor's 2 s checkpoint
@@ -873,7 +873,7 @@ def main(argv: list[str] | None = None) -> int:
         # host. Only the phase at the default tick may be shed: the
         # supervisor's 2 s checkpoint alone makes a dozen 10 ms ticks late,
         # and on a slow stretch of the shared host that is the governor's 20
-        # in a row (1 run in 3 at 8 rooms; my chip calls 26-27, PR 25).
+        # in a row (3 runs in 5 at 8 rooms; my chip calls 26-29, PR 25).
         for name, plane, tick_ms, rooms, lead, ticks in (
             ("default", TOY if toy else SERVE_DEFAULT,
              4 * DEFAULT_TICK_MS if toy else DEFAULT_TICK_MS,
