@@ -34,6 +34,7 @@ import jax
 import numpy as np
 
 from livekit_server_tpu.models import plane
+from livekit_server_tpu.runtime import trace as trace_mod
 from livekit_server_tpu.runtime.ingest import IngestBuffer
 from livekit_server_tpu.runtime.munge import HostMunger
 from livekit_server_tpu.runtime.probe import PAD_BYTES, ProbeController
@@ -351,6 +352,19 @@ class StagedTick:
     upload_t0: float = 0.0
     upload_s: float = 0.0
     device_t0: float = 0.0
+    # The loop's waits before the dispatch (the sleep to the edge, the
+    # wait for state_lock), the device call's parts on the worker thread
+    # (laid end to end from device_t0, read off their boundaries) and how
+    # long the finished step waited for the event loop.
+    sleep_t0: float = 0.0
+    sleep_s: float = 0.0
+    lock_t0: float = 0.0
+    lock_s: float = 0.0
+    dispatch_s: float = 0.0
+    fetch_s: float = 0.0
+    mirror_s: float = 0.0
+    audit_s: float = 0.0
+    handoff_s: float = 0.0
 
 
 class PlaneRuntime:
@@ -491,9 +505,9 @@ class PlaneRuntime:
         }
         from collections import deque
 
-        self.recent_tick_s: deque = deque(maxlen=120)  # /debug/ticks window
         # Per-tick stage breakdown dicts (idx/stage_ms/device_ms/fanout_ms/
-        # total_ms/depth/late) — the /debug/ticks pipeline view.
+        # total_ms/depth/late and the waits between them) — the
+        # /debug/ticks pipeline view.
         self.recent_ticks: deque = deque(maxlen=120)
         # Tick-edge sleep calibration: measured coarse-sleep overshoot
         # for this host (seconds; <0 = not yet calibrated — falls back
@@ -517,14 +531,14 @@ class PlaneRuntime:
         # Flight-recorder tracing plane (runtime/trace.py): fixed ring of
         # per-tick span records, the sampled wire-latency attribution
         # stage decomposer, and the per-room black-box event recorder.
-        # trace/wire_stages are None when disabled; the black box is
+        # trace/wire_stages are None when disabled, and the spans then
+        # stamp without annotating or totalling; the black box is
         # always on (cold-path emits only, bounded per-room rings).
-        from livekit_server_tpu.runtime import trace as trace_mod
-
+        self.spans = trace_mod.Spans(trace_enabled)
         self.trace = None
         self.wire_stages = None
         if trace_enabled:
-            self.trace = trace_mod.TickTraceRing(trace_ring_ticks)
+            self.trace = trace_mod.TickTraceRing(trace_ring_ticks, self.spans)
             self.wire_stages = trace_mod.LatencyAttribution(trace_sample_every)
         self.blackbox = trace_mod.BlackBox(R, blackbox_events)
 
@@ -773,36 +787,55 @@ class PlaneRuntime:
         injected stall so a woken stale thread never consumes — or
         donates — state the restart already restored."""
         epoch = self.run_epoch
-        t0 = time.perf_counter()
-        st.device_t0 = t0
-        if self.fault is not None:
-            self.fault.maybe_stall()
-        if epoch != self.run_epoch:
-            return None
-        if self.fault is not None:
-            self.fault.maybe_bitflip(self, st.idx)
-        if self._mesh is not None:
-            state, out = self._step(self.state, st.inp)
-            # The mesh path's one per-tick drain: outputs land host-side
-            # here (the non-mesh path drains in _unpack_outputs instead).
-            out = jax.tree.map(np.asarray, out)  # graftcheck: disable=GC12
-        else:
-            state, buf = self._step(self.state, *st.packed)
-            out = self._unpack_outputs(buf)
-        if epoch != self.run_epoch:
-            return None  # restarted mid-step: result belongs to a dead run
-        self.state = state
-        if self.express is not None and self.express.wants_mirror():
-            # Post-commit selector mirror for the express lane: fetched
-            # here (same device sync as `out`), consumed at the next
-            # retier on the event loop — decisions made from it are
-            # bounded ≤1 tick stale.
-            self.express.post_mirror(*self._sel_mirror(state))
-        if self.integrity is not None:
-            # Audit the committed state on the cadence; the fetched mask
-            # is a few dozen bytes riding the same device sync as `out`.
-            self.integrity.maybe_audit(st.idx)
-        st.device_s = time.perf_counter() - t0
+        span = self.spans.span
+        with span(trace_mod.SP_DEVICE_CALL) as call:
+            st.device_t0 = call.t0
+            if self.fault is not None:
+                self.fault.maybe_stall()
+            if epoch != self.run_epoch:
+                return None
+            if self.fault is not None:
+                self.fault.maybe_bitflip(self, st.idx)
+            # The call's parts are read off their boundaries, so they
+            # add up to it: a wait for the interpreter lock between two
+            # of them counts to the later one. dispatch: until the jitted
+            # call returns (asynchronous on a TPU).
+            with span(trace_mod.SP_DEVICE_DISPATCH) as part:
+                if self._mesh is not None:
+                    state, out = self._step(self.state, st.inp)
+                else:
+                    state, buf = self._step(self.state, *st.packed)
+            st.dispatch_s = part.t1 - call.t0
+            # fetch: the wait for the device, the copy out and the commit.
+            with span(trace_mod.SP_DEVICE_FETCH) as part:
+                if self._mesh is not None:
+                    # The mesh path's one per-tick drain: outputs land
+                    # host-side here (the non-mesh path drains in
+                    # _unpack_outputs instead).
+                    out = jax.tree.map(np.asarray, out)  # graftcheck: disable=GC12
+                else:
+                    out = self._unpack_outputs(buf)
+                if epoch != self.run_epoch:
+                    return None  # restarted mid-step: result belongs to a dead run
+                self.state = state
+            done = part.t1
+            st.fetch_s = done - call.t0 - st.dispatch_s
+            if self.express is not None and self.express.wants_mirror():
+                # Post-commit selector mirror for the express lane: fetched
+                # here (same device sync as `out`), consumed at the next
+                # retier on the event loop — decisions made from it are
+                # bounded ≤1 tick stale.
+                with span(trace_mod.SP_DEVICE_MIRROR) as part:
+                    self.express.post_mirror(*self._sel_mirror(state))
+                st.mirror_s = part.t1 - done
+                done = part.t1
+            if self.integrity is not None:
+                # Audit the committed state on the cadence; the fetched mask
+                # is a few dozen bytes riding the same device sync as `out`.
+                with span(trace_mod.SP_DEVICE_AUDIT) as part:
+                    self.integrity.maybe_audit(st.idx)
+                st.audit_s = part.t1 - done
+        st.device_s = call.dt
         return out
 
     def _stage_host(self) -> StagedTick:
@@ -812,43 +845,46 @@ class PlaneRuntime:
         it needs no lock and can overlap an in-flight device step. Probe
         scheduling happens later, at dispatch (_schedule_probe), where the
         freshest device mirrors are available."""
-        t0 = time.perf_counter()
-        idx = self.tick_index
-        self.tick_index += 1
-        # Close the quality/stats window about once per second
-        # (connectionquality windows; room.go:1318 worker cadence).
-        q_ticks = max(1, 1000 // self.tick_ms)
-        roll = (idx + 1) % q_ticks == 0
-        ex_rows = ex_words = ex_log = None
-        retier_s = 0.0
-        if self.express is not None:
-            # Tier boundary, in the same synchronous event-loop slice as
-            # the drain (atomic w.r.t. arrivals and migration freezes):
-            # close the ending window, re-tier, and take over the closing
-            # window for freshly promoted rooms. Returns the rooms whose
-            # fast-path subscriber bits this tick's fan-out must skip.
-            r0 = time.perf_counter()
-            ex_rows, ex_words, ex_log = self.express.tick_boundary(self.ingest)
-            retier_s = time.perf_counter() - r0
-        inp, payloads = self.ingest.drain(
-            roll_quality=roll, tick_index=idx,
-            reuse_fields=(self._mesh is None),
-        )
-        # Retain the slab for the RTX window: replay keys minted this tick
-        # reference slot (tick % SLAB_WINDOW) until it recycles.
-        self._slab_history[idx % plane.SLAB_WINDOW] = payloads
-        packed = None
-        if self._mesh is None:
-            # Pack here — NOT in the worker — so the drained staging set's
-            # zero-copy field views are consumed before the set recycles,
-            # and the packing memcpys overlap the previous device step.
-            packed = self._pack_inputs(inp)
-        st = StagedTick(inp=inp, payloads=payloads, idx=idx, roll=roll,
-                        packed=packed, express_rows=ex_rows,
-                        express_words=ex_words, express_log=ex_log)
-        st.stage_t0 = t0
+        with self.spans.span(trace_mod.SP_STAGE_HOST) as stage:
+            idx = self.tick_index
+            self.tick_index += 1
+            # Close the quality/stats window about once per second
+            # (connectionquality windows; room.go:1318 worker cadence).
+            q_ticks = max(1, 1000 // self.tick_ms)
+            roll = (idx + 1) % q_ticks == 0
+            ex_rows = ex_words = ex_log = None
+            retier_s = 0.0
+            if self.express is not None:
+                # Tier boundary, in the same synchronous event-loop slice
+                # as the drain (atomic w.r.t. arrivals and migration
+                # freezes): close the ending window, re-tier, and take
+                # over the closing window for freshly promoted rooms.
+                # Returns the rooms whose fast-path subscriber bits this
+                # tick's fan-out must skip.
+                with self.spans.span(trace_mod.SP_STAGE_RETIER) as retier:
+                    ex_rows, ex_words, ex_log = self.express.tick_boundary(
+                        self.ingest)
+                retier_s = retier.dt
+            inp, payloads = self.ingest.drain(
+                roll_quality=roll, tick_index=idx,
+                reuse_fields=(self._mesh is None),
+            )
+            # Retain the slab for the RTX window: replay keys minted this
+            # tick reference slot (tick % SLAB_WINDOW) until it recycles.
+            self._slab_history[idx % plane.SLAB_WINDOW] = payloads
+            packed = None
+            if self._mesh is None:
+                # Pack here — NOT in the worker — so the drained staging
+                # set's zero-copy field views are consumed before the set
+                # recycles, and the packing memcpys overlap the previous
+                # device step.
+                packed = self._pack_inputs(inp)
+            st = StagedTick(inp=inp, payloads=payloads, idx=idx, roll=roll,
+                            packed=packed, express_rows=ex_rows,
+                            express_words=ex_words, express_log=ex_log)
+        st.stage_t0 = stage.t0
         st.retier_s = retier_s
-        st.stage_s = time.perf_counter() - t0
+        st.stage_s = stage.dt
         return st
 
     def _schedule_probe(self, st: StagedTick) -> None:
@@ -899,12 +935,12 @@ class PlaneRuntime:
         lateness is judged against the OWNING tick's deadline (dispatch
         edge + (1 + depth) periods), checked after the delivery callbacks
         have actually run."""
-        c0 = time.perf_counter()
-        result = self._fan_out(
-            out, st.payloads, st.inp, 0.0, st.idx,
-            express=(st.express_rows, st.express_words, st.express_log),
-        )
-        fanout_s = time.perf_counter() - c0
+        with self.spans.span(trace_mod.SP_FANOUT_ASSEMBLE) as assemble:
+            result = self._fan_out(
+                out, st.payloads, st.inp, 0.0, st.idx,
+                express=(st.express_rows, st.express_words, st.express_log),
+            )
+        c0, fanout_s = assemble.t0, assemble.dt
         # Attribution stamps for the wire-latency stage decomposer: the
         # egress consumer (udp.send_egress_batch's do_send — possibly on
         # a pacer thread) reads these off the batch, so they must land
@@ -913,13 +949,14 @@ class PlaneRuntime:
         result.egress_batch.t_device_end = st.device_t0 + st.device_s
         result.tick_s = st.stage_s + st.device_s + fanout_s
         result.quality_window_closed = st.roll
-        self.recent_tick_s.append(round(result.tick_s, 5))
         self.stats["ticks"] += 1
         self.stats["fwd_packets"] += result.fwd_packets
         self.stats["fwd_bytes"] += result.fwd_bytes
         self.stats["stage_s"] += st.stage_s
         self.stats["device_s"] += st.device_s
         self.stats["fanout_s"] += fanout_s
+        # The callbacks may await (a stamp pair, no annotation: one held
+        # across an await would take in other coroutines' spans).
         s0 = time.perf_counter()
         for cb in self._on_tick:
             r = cb(result)
@@ -946,6 +983,23 @@ class PlaneRuntime:
                 else result.tick_s), 3),
             "late": late,
             "edge_overshoot_us": round(st.edge_over_us, 1),
+            # Where the tick waited, and the parts of the stages above:
+            # asleep to the edge (the rx handlers run in it), edge to the
+            # worker thread's first statement (lock_wait and upload lie
+            # in it), the device call's dispatch and fetch, the finished
+            # step's wait for the event loop, then for its deferred
+            # fan-out (a tick deep by design), and the send callbacks.
+            "sleep_ms": round(st.sleep_s * 1000.0, 3),
+            "dispatch_delay_ms": round(
+                trace_mod.between(st.edge, st.device_t0) * 1000.0, 3),
+            "lock_wait_ms": round(st.lock_s * 1000.0, 3),
+            "upload_ms": round(st.upload_s * 1000.0, 3),
+            "device_dispatch_ms": round(st.dispatch_s * 1000.0, 3),
+            "device_fetch_ms": round(st.fetch_s * 1000.0, 3),
+            "handoff_ms": round(st.handoff_s * 1000.0, 3),
+            "egress_wait_ms": round(trace_mod.between(
+                st.device_t0 + st.device_s, c0) * 1000.0, 3),
+            "send_ms": round(send_s * 1000.0, 3),
         }
         # Per-shard egress timing: the send callbacks above just ran, so
         # the plane's last-send snapshot is THIS tick's (munge likewise).
@@ -965,7 +1019,11 @@ class PlaneRuntime:
                 st.idx, st.edge, st.stage_t0, st.stage_s, st.retier_s,
                 st.upload_t0, st.upload_s, st.device_t0, st.device_s,
                 c0, fanout_s, send_s, st.edge_over_us, st.depth, late,
-                kernel_s=st.kernel_s,
+                kernel_s=st.kernel_s, sleep_t0=st.sleep_t0,
+                sleep_s=st.sleep_s, lock_t0=st.lock_t0, lock_s=st.lock_s,
+                dispatch_s=st.dispatch_s,
+                fetch_s=st.fetch_s, mirror_s=st.mirror_s,
+                audit_s=st.audit_s, handoff_s=st.handoff_s,
             )
             if ep.last_send:
                 shards = ep.last_send.get("shards", ())
@@ -1018,11 +1076,15 @@ class PlaneRuntime:
         # migration) must never observe donated-and-deleted buffers.
         st = self._stage_host()
         self._schedule_probe(st)
+        st.lock_t0 = time.perf_counter()
         async with self.state_lock:
-            st.upload_t0 = time.perf_counter()
-            self._upload_ctrl()
-            st.upload_s = time.perf_counter() - st.upload_t0
+            with self.spans.span(trace_mod.SP_CTRL_UPLOAD) as upload:
+                self._upload_ctrl()
+            st.lock_s = upload.t0 - st.lock_t0
+            st.upload_t0, st.upload_s = upload.t0, upload.dt
             out = await loop.run_in_executor(self._executor, self._device_step, st)
+            st.handoff_s = trace_mod.between(
+                st.device_t0 + st.device_s, time.perf_counter())
         if out is None:
             raise asyncio.CancelledError("device step abandoned by restart")
         self._mirror_probe_inputs(out)
@@ -1339,7 +1401,9 @@ class PlaneRuntime:
                     staged.edge = next_at
                     staged.deadline = next_at + (1 + depth) * period
                     self._schedule_probe(staged)
+                sleep_t0 = time.perf_counter()
                 await self._sleep_until(next_at)
+                sleep_s = time.perf_counter() - sleep_t0
                 if self.integrity is not None and self.integrity._pending_repair:
                     # Drain the row-repair queue filled by the last audit,
                     # at the window edge and OUTSIDE the lock region below:
@@ -1366,17 +1430,20 @@ class PlaneRuntime:
                     self._schedule_probe(staged)
                 cur, staged = staged, None
                 cur.edge_over_us = self._edge_overshoot_us
+                cur.sleep_t0, cur.sleep_s = sleep_t0, sleep_s
                 if self.ingest.frozen_rows:
                     # A migration freeze can land during the sleep, after
                     # the pre-edge probe scheduling: re-zero frozen rows'
                     # probe padding at dispatch (pads advance munger
                     # lanes; a frozen row must stay at its snapshot).
                     np.asarray(cur.inp.pad_num)[list(self.ingest.frozen_rows)] = 0
+                cur.lock_t0 = time.perf_counter()
                 await self.state_lock.acquire()
                 try:
-                    cur.upload_t0 = time.perf_counter()
-                    self._upload_ctrl()
-                    cur.upload_s = time.perf_counter() - cur.upload_t0
+                    with self.spans.span(trace_mod.SP_CTRL_UPLOAD) as upload:
+                        self._upload_ctrl()
+                    cur.lock_s = upload.t0 - cur.lock_t0
+                    cur.upload_t0, cur.upload_s = upload.t0, upload.dt
                     fut = loop.run_in_executor(self._executor, self._device_step, cur)
                     if pending is not None:
                         pending_task = self._complete_task = asyncio.ensure_future(
@@ -1394,6 +1461,8 @@ class PlaneRuntime:
                     # Fan-out N-1 (the task above) and any arriving-packet
                     # handlers run on the event loop during this await.
                     out = await fut
+                    cur.handoff_s = trace_mod.between(
+                        cur.device_t0 + cur.device_s, time.perf_counter())
                 finally:
                     self.state_lock.release()
                 if out is None:

@@ -42,6 +42,7 @@ import time
 from collections import deque
 from typing import Any, Awaitable, Callable
 
+from livekit_server_tpu.runtime import trace
 from livekit_server_tpu.utils.backoff import BackoffPolicy
 from livekit_server_tpu.utils.logger import Logger
 
@@ -135,15 +136,25 @@ class PlaneSupervisor:
         has the arrays mid-flight. The snapshot is encoded + checksummed
         into the generation ring; the corrupt_ckpt fault seam damages the
         encoded bytes here, exactly where real bit rot would land."""
+        spans = self.runtime.spans
+        t0 = time.perf_counter()
         async with self.runtime.state_lock:
-            self.last_snapshot = self.runtime.snapshot()
-        blob = self.runtime.encode_snapshot(self.last_snapshot)
+            # The serving loop's `loop/lock_wait` is this span, seen from
+            # the tick that wanted the lock.
+            with spans.span(trace.SP_CKPT_SNAPSHOT):
+                self.last_snapshot = self.runtime.snapshot()
+        with spans.span(trace.SP_CKPT_ENCODE):
+            blob = self.runtime.encode_snapshot(self.last_snapshot)
         fault = getattr(self.runtime, "fault", None)
         if fault is not None:
             blob = fault.corrupt_ckpt(blob)
         self._gens.appendleft(blob)
         if self.room_checkpoint_cb is not None:
+            c0 = time.perf_counter()
             await self.room_checkpoint_cb()
+            spans.add(trace.SP_CKPT_CALLBACK, time.perf_counter() - c0)
+        # The whole and the callback cross awaits: stamp pairs.
+        spans.add(trace.SP_CHECKPOINT, time.perf_counter() - t0)
 
     def last_good_snapshot(self) -> dict[str, Any] | None:
         """Newest checkpoint generation that verifies, decoded — the

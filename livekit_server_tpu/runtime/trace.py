@@ -26,6 +26,18 @@ discipline at the call sites):
   for /debug/blackbox/{room}) on quarantine, repair failure, supervisor
   restart, migration rollback, or a NACK storm — the post-mortem no
   longer depends on whatever counters happened to be scraped.
+- **Spans** — the named host spans of the serving loop (`SPANS`), each
+  with cumulative totals (`n`, `items`, `busy_s`, `max_s`) served at
+  /debug/rooms. `Spans.span(id)` is the one helper: a
+  `jax.profiler.TraceAnnotation("sfu/<name>")`, so that a profiler
+  session holds the span beside the device's operations on the
+  profiler's own clock, and a `perf_counter` stamp pair. Spans of a
+  tick are totalled from the ring's record (`TickTraceRing.record_tick`)
+  so totals and ring agree tick for tick; the checkpoint's spans
+  total themselves on exit. A span whose ends lie on two
+  threads, or that crosses an `await`, is a stamp pair only
+  (`Spans.add`): a `TraceAnnotation` is a per-thread stack, and other
+  coroutines' spans would interleave with one held across an `await`.
 """
 
 from __future__ import annotations
@@ -34,6 +46,7 @@ import threading
 import time
 from typing import Any
 
+import jax
 import numpy as np
 
 # Egress-shard lanes a tick record can hold (EgressPlane caps at 16).
@@ -82,17 +95,125 @@ STAGES = ("staging", "device", "egress", "total", "express")
 _S_STAGING, _S_DEVICE, _S_EGRESS, _S_TOTAL, _S_EXPRESS = range(len(STAGES))
 
 
+# -- host spans ---------------------------------------------------------------
+# Names as they appear in a profiler trace (`sfu/<name>`) and under
+# /debug/rooms `spans`. `/` and never `.`: the benchmark's readers split a
+# path on dots. The spans of a tick come first (ids below N_TICK_SPANS):
+# `record_tick` totals those, `Spans.span` totals the rest on exit.
+SPANS = (
+    "loop/sleep", "loop/dispatch_delay", "loop/lock_wait", "loop/handoff",
+    "stage/host", "stage/retier", "ctrl/upload",
+    "device/call", "device/dispatch", "device/fetch", "device/mirror",
+    "device/audit",
+    "egress/wait", "fanout/assemble", "egress/send",
+    "rx",
+    "supervisor/checkpoint", "supervisor/checkpoint/snapshot",
+    "supervisor/checkpoint/encode", "supervisor/checkpoint/callback",
+)
+(SP_SLEEP, SP_DISPATCH_DELAY, SP_LOCK_WAIT, SP_HANDOFF,
+ SP_STAGE_HOST, SP_STAGE_RETIER, SP_CTRL_UPLOAD,
+ SP_DEVICE_CALL, SP_DEVICE_DISPATCH, SP_DEVICE_FETCH, SP_DEVICE_MIRROR,
+ SP_DEVICE_AUDIT,
+ SP_EGRESS_WAIT, SP_FANOUT_ASSEMBLE, SP_EGRESS_SEND,
+ SP_RX,
+ SP_CHECKPOINT, SP_CKPT_SNAPSHOT, SP_CKPT_ENCODE, SP_CKPT_CALLBACK,
+ ) = range(len(SPANS))
+N_TICK_SPANS = SP_RX
+_ANNOTATIONS = tuple("sfu/" + name for name in SPANS)
+
+
+def between(t_from: float, t_to: float) -> float:
+    """Seconds from one stamp to a later one; 0 where either was not
+    taken (0.0) or they lie the other way round (stamps of two threads)."""
+    return t_to - t_from if t_to > t_from > 0.0 else 0.0
+
+
+class _Span:
+    """One use of `Spans.span`: `t0` on entry, `t1` and `dt` on exit
+    (`perf_counter`), whether or not tracing is on — the tick record
+    reads them either way."""
+
+    __slots__ = ("_spans", "_sid", "_annotation", "t0", "t1", "dt")
+
+    def __init__(self, spans: "Spans", sid: int):
+        self._spans = spans
+        self._sid = sid
+        self._annotation = None
+        self.t0 = self.t1 = self.dt = 0.0
+
+    def __enter__(self) -> "_Span":
+        if self._spans.enabled:
+            # A C++ no-op unless a profiler session is on.
+            self._annotation = jax.profiler.TraceAnnotation(
+                _ANNOTATIONS[self._sid])
+            self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self.dt = self.t1 - self.t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        if self._sid >= N_TICK_SPANS:
+            self._spans.add(self._sid, self.dt)
+
+
+class Spans:
+    """Cumulative totals of the named host spans: preallocated lists,
+    scalar stores only (the GC07 rule the ring keeps). Each span has one
+    writer thread (`device/*` reach here through the ring's record, on
+    the event loop), so no lock. `enabled` False (`trace.enabled:
+    false`): no annotation, no totals, `snapshot()` empty."""
+
+    def __init__(self, enabled: bool = True):
+        self.enabled = bool(enabled)
+        k = len(SPANS)
+        self.n = [0] * k
+        self.items = [0] * k
+        self.busy_s = [0.0] * k
+        self.max_s = [0.0] * k
+
+    def span(self, sid: int) -> _Span:
+        return _Span(self, sid)
+
+    def add(self, sid: int, dt: float, items: int = 0) -> None:
+        if not self.enabled:
+            return
+        self.n[sid] += 1
+        self.items[sid] += items
+        self.busy_s[sid] += dt
+        if dt > self.max_s[sid]:
+            self.max_s[sid] = dt
+
+    def snapshot(self) -> dict[str, dict[str, float]]:
+        """{name: {n, items, busy_s, max_ms}}, every span, so that a
+        reader by difference finds its key at both ends of a window
+        (cold path: /debug/rooms)."""
+        if not self.enabled:
+            return {}
+        return {
+            name: {"n": self.n[i], "items": self.items[i],
+                   "busy_s": round(self.busy_s[i], 6),
+                   "max_ms": round(self.max_s[i] * 1e3, 3)}
+            for i, name in enumerate(SPANS)
+        }
+
+
 class TickTraceRing:
     """Fixed ring of per-tick span records, preallocated columns.
 
     Single writer (the event loop's `_complete`); `record_tick` and
     `set_shard` are scalar stores only — the GC07-checked bounded API.
-    `snapshot` (cold path: /debug/trace, tools/trace) materializes the
-    newest records as dicts for the exporter."""
+    `record_tick` also feeds `spans` (the cumulative totals) from the
+    same values, in this one place, so totals and ring agree tick for
+    tick. `snapshot` (cold path: /debug/trace, tools/trace)
+    materializes the newest records as dicts for the exporter."""
 
-    def __init__(self, cap: int = 512):
+    def __init__(self, cap: int = 512, spans: Spans | None = None):
         cap = max(8, int(cap))
         self.cap = cap
+        self.spans = spans
         self.idx = np.full(cap, -1, np.int64)
         self.edge = np.zeros(cap, np.float64)
         self.stage_t0 = np.zeros(cap, np.float64)
@@ -108,6 +229,19 @@ class TickTraceRing:
         self.fanout_t0 = np.zeros(cap, np.float64)
         self.fanout_dur = np.zeros(cap, np.float64)
         self.send_dur = np.zeros(cap, np.float64)
+        # The loop's waits and the device call's parts (all 0 where the
+        # path has none: step_once does not sleep, the stock tick has no
+        # mirror). The device call's parts are laid end to end from
+        # device_t0; the hand-off starts where the device span ends.
+        self.sleep_t0 = np.zeros(cap, np.float64)
+        self.sleep_dur = np.zeros(cap, np.float64)
+        self.lock_t0 = np.zeros(cap, np.float64)
+        self.lock_dur = np.zeros(cap, np.float64)
+        self.dispatch_dur = np.zeros(cap, np.float64)
+        self.fetch_dur = np.zeros(cap, np.float64)
+        self.mirror_dur = np.zeros(cap, np.float64)
+        self.audit_dur = np.zeros(cap, np.float64)
+        self.handoff_dur = np.zeros(cap, np.float64)
         self.wake_over_us = np.zeros(cap, np.float32)
         self.depth = np.zeros(cap, np.int8)
         self.late = np.zeros(cap, np.int8)
@@ -122,7 +256,12 @@ class TickTraceRing:
                     upload_s: float, device_t0: float, device_s: float,
                     fanout_t0: float, fanout_s: float, send_s: float,
                     wake_over_us: float, depth: int, late: bool,
-                    kernel_s: float = 0.0) -> int:
+                    kernel_s: float = 0.0, sleep_t0: float = 0.0,
+                    sleep_s: float = 0.0, lock_t0: float = 0.0,
+                    lock_s: float = 0.0,
+                    dispatch_s: float = 0.0, fetch_s: float = 0.0,
+                    mirror_s: float = 0.0, audit_s: float = 0.0,
+                    handoff_s: float = 0.0) -> int:
         slot = self._pos
         self.idx[slot] = idx
         self.edge[slot] = edge
@@ -137,12 +276,48 @@ class TickTraceRing:
         self.fanout_t0[slot] = fanout_t0
         self.fanout_dur[slot] = fanout_s
         self.send_dur[slot] = send_s
+        self.sleep_t0[slot] = sleep_t0
+        self.sleep_dur[slot] = sleep_s
+        self.lock_t0[slot] = lock_t0
+        self.lock_dur[slot] = lock_s
+        self.dispatch_dur[slot] = dispatch_s
+        self.fetch_dur[slot] = fetch_s
+        self.mirror_dur[slot] = mirror_s
+        self.audit_dur[slot] = audit_s
+        self.handoff_dur[slot] = handoff_s
         self.wake_over_us[slot] = wake_over_us
         self.depth[slot] = depth
         self.late[slot] = late
         self.n_shards[slot] = 0
         self._pos = (slot + 1) % self.cap
         self.recorded += 1
+        sp = self.spans
+        if sp is not None:
+            # A span that did not run in this tick (duration 0) is not
+            # counted; the two waits read off other stamps are.
+            device_end = device_t0 + device_s
+            sp.add(SP_STAGE_HOST, stage_s)
+            sp.add(SP_CTRL_UPLOAD, upload_s)
+            sp.add(SP_DEVICE_CALL, device_s)
+            sp.add(SP_FANOUT_ASSEMBLE, fanout_s)
+            sp.add(SP_EGRESS_SEND, send_s)
+            sp.add(SP_EGRESS_WAIT, between(device_end, fanout_t0))
+            if edge > 0.0:
+                sp.add(SP_DISPATCH_DELAY, between(edge, device_t0))
+            if sleep_s > 0.0:
+                sp.add(SP_SLEEP, sleep_s)
+            if lock_t0 > 0.0:
+                sp.add(SP_LOCK_WAIT, lock_s)
+            if retier_s > 0.0:
+                sp.add(SP_STAGE_RETIER, retier_s)
+            if dispatch_s > 0.0:
+                sp.add(SP_DEVICE_DISPATCH, dispatch_s)
+                sp.add(SP_DEVICE_FETCH, fetch_s)
+                sp.add(SP_HANDOFF, handoff_s)
+            if mirror_s > 0.0:
+                sp.add(SP_DEVICE_MIRROR, mirror_s)
+            if audit_s > 0.0:
+                sp.add(SP_DEVICE_AUDIT, audit_s)
         return slot
 
     def set_shard(self, slot: int, lane: int, munge_ms: float,
@@ -178,6 +353,15 @@ class TickTraceRing:
                 "fanout_t0": float(self.fanout_t0[slot]),
                 "fanout_s": float(self.fanout_dur[slot]),
                 "send_s": float(self.send_dur[slot]),
+                "sleep_t0": float(self.sleep_t0[slot]),
+                "sleep_s": float(self.sleep_dur[slot]),
+                "lock_t0": float(self.lock_t0[slot]),
+                "lock_s": float(self.lock_dur[slot]),
+                "dispatch_s": float(self.dispatch_dur[slot]),
+                "fetch_s": float(self.fetch_dur[slot]),
+                "mirror_s": float(self.mirror_dur[slot]),
+                "audit_s": float(self.audit_dur[slot]),
+                "handoff_s": float(self.handoff_dur[slot]),
                 "wake_over_us": float(self.wake_over_us[slot]),
                 "depth": int(self.depth[slot]),
                 "late": bool(self.late[slot]),
@@ -212,18 +396,26 @@ class LatencyAttribution:
         self.sample_every = max(1, int(sample_every))
         n = len(STAGES)
         self.ring = np.zeros((n, self.CAP), np.float32)
-        self.total = np.zeros(n, np.int64)       # lifetime samples pushed
+        self.total = np.zeros(n, np.int64)       # samples pushed since reset()
         self._drained = np.zeros(n, np.int64)    # consumed watermark
+        # Monotone pair per stage: samples ever pushed and their sum.
+        # reset() and drain() leave it alone, so a mean over any window
+        # reads by difference (/debug/rooms `wire_stages`).
+        self.pushed = np.zeros(n, np.int64)
+        self.sum_ms = np.zeros(n, np.float64)
         self._lock = threading.Lock()
 
     def _push(self, stage: int, vals_ms: np.ndarray) -> None:
         m = len(vals_ms)
         if not m:
             return
+        pushed, sum_ms = m, float(vals_ms.sum(dtype=np.float64))
         if m > self.CAP:
             vals_ms = vals_ms[-self.CAP:]
             m = self.CAP
         with self._lock:
+            self.pushed[stage] += pushed
+            self.sum_ms[stage] += sum_ms
             pos = int(self.total[stage]) % self.CAP
             end = pos + m
             if end <= self.CAP:
@@ -302,6 +494,16 @@ class LatencyAttribution:
                 self._drained[s] = total
                 out[name] = vals
         return out
+
+    def cumulative(self) -> dict[str, dict[str, float]]:
+        """{stage: {n, sum_ms}} since the process began, every stage
+        (cold path: /debug/rooms)."""
+        with self._lock:
+            return {
+                name: {"n": int(self.pushed[s]),
+                       "sum_ms": round(float(self.sum_ms[s]), 3)}
+                for s, name in enumerate(STAGES)
+            }
 
     def summary(self) -> dict[str, dict[str, float]]:
         """Exact percentiles over each stage's retained window (bench and

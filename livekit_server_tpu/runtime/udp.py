@@ -43,6 +43,7 @@ from livekit_server_tpu.runtime.crypto import (
     parse_key_id,
 )
 from livekit_server_tpu.runtime.ingest import IngestBuffer
+from livekit_server_tpu.runtime.trace import SP_RX
 
 VP8_PT = 96
 OPUS_PT = 111
@@ -539,6 +540,13 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
         # LatencyAttribution); attached by the server/bench alongside the
         # egress plane. None = no per-stage attribution.
         self.wire_stages = None
+        # Span totals (runtime/trace.py Spans), attached beside it: the
+        # receive path adds one `rx` record a wake-up — a call of
+        # feed_batch, or of _flush_rx where the asyncio endpoint carries
+        # the socket — with the datagrams it brought and the time from
+        # the batch's arrival stamp to its last packet staged. No span
+        # object here: this runs thousands of times a second.
+        self.spans = None
         # Express lane (runtime/express.py): attached by the room manager
         # when plane.express_max_subs > 0; rx_batch hands each receive
         # batch to it right after staging.
@@ -1060,6 +1068,8 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
                     np.zeros(len(ci), np.int64), None, None, now_ms, t_rx,
                     gateway_only=self.require_encryption,
                 )
+        if self.spans is not None:
+            self.spans.add(SP_RX, time.perf_counter() - t_rx, int(n))
 
     def _classify_and_process(self, blob, offs, lens, addr_code, sess_code,
                               sessions, kid, now_ms, t_rx: float = 0.0,
@@ -1494,6 +1504,7 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
         pending, self._rx_pending = self._rx_pending, []
         if not pending:
             return
+        t_rx = time.perf_counter()
         now_ms = asyncio.get_event_loop().time() * 1000.0
         n = len(pending)
         lengths = np.fromiter((len(d) for d, _, _ in pending), np.int32, n)
@@ -1510,6 +1521,9 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
         self._process_media_arrays(
             blob, offsets, lengths, addr_code, sess_code, now_ms
         )
+        if self.spans is not None:
+            # (the per-datagram open in datagram_received is not in it)
+            self.spans.add(SP_RX, time.perf_counter() - t_rx, n)
 
     def _gateway_media(self, pkts: list, t_rx: float) -> None:
         """SRTP datagrams from latched gateway peers → per-packet

@@ -25,6 +25,7 @@ from livekit_server_tpu.routing import (
 )
 from livekit_server_tpu.routing.node import sample_system_stats
 from livekit_server_tpu.routing.selector import NoNodesAvailable
+from livekit_server_tpu.runtime.compile_ledger import LEDGER
 from livekit_server_tpu.service.roommanager import RoomManager
 from livekit_server_tpu.service.roomservice import RoomServiceAPI
 from livekit_server_tpu.service.rtcservice import RTCService
@@ -89,6 +90,8 @@ class LivekitServer:
         self._sites: list[web.TCPSite] = []
         self._stats_task: asyncio.Task | None = None
         self.started_at = 0.0
+        # {phase: {wall_s, compile_s}} of start-up (/debug/compiles).
+        self.startup: dict[str, dict[str, float]] = {}
 
     # -- selector ---------------------------------------------------------
     def select_node(self) -> LocalNode | None:
@@ -179,14 +182,13 @@ class LivekitServer:
     async def debug_ticks(self, request: web.Request) -> web.Response:
         """Recent tick timing breakdown (§5.1 profiling surface): totals
         plus the per-tick pipeline-stage split (stage/device/fanout ms,
-        depth, late) so an overlap regression is visible per stage rather
-        than inferred from host_ms_per_tick."""
+        depth, late, and where the tick waited) so an overlap regression
+        is visible per stage rather than inferred from host_ms_per_tick."""
         rt = self.room_manager.runtime
         body = {
             "tick_ms": rt.tick_ms,
             "stats": rt.stats,
             "pipeline_depth": 0 if rt.low_latency else 1,
-            "recent_tick_s": list(getattr(rt, "recent_tick_s", [])),
             "recent_ticks": list(getattr(rt, "recent_ticks", [])),
         }
         body["sleep_bias_us"] = round(
@@ -431,9 +433,16 @@ class LivekitServer:
         events. `xla_compiles_post_warmup` > 0 means the steady-state
         tick path is retracing — a shape escaped the pow2 buckets or a
         static arg lost cache identity (GC11's runtime half)."""
-        return web.json_response(
-            self.room_manager.runtime.compile_ledger.snapshot()
-        )
+        body = self.room_manager.runtime.compile_ledger.snapshot()
+        # Start-up by phase: the wall of each and the ledger's compile
+        # seconds inside it; warm_exec_s is what of start-up is not
+        # compilation (tracing, cache reads, first executions, sockets).
+        body["startup"] = dict(self.startup)
+        if self.startup:
+            body["startup"]["warm_exec_s"] = round(sum(
+                ph["wall_s"] - ph["compile_s"] for ph in self.startup.values()
+            ), 3)
+        return web.json_response(body)
 
     async def debug_analytics(self, request: web.Request) -> web.Response:
         """Recent per-track analytics records (statsworker.go stream seat)."""
@@ -462,10 +471,29 @@ class LivekitServer:
                 },
                 "plane": rm.runtime.stats,
                 "ingest_dropped": rm.runtime.ingest.dropped,
+                # Cumulative, for readers by difference over a window:
+                # the host spans' totals and the sampled wire-latency
+                # stages' sums (both empty with trace.enabled false).
+                "spans": rm.runtime.spans.snapshot(),
+                "wire_stages": (
+                    rm.runtime.wire_stages.cumulative()
+                    if rm.runtime.wire_stages is not None else {}
+                ),
             }
         )
 
     # -- lifecycle --------------------------------------------------------
+    def startup_phase(self, phase: str, mark: tuple[float, float]) -> None:
+        """Close one phase of start-up, begun at `mark`
+        (`startup_mark()`): its wall and the compile ledger's seconds
+        inside it, into `self.startup` (/debug/compiles). A stamp pair:
+        the phases cross awaits."""
+        t0, compile_ms0 = mark
+        self.startup[phase] = {
+            "wall_s": round(time.perf_counter() - t0, 3),
+            "compile_s": round((LEDGER.total_ms - compile_ms0) / 1e3, 3),
+        }
+
     async def start(self) -> None:
         # Identify this node's bus connection to the BusServer before any
         # other op: the partition-injection harness severs/heals by node
@@ -479,16 +507,21 @@ class LivekitServer:
         # Warm-compile the media-plane step before accepting traffic so the
         # first tick doesn't stall the event loop mid-session (XLA compiles
         # once per (shapes, params); later ticks hit the cache).
+        mark = startup_mark()
         await self.room_manager.runtime.step_once()
+        self.startup_phase("warm_step", mark)
         # ...and the programs a join, a migration or a repair would
         # otherwise compile mid-session.
+        mark = startup_mark()
         async with self.room_manager.runtime.state_lock:
             self.room_manager.runtime.warm_compile()
+        self.startup_phase("warm_compile", mark)
         # Watermark for the recompile watchdog: anything XLA compiles
         # after this point is a steady-state retrace (surfaced at
         # /debug/compiles and livekit_xla_compiles_total).
         self.room_manager.runtime.mark_warm()
         # Native UDP media transport on the RTC port (rtc/config.go UDPMux).
+        mark = startup_mark()
         if self.config.rtc.udp_port:
             from livekit_server_tpu.runtime.udp import start_udp_transport
 
@@ -514,6 +547,7 @@ class LivekitServer:
                 self.room_manager.udp.wire_stages = (
                     self.room_manager.runtime.wire_stages
                 )
+                self.room_manager.udp.spans = self.room_manager.runtime.spans
                 # Express lane (plane.express_max_subs > 0): interactive
                 # rooms forward on packet arrival through this transport
                 # instead of the batched tick (runtime/express.py).
@@ -606,6 +640,7 @@ class LivekitServer:
                         pass  # relay port busy: direct path still works
             except OSError:
                 pass  # port busy: WS media path still works
+        self.startup_phase("udp_start", mark)
         await self.ioinfo.start()
         await self.room_api.start()
         self.room_manager.start()
@@ -681,8 +716,18 @@ async def connect_bus(config: Config):
     )
 
 
+def startup_mark() -> tuple[float, float]:
+    """(now, the compile ledger's milliseconds so far): where a phase
+    of start-up begins (`LivekitServer.startup_phase` closes it)."""
+    return time.perf_counter(), LEDGER.total_ms
+
+
 def create_server(config: Config, bus=None, mesh=None) -> LivekitServer:
     """The Wire graph (wire_gen.go InitializeServer) as explicit wiring."""
+    # The ledger counts from here, not from the runtime's construction:
+    # the plane's initial state compiles too.
+    LEDGER.install()
+    mark = startup_mark()
     node = LocalNode(region=config.region)
     sample_system_stats(node.stats)
     if bus is None and config.kv.kind == "memory":
@@ -704,4 +749,5 @@ def create_server(config: Config, bus=None, mesh=None) -> LivekitServer:
         # Drain-target ranking reuses the placement selector, so a drain
         # spreads rooms the same way the router places new ones.
         rm.migration.selector = server._selector
+    server.startup_phase("create_server", mark)
     return server

@@ -7,10 +7,18 @@ events with µs timestamps) that chrome://tracing and ui.perfetto.dev
 load directly:
 
   pid 1, one tid per pipeline lane:
-    loop    — stage_host (with the express retier nested inside),
-              ctrl_upload, and a tick_edge instant marker
-    device  — device_step
+    loop    — loop_sleep, lock_wait, ctrl_upload, stage_host (with the
+              express retier nested inside), and a tick_edge instant
+              marker: what the event loop's task did, in order
+    device  — device_step with its parts nested inside: device_dispatch,
+              device_fetch, device_mirror, device_audit
     fanout  — fan_out (munge+assemble) and egress_send (delivery cbs)
+    dispatch-wait — dispatch_delay: edge to the worker thread's first
+              statement (sleep overshoot, lock_wait and ctrl_upload lie
+              in it; its own lane, it overlaps loop_sleep's tail)
+    egress-wait — egress_wait: device end to the deferred fan-out, with
+              loop_handoff (device end to the loop's resumption) nested
+              at its head
     shard N — per-egress-shard munge/send walls, synthesized inside the
               fan-out/send windows
 
@@ -29,9 +37,13 @@ from typing import Any
 TID_LOOP = 1
 TID_DEVICE = 2
 TID_FANOUT = 3
+TID_DISPATCH_WAIT = 4
+TID_EGRESS_WAIT = 5
 TID_SHARD0 = 10  # shard i → tid TID_SHARD0 + i
 
-_LANE_NAMES = {TID_LOOP: "loop", TID_DEVICE: "device", TID_FANOUT: "fanout"}
+_LANE_NAMES = {TID_LOOP: "loop", TID_DEVICE: "device", TID_FANOUT: "fanout",
+               TID_DISPATCH_WAIT: "dispatch-wait",
+               TID_EGRESS_WAIT: "egress-wait"}
 
 
 def to_chrome(records: list[dict[str, Any]], tick_ms: int = 0) -> list[dict]:
@@ -41,7 +53,8 @@ def to_chrome(records: list[dict[str, Any]], tick_ms: int = 0) -> list[dict]:
     # Time base: earliest known timestamp in the window → ts 0.
     t0s = []
     for r in records:
-        for k in ("edge", "stage_t0", "upload_t0", "device_t0", "fanout_t0"):
+        for k in ("edge", "sleep_t0", "stage_t0", "upload_t0", "device_t0",
+                  "fanout_t0"):
             v = r.get(k, 0.0)
             if v > 0.0:
                 t0s.append(v)
@@ -55,10 +68,50 @@ def to_chrome(records: list[dict[str, Any]], tick_ms: int = 0) -> list[dict]:
 
     events: list[dict] = []
     shard_lanes = 0
+    # A wait of tick N+1 can begin before tick N's has ended (a tick
+    # dispatched a period late; a fan-out deferred past the next device
+    # step): on its lane each begins where the last one ended at the
+    # earliest, so the lane nests; `args.wait_us` keeps the whole.
+    wait_end = {TID_DISPATCH_WAIT: 0.0, TID_EGRESS_WAIT: 0.0}
+
+    def wait(name: str, tid: int, t_from: float, t_to: float, tick: int,
+             advance: bool = True) -> None:
+        start = max(t_from, wait_end[tid])
+        if t_from <= 0.0 or t_to <= start:
+            return
+        events.append({
+            "name": name, "ph": "X", "ts": us(start),
+            "dur": dur_us(t_to - start), "pid": 1, "tid": tid,
+            "args": {"tick": tick, "wait_us": dur_us(t_to - t_from)},
+        })
+        if advance:
+            wait_end[tid] = t_to
+
+    def child(name: str, tid: int, t0: float, dur: float, tick: int,
+              end: float | None = None) -> float:
+        """A span of the new columns, none where it did not run. As a
+        part nested in a span that ends at `end` it is clipped to that
+        (the parts' starts are laid end to end); → where the next
+        begins."""
+        if end is not None:
+            dur = min(dur, max(end - t0, 0.0))
+        if t0 > 0.0 and dur > 0.0:
+            events.append({
+                "name": name, "ph": "X", "ts": us(t0), "dur": dur_us(dur),
+                "pid": 1, "tid": tid, "args": {"tick": tick},
+            })
+        return t0 + dur
+
     for r in records:
         tick = r["tick"]
         args = {"tick": tick, "depth": r.get("depth", 0),
                 "late": bool(r.get("late", False))}
+        child("loop_sleep", TID_LOOP, r.get("sleep_t0", 0.0),
+              r.get("sleep_s", 0.0), tick)
+        child("lock_wait", TID_LOOP, r.get("lock_t0", 0.0),
+              r.get("lock_s", 0.0), tick)
+        wait("dispatch_delay", TID_DISPATCH_WAIT, r.get("edge", 0.0),
+             r.get("device_t0", 0.0), tick)
         if r.get("edge", 0.0) > 0.0:
             events.append({
                 "name": "tick_edge", "ph": "I", "s": "t",
@@ -94,15 +147,31 @@ def to_chrome(records: list[dict[str, Any]], tick_ms: int = 0) -> list[dict]:
                 "dur": dur_us(r.get("device_s", 0.0)),
                 "pid": 1, "tid": TID_DEVICE, "args": args,
             })
-            # Paged-kernel slice: the phase-0 decide dispatch nested at
-            # the head of the device span (0 when the stock tick ran).
-            if r.get("kernel_s", 0.0) > 0.0:
-                events.append({
-                    "name": "paged_kernel", "ph": "X",
-                    "ts": us(r["device_t0"]),
-                    "dur": dur_us(r["kernel_s"]),
-                    "pid": 1, "tid": TID_DEVICE, "args": {"tick": tick},
-                })
+            device_end = r["device_t0"] + r.get("device_s", 0.0)
+            # The call's parts, laid end to end from its start.
+            dispatch_s = r.get("dispatch_s", 0.0)
+            t = child("device_dispatch", TID_DEVICE, r["device_t0"],
+                      dispatch_s, tick, device_end)
+            t = child("device_fetch", TID_DEVICE, t,
+                      r.get("fetch_s", 0.0), tick, device_end)
+            t = child("device_mirror", TID_DEVICE, t,
+                      r.get("mirror_s", 0.0), tick, device_end)
+            child("device_audit", TID_DEVICE, t,
+                  r.get("audit_s", 0.0), tick, device_end)
+            # Paged-kernel slice: the phase-0 decide dispatch, at the
+            # head of the dispatch it is part of (of the device span in
+            # a record without parts; 0 when the stock tick ran).
+            child("paged_kernel", TID_DEVICE, r["device_t0"],
+                  r.get("kernel_s", 0.0), tick,
+                  r["device_t0"] + dispatch_s if dispatch_s > 0.0
+                  else device_end)
+            # Device end → the loop's resumption, inside device end →
+            # the (deferred) fan-out.
+            f0 = r.get("fanout_t0", 0.0)
+            wait("loop_handoff", TID_EGRESS_WAIT, device_end,
+                 min(device_end + r.get("handoff_s", 0.0), f0), tick,
+                 advance=False)
+            wait("egress_wait", TID_EGRESS_WAIT, device_end, f0, tick)
         f0 = r.get("fanout_t0", 0.0)
         if f0 > 0.0:
             fan_s = r.get("fanout_s", 0.0)

@@ -5,9 +5,12 @@ audio track each — the reference's TestSinglePublisher scenario,
 test/singlenode_test.go:140) plus a VP8 simulcast room.
 """
 
+import asyncio
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from livekit_server_tpu.models import plane
 from livekit_server_tpu.ops import audio
@@ -526,3 +529,38 @@ async def test_watchdog_restarts_stalled_plane_from_snapshot():
     finally:
         await sup.stop()
         await rt.stop()
+
+
+@pytest.mark.parametrize("with_callback", [False, True])
+async def test_checkpoint_now_is_one_span_with_its_children(with_callback):
+    """One call adds one `supervisor/checkpoint` to the span totals, with
+    its parts inside it: the snapshot under state_lock, the encode (+
+    checksum) and, where there is one, the per-room callback."""
+    from livekit_server_tpu.runtime import PlaneRuntime, PlaneSupervisor
+
+    dims = plane.PlaneDims(rooms=2, tracks=4, pkts=4, subs=4)
+    rt = PlaneRuntime(dims, tick_ms=10)
+    await rt.step_once()
+    called = []
+
+    async def callback():
+        called.append(1)
+        await asyncio.sleep(0.002)
+
+    sup = PlaneSupervisor(rt, checkpoint_interval_s=60.0)
+    if with_callback:
+        sup.room_checkpoint_cb = callback
+    await sup.checkpoint_now()
+    spans = rt.spans.snapshot()
+    whole = spans["supervisor/checkpoint"]
+    parts = [spans[f"supervisor/checkpoint/{part}"]
+             for part in ("snapshot", "encode", "callback")]
+    assert whole["n"] == 1 and [p["n"] for p in parts] == [1, 1, int(with_callback)]
+    assert all(p["busy_s"] > 0.0 for p in parts[:2])
+    assert sum(p["busy_s"] for p in parts) <= whole["busy_s"] + 3e-6
+    if with_callback:
+        assert called == [1] and parts[2]["busy_s"] >= 0.002
+    assert sup.last_good_snapshot() is not None
+    await sup.checkpoint_now()
+    assert rt.spans.snapshot()["supervisor/checkpoint"]["n"] == 2
+    await rt.stop()

@@ -410,6 +410,42 @@ async def test_metrics_and_debug():
             await alice.close()
 
 
+async def test_spans_at_the_debug_endpoints():
+    """Where the spans are served: /debug/rooms (totals, wire stages'
+    sums), /debug/ticks (the record's keys), /debug/compiles (start-up
+    by phase, which is no span: one record a number)."""
+    from livekit_server_tpu.runtime import trace
+
+    async with running_server() as server:
+        async with aiohttp.ClientSession() as s:
+            url = f"http://127.0.0.1:{server.port}"
+            await asyncio.sleep(0.2)  # let some ticks complete
+            async with s.get(f"{url}/debug/rooms") as r:
+                rooms = await r.json()
+            assert set(rooms["spans"]) == set(trace.SPANS)
+            for name in ("loop/sleep", "stage/host", "device/call",
+                         "device/dispatch", "device/fetch", "fanout/assemble"):
+                assert rooms["spans"][name]["n"] >= 1, name
+                assert rooms["spans"][name]["busy_s"] > 0.0, name
+            assert set(rooms["wire_stages"]) == set(trace.STAGES)
+            assert rooms["wire_stages"]["total"] == {"n": 0, "sum_ms": 0.0}
+            async with s.get(f"{url}/debug/compiles") as r:
+                startup = (await r.json())["startup"]
+            phases = ("create_server", "warm_step", "warm_compile", "udp_start")
+            assert set(startup) == set(phases) | {"warm_exec_s"}
+            for ph in phases:
+                assert 0.0 <= startup[ph]["compile_s"] <= startup[ph]["wall_s"]
+            assert startup["warm_exec_s"] == pytest.approx(sum(
+                startup[ph]["wall_s"] - startup[ph]["compile_s"]
+                for ph in phases), abs=2e-3)
+            async with s.get(f"{url}/debug/ticks") as r:
+                ticks = await r.json()
+            assert "recent_tick_s" not in ticks and ticks["recent_ticks"]
+            assert {"sleep_ms", "dispatch_delay_ms", "lock_wait_ms", "upload_ms",
+                    "device_dispatch_ms", "device_fetch_ms", "handoff_ms",
+                    "egress_wait_ms", "send_ms"} <= set(ticks["recent_ticks"][-1])
+
+
 async def test_trace_and_blackbox_endpoints():
     from livekit_server_tpu.telemetry import trace_export
 

@@ -10,6 +10,7 @@ import socket
 import time
 
 import numpy as np
+import pytest
 
 from livekit_server_tpu.models import plane
 from livekit_server_tpu.native import rtp as parser
@@ -771,6 +772,44 @@ async def test_forward_latency_probe_measures_rx_to_wire():
         assert hi <= time.perf_counter() - t0
         assert probe.summary()["p99_ms"] >= 15.0
         sub.close()
+    finally:
+        transport.transport.close()
+        await runtime.stop()
+
+
+@pytest.mark.parametrize("k", [1, 5])
+async def test_feed_batch_is_one_rx_wakeup_of_k_datagrams(k):
+    """The receive path's record: a call of feed_batch adds 1 to `rx.n`
+    and its datagrams to `rx.items`, with the time to stage them."""
+    from livekit_server_tpu.runtime.trace import Spans
+
+    runtime = PlaneRuntime(DIMS, tick_ms=10)
+    s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    transport = await start_udp_transport(runtime.ingest, "127.0.0.1", port)
+    try:
+        runtime.set_track(0, 0, published=True, is_video=False)
+        ssrc = transport.assign_ssrc(room=0, track=0, is_video=False)
+        dgrams = [rtp_packet(sn=100 + i, ts=960 * i, ssrc=ssrc, payload=b"x" * 40)
+                  for i in range(k)]
+        blob = np.frombuffer(b"".join(dgrams), np.uint8)
+        lens = np.array([len(d) for d in dgrams], np.int32)
+        offs = np.zeros(k, np.int32)
+        np.cumsum(lens[:-1], out=offs[1:])
+        ips, ports = np.full(k, 0x7F000001, np.uint32), np.full(k, 40000, np.uint16)
+        transport.feed_batch(blob, offs, lens, ips, ports, k)    # no totals attached
+        transport.spans = Spans(True)
+        t0 = time.perf_counter()
+        transport.feed_batch(blob, offs, lens, ips, ports, k, t_rx=t0)
+        rx = transport.spans.snapshot()["rx"]
+        assert (rx["n"], rx["items"]) == (1, k)
+        assert 0.0 < rx["busy_s"] <= time.perf_counter() - t0
+        transport.feed_batch(blob, offs, lens, ips, ports, k)
+        rx = transport.spans.snapshot()["rx"]
+        assert (rx["n"], rx["items"]) == (2, 2 * k)
+        assert transport.stats["rx"] == 3 * k
     finally:
         transport.transport.close()
         await runtime.stop()
