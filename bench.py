@@ -286,7 +286,6 @@ async def wire_bench(
     ack_ms: float = 25.0,
     n_slices: int = 4,
     warm_timeout_s: float = 120.0,
-    low_latency: bool = False,
     egress_shards: int = 0,
     express_max_subs: int = 0,
 ) -> dict:
@@ -311,7 +310,7 @@ async def wire_bench(
         start_udp_transport,
     )
 
-    runtime = PlaneRuntime(dims, tick_ms=tick_ms, low_latency=low_latency,
+    runtime = PlaneRuntime(dims, tick_ms=tick_ms,
                            egress_shards=egress_shards,
                            express_max_subs=express_max_subs,
                            express_max_rooms=dims.rooms)
@@ -660,7 +659,6 @@ async def wire_bench(
         "stage_ms_per_tick": stage_ms("stage_s"),
         "device_ms_per_tick": stage_ms("device_s"),
         "fanout_ms_per_tick": stage_ms("fanout_s"),
-        "pipeline_depth": 0 if runtime.low_latency else 1,
         "pipeline_stalls": runtime.stats.get("pipeline_stalls", 0) - base["stalls"],
         "host_egress_pps": round(tx / host_busy_s, 1) if tx else 0.0,
         # Sharded-plane view of the same window: EMA of entries over the
@@ -746,8 +744,6 @@ def main() -> None:
                          "multiple variants (--wire-only mode)")
     ap.add_argument("--wire-rooms", type=int, default=32)
     ap.add_argument("--wire-kbps", type=float, default=3000.0)
-    ap.add_argument("--wire-low-latency", action="store_true",
-                    help="complete egress in-tick (PlaneRuntime low_latency)")
     args = ap.parse_args()
     if args.budget is not None:
         _BUDGET[0] = args.budget
@@ -785,7 +781,6 @@ def main() -> None:
             dims_w = plane.PlaneDims(args.wire_rooms, 8, 8, 6)
             _run_wire(key, dims_w, t,
                       args.wire_seconds, video_kbps=args.wire_kbps,
-                      low_latency=args.wire_low_latency,
                       express_max_subs=(dims_w.subs if spec.endswith("e")
                                         else 0))
             emit()

@@ -159,11 +159,6 @@ class PlaneConfig:
     pkts_per_track: int = 16     # packet slots per track per tick
     subs_per_room: int = 32
     donate_state: bool = True
-    # Complete each tick's egress before starting the next tick instead of
-    # overlapping it with the next device step: ~1 tick lower forward
-    # latency, at the cost of the wall budget being the SUM of device +
-    # host egress instead of their max. Worth it when both fit the tick.
-    low_latency: bool = False
     # Express lane (two-tier latency plane): rooms with at most this many
     # subscribers forward on packet ARRIVAL from the last device selector
     # mirror (≤1-tick-stale, bit-equivalent decisions) instead of waiting

@@ -39,11 +39,11 @@ forwarding decision/rewrite/send happens.
 
 Tier handover ordering: demotion is exact (the batched tier resumes
 with strictly newer packets). Promotion takes over the closing window
-synchronously at the tick boundary (`takeover`), so in low-latency mode
-— where each tick's fan-out completes inside its own window — the
-munger lanes advance in strict arrival order across the switch. In
-pipelined mode one prior window's deferred fan-out can interleave a
-promotion; the worst case is a transient one-SN gap on the promoted
+synchronously at the tick boundary (`takeover`), so at depth 0 — where
+each tick's fan-out completes inside its own window — the munger lanes
+advance in strict arrival order across the switch. At depth 1 (the
+serving loop's catch-up) one prior window's deferred fan-out can
+interleave a promotion; the worst case is a transient one-SN gap on the promoted
 room's lanes (perceived loss, recovered by NACK), never corruption.
 """
 

@@ -24,8 +24,9 @@ degradation levels, each mapped to an existing actuator:
 
 Sensors are evaluated once per completed tick (PlaneRuntime._complete →
 on_tick): deadline lateness, work ratio (what the tick asks of its window /
-tick period: with the pipelined loop the device step overlaps staging and
-fan-out, so that is the longer of the two, not their sum),
+tick period: where the loop runs pipelined, at depth 1, the device step
+overlaps staging and fan-out, so that is the longer of the two; at depth 0
+it is their sum),
 new pipeline stalls, and new ingest *capacity* drops. Policed drops are
 deliberately excluded — intentional shedding must not read as pressure,
 which is the point of the dropped_capacity / dropped_policed split.
@@ -130,8 +131,8 @@ class OverloadGovernor:
         self._stalls_seen = stalls
         self._cap_drops_seen = cap_drops
         # The tick's share of its window: `work_ms` where the record has
-        # it (the pipelined loop's own budget, max(device, stage + fan-out)),
-        # else the plain sum of the stages.
+        # it (at depth 1 the pipelined loop's own budget, max(device, stage +
+        # fan-out); at depth 0 their sum), else the plain sum of the stages.
         work = rec.get("work_ms", rec.get("total_ms", 0.0)) / max(
             float(rt.tick_ms), 1e-3)
         late = bool(rec.get("late"))

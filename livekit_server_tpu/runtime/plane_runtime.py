@@ -40,6 +40,16 @@ from livekit_server_tpu.runtime.munge import HostMunger
 from livekit_server_tpu.runtime.probe import PAD_BYTES, ProbeController
 from livekit_server_tpu.runtime.slots import SlotAllocator
 
+# `PlaneRuntime.choose_depth`'s thresholds, shares of the period and ticks:
+# constants, not configuration.
+CHAIN_TICKS = 8           # ticks the chain's running estimate averages over
+DEPTH_ENTER_SHARE = 0.95  # at depth 0, chain + lag over it: behind, pipeline
+DEPTH_FIT_SHARE = 0.85    # a chain over it, once behind, is what did not fit
+DEPTH_LAG_GONE = 0.15     # pipelined, a lag under it: caught up
+DEPTH_TRY_SLACK = 0.25    # a try needs to have slept this much before its edge
+RETRY_TICKS = 32          # a chain that did not fit: ticks before depth 0 is
+RETRY_MAX_TICKS = 1024    # tried again, doubling up to this a failed try
+
 
 @dataclass
 class EgressPacket:
@@ -378,7 +388,6 @@ class PlaneRuntime:
         audio_params=None,
         bwe_params=None,
         red_enabled: bool = True,
-        low_latency: bool = False,
         egress_shards: int = 0,
         egress_multicast: bool = True,
         express_max_subs: int = 0,
@@ -393,10 +402,6 @@ class PlaneRuntime:
         self.dims = dims
         self.tick_ms = tick_ms
         self.red_enabled = red_enabled
-        # low_latency: complete each tick's egress before the next tick
-        # starts (≈1 tick less forward latency) instead of overlapping it
-        # with the next device step (higher throughput ceiling).
-        self.low_latency = low_latency
         self.slots = SlotAllocator(dims.rooms, dims.tracks, dims.subs)
         self.ingest = IngestBuffer(dims, tick_ms)
         self.tick_index = 0
@@ -495,6 +500,9 @@ class PlaneRuntime:
         self._on_tick: list[Callable[[TickResult], Awaitable[None] | None]] = []
         self.stats = {
             "ticks": 0, "fwd_packets": 0, "fwd_bytes": 0, "late_ticks": 0,
+            # Ticks the serving loop ran at depth 0 (`choose_depth`); the
+            # rest of `ticks` ran pipelined, a tick deep.
+            "depth0_ticks": 0,
             # Pipeline shape: cumulative per-stage seconds + stall count
             # (a window that found the previous fan-out still running).
             "stage_s": 0.0, "device_s": 0.0, "fanout_s": 0.0,
@@ -950,6 +958,8 @@ class PlaneRuntime:
         result.tick_s = st.stage_s + st.device_s + fanout_s
         result.quality_window_closed = st.roll
         self.stats["ticks"] += 1
+        if not st.depth:
+            self.stats["depth0_ticks"] += 1
         self.stats["fwd_packets"] += result.fwd_packets
         self.stats["fwd_bytes"] += result.fwd_bytes
         self.stats["stage_s"] += st.stage_s
@@ -1051,9 +1061,9 @@ class PlaneRuntime:
     async def step_once(self) -> TickResult:
         """One sequential tick (tests, warmup, manual stepping); the device
         round trip runs in a worker thread so the event loop (signal
-        sessions) never blocks on the device round trip. The serving loop
-        (`_run`) instead pipelines: staging of tick N+1 and egress fan-out
-        of tick N-1 overlap tick N's device step.
+        sessions) never blocks on the device round trip. This is depth 0,
+        the serving loop's (`_run`) normal order, without its edges; the
+        loop also pipelines (depth 1) while it runs behind.
 
         step_once must NOT interleave with a RUNNING serving loop: the
         device steps serialize safely under state_lock, but this path's
@@ -1355,20 +1365,66 @@ class PlaneRuntime:
         if over > 2.5e-4 and self._sleep_bias >= 0:
             self._sleep_bias = min(self._sleep_bias + 0.25 * over, 4e-3)
 
+    @staticmethod
+    def choose_depth(chain_s: float, lag_s: float, slack_s: float, period: float,
+                     depth: int, stay: int, retry: int) -> tuple[int, int]:
+        """The pipeline's depth for the tick about to be dispatched, and
+        the ticks to stay pipelined before depth 0 is tried again: a pure
+        function, called once a tick by `_run`.
+
+        `chain_s` is the running estimate of what a tick asks of its
+        window at depth 0: stage, device call, fan-out, send and what
+        `rx` and the other handlers took between them (`_run` measures
+        it whole, from the wake at the edge until it sleeps again, at
+        depth 0 only: pipelined, nothing the loop can measure says what
+        the device call would cost beside `rx`). `lag_s` is how far
+        behind its edge this dispatch runs, `slack_s` how long the loop
+        slept before it, `stay` the ticks spent at `depth` so far.
+
+        At depth 0 a tick has to end before the next edge: once chain +
+        lag passes DEPTH_ENTER_SHARE of the period the loop pipelines,
+        which takes the device call and the host's work off each other's
+        path and wins the lag back sooner. If the chain fits and only
+        the lag does not (a hold, such as the supervisor's checkpoint),
+        it returns as soon as the lag is gone. If the chain itself did
+        not fit, on a stay shorter than RETRY_TICKS, depth 0 was a try
+        that failed: it is tried again after `retry` ticks, twice as
+        many a failure, and only from a tick that slept DEPTH_TRY_SLACK
+        of the period (a try puts the device call on the loop's path: at
+        a node's knee there is no room for it, and a failed try is a
+        small hold of its own).
+
+        Tests that need a fixed depth replace this on the instance."""
+        if depth:
+            back = stay >= retry and lag_s < DEPTH_LAG_GONE * period and (
+                not retry or slack_s > DEPTH_TRY_SLACK * period)
+            return int(not back), retry
+        if chain_s + max(lag_s, 0.0) <= DEPTH_ENTER_SHARE * period:
+            return 0, retry
+        failed = chain_s > DEPTH_FIT_SHARE * period and stay < RETRY_TICKS
+        return 1, min(max(2 * retry, RETRY_TICKS), RETRY_MAX_TICKS) if failed else 0
+
     async def _run(self) -> None:
-        """Three-stage pipelined serving loop (the 'double-buffered DMA'
-        this module documents): within one tick window,
+        """The serving loop; each tick runs at the depth `choose_depth`
+        gives it.
 
-            stage N+1  ‖  device N  ‖  fan-out N-1
+        Depth 0, the normal order: at the window edge the loop drains and
+        stages the tick, uploads the (delta) ctrl and dispatches the
+        device step to the worker thread; when the step returns it fans
+        out and sends that tick, then sleeps to the next edge.
 
-        Tick N — staged during the PREVIOUS window — is dispatched to the
-        worker thread at the window edge; while the device crunches, the
-        event loop stages tick N+1 (ingest drain + input pre-pack, into
-        the other ingest ping-pong set) and runs tick N-1's fan-out +
-        egress. A tick's wall budget is max(device, stage + fan-out) +
-        dispatch ε instead of the former stage + max(device, fan-out):
-        nothing host-side sits in front of the device dispatch but the
-        (delta) ctrl upload.
+        Depth 1, the catch-up: stage N+1  ‖  device N  ‖  fan-out N-1.
+        Tick N — staged during the PREVIOUS window — is dispatched at the
+        edge; while the device crunches, the event loop stages tick N+1
+        (into the other ingest ping-pong set) and runs tick N-1's fan-out
+        + egress. A tick's wall budget is max(device, stage + fan-out) +
+        dispatch ε instead of their sum; a packet waits two windows more.
+
+        A change of depth keeps the wire order. 1 → 0: the deferred
+        fan-out of the tick before starts after this dispatch, as ever,
+        and is awaited before this tick's own; the tick pre-staged for
+        this edge is used as it is. 0 → 1: the loop pre-stages after this
+        dispatch and holds this tick's fan-out, nothing else.
 
         The completion queue is bounded at 1: if host egress can't keep
         up, the loop degrades to sequential (counted in pipeline_stalls)
@@ -1378,9 +1434,8 @@ class PlaneRuntime:
 
         self.state stays single-owner: only the ctrl upload + dispatched
         device step touch the donated state, and exactly that span runs
-        under state_lock. Staging reads host mirrors only and needs no
-        lock (the GC01 split: _upload_ctrl/_device_step keep the
-        lock-held contract, _stage_host is lock-free)."""
+        under state_lock; staging reads host mirrors only (the GC01
+        split)."""
         period = self.tick_ms / 1000.0
         await self._calibrate_sleep()
         next_at = time.perf_counter() + period
@@ -1388,20 +1443,23 @@ class PlaneRuntime:
         pending: tuple | None = None   # (out, StagedTick) awaiting fan-out
         pending_task: asyncio.Task | None = None
         staged: StagedTick | None = None  # pre-staged next tick
-        depth = 0 if self.low_latency else 1
+        depth = stay = retry = 0       # `choose_depth`'s state
+        chain_s = woke = 0.0           # its running estimate; the last wake
         try:
             while True:
                 if staged is not None:
-                    # Edge surgery: deadline accounting and probe
-                    # scheduling for a pre-staged tick happen BEFORE the
-                    # sleep — no device step completes while the loop
-                    # sleeps, so the mirrors _schedule_probe reads cannot
-                    # change — leaving the post-wake path dispatch-only.
-                    staged.depth = depth
-                    staged.edge = next_at
-                    staged.deadline = next_at + (1 + depth) * period
+                    # Edge surgery: probe scheduling for a pre-staged tick
+                    # happens BEFORE the sleep — no device step completes
+                    # while the loop sleeps, so the mirrors
+                    # _schedule_probe reads cannot change — leaving the
+                    # post-wake path dispatch-only.
                     self._schedule_probe(staged)
                 sleep_t0 = time.perf_counter()
+                if woke and not depth:
+                    # What the last tick asked of its window; a sample
+                    # past the period says "does not fit" as well as any
+                    # larger one (a hold, a first tick that compiles).
+                    chain_s += (min(sleep_t0 - woke, period) - chain_s) / CHAIN_TICKS
                 await self._sleep_until(next_at)
                 sleep_s = time.perf_counter() - sleep_t0
                 if self.integrity is not None and self.integrity._pending_repair:
@@ -1419,16 +1477,24 @@ class PlaneRuntime:
                         self.stats["pipeline_stalls"] += 1
                     await pending_task
                     pending_task = self._complete_task = None
+                woke = time.perf_counter()
+                want, retry = self.choose_depth(
+                    chain_s, woke - next_at, sleep_s, period, depth, stay, retry)
+                if want != depth:
+                    depth, stay = want, 0
+                    if not depth:
+                        # A try starts from a chain that fits, and learns.
+                        chain_s = min(chain_s, DEPTH_FIT_SHARE * period)
+                stay += 1
                 if staged is None:
-                    # Cold start, post-resync, or low_latency mode: stage
-                    # at the window edge (low latency keeps the freshest
-                    # possible drain at the cost of serializing it).
+                    # Depth 0, cold start or post-resync: stage at the
+                    # window edge, the freshest possible drain.
                     staged = self._stage_host()
-                    staged.depth = depth
-                    staged.edge = next_at
-                    staged.deadline = next_at + (1 + depth) * period
                     self._schedule_probe(staged)
                 cur, staged = staged, None
+                cur.depth = depth
+                cur.edge = next_at
+                cur.deadline = next_at + (1 + depth) * period
                 cur.edge_over_us = self._edge_overshoot_us
                 cur.sleep_t0, cur.sleep_s = sleep_t0, sleep_s
                 if self.ingest.frozen_rows:
@@ -1450,13 +1516,13 @@ class PlaneRuntime:
                             self._complete(pending[0], pending[1])
                         )
                         pending = None
-                    if not self.low_latency:
+                    if depth:
                         # Stage N+1 while device N runs in the worker:
                         # the drain flips to the other ingest ping-pong
                         # set and the pre-pack memcpys overlap the device
-                        # step — the tentpole overlap. Staging touches
-                        # host mirrors only; the lock we hold here guards
-                        # the in-flight donated state, not this.
+                        # step. Staging touches host mirrors only; the
+                        # lock we hold here guards the in-flight donated
+                        # state, not this.
                         staged = self._stage_host()
                     # Fan-out N-1 (the task above) and any arriving-packet
                     # handlers run on the event loop during this await.
@@ -1471,17 +1537,20 @@ class PlaneRuntime:
                     raise asyncio.CancelledError("device step abandoned by restart")
                 self._mirror_probe_inputs(out)
                 self.ingest.scrub_retired()
-                pending = (out, cur)
-                if self.low_latency:
-                    # Fan out THIS tick's egress now rather than
-                    # overlapping it with the next device step: the sends
-                    # leave within the same tick period. `pending` is
-                    # cleared BEFORE the await — a cancellation landing
+                if depth:
+                    pending = (out, cur)
+                else:
+                    # Fan out THIS tick's egress now: the sends leave
+                    # within the same tick period, after the deferred
+                    # fan-out of a tick before that ran at depth 1. The
+                    # tick never becomes `pending`: a cancellation landing
                     # inside _complete must not let the drain handler
-                    # re-run the same tick (double egress + munger state
-                    # advanced twice).
-                    to_complete, pending = pending, None
-                    await self._complete(to_complete[0], to_complete[1])
+                    # re-run it (double egress + munger state advanced
+                    # twice).
+                    if pending_task is not None:
+                        await pending_task
+                        pending_task = self._complete_task = None
+                    await self._complete(out, cur)
                 next_at += period
                 if next_at < time.perf_counter() - 5 * period:
                     next_at = time.perf_counter() + period  # resync after stall

@@ -99,7 +99,6 @@ class RoomManager:
             tick_ms=p.tick_ms,
             mesh=mesh,
             **extra,
-            low_latency=p.low_latency,
             red_enabled="audio/red" in config.room.enabled_codecs,
             audio_params=audio_ops.AudioLevelParams(
                 active_level=config.audio.active_level,

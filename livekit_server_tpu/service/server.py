@@ -188,7 +188,8 @@ class LivekitServer:
         body = {
             "tick_ms": rt.tick_ms,
             "stats": rt.stats,
-            "pipeline_depth": 0 if rt.low_latency else 1,
+            # the depth of the last tick (`PlaneRuntime.choose_depth`)
+            "pipeline_depth": rt.recent_ticks[-1]["depth"] if rt.recent_ticks else 0,
             "recent_ticks": list(getattr(rt, "recent_ticks", [])),
         }
         body["sleep_bias_us"] = round(

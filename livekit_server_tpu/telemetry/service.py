@@ -145,7 +145,7 @@ class TelemetryService:
         # accounting — cumulative, so rates are scrape-window deltas.
         for k in ("stage_s", "device_s", "fanout_s"):
             self.set_gauge(f"livekit_plane_{k}_total", stats.get(k, 0.0))
-        for k in ("pipeline_stalls", "ctrl_full_uploads", "ctrl_delta_uploads",
+        for k in ("depth0_ticks", "pipeline_stalls", "ctrl_full_uploads", "ctrl_delta_uploads",
                   "ctrl_delta_rows", "ctrl_upload_bytes"):
             self.set_gauge(f"livekit_plane_{k}_total", stats.get(k, 0))
         # Tick-edge calibration: measured coarse-sleep bias + last wake

@@ -32,7 +32,6 @@ async def test_express_wire_p99_smoke():
         warm_ticks=30,
         video_tracks=4,
         audio_tracks=4,
-        low_latency=True,
         express_max_subs=dims.subs,
     )
     assert out.get("task_errors") is None or not out["task_errors"]

@@ -92,9 +92,12 @@ def test_overlapped_stages_are_not_summed_into_pressure():
 
 async def test_tick_record_work_is_the_longer_of_the_overlapped_halves():
     """`work_ms` in a served tick's record: max(device, stage + fan-out)
-    when the loop pipelines, the plain sum when it does not."""
-    for low_latency in (False, True):
-        rt = PlaneRuntime(DIMS, tick_ms=10, low_latency=low_latency)
+    when the loop pipelines (depth 1), the plain sum when it does not
+    (depth 0). The loop chooses its depth from what it measures, so the
+    chooser is pinned on the instance for each reading."""
+    for depth in (1, 0):
+        rt = PlaneRuntime(DIMS, tick_ms=10)
+        rt.choose_depth = lambda *a, _d=depth: (_d, 0)
         rt.start()
         try:
             deadline = asyncio.get_event_loop().time() + 20.0
@@ -105,8 +108,8 @@ async def test_tick_record_work_is_the_longer_of_the_overlapped_halves():
             await rt.stop()
         rec = list(rt.recent_ticks)[-1]
         parts = (rec["device_ms"], rec["stage_ms"] + rec["fanout_ms"])
-        want = sum(parts) if low_latency else max(parts)
-        assert rec["depth"] == (0 if low_latency else 1)
+        want = max(parts) if depth else sum(parts)
+        assert rec["depth"] == depth
         assert abs(rec["work_ms"] - want) < 0.01, rec
 
 

@@ -1,8 +1,10 @@
-"""Three-stage tick pipeline (runtime/plane_runtime.py _run).
+"""The serving loop and its pipeline (runtime/plane_runtime.py _run).
 
-Covers the PR's pipeline invariants end to end: the step_once/serving-loop
-mutual exclusion guard, cross-tick egress ordering under overlap, bounded
-pipeline depth when the device stalls (faultinject), dirty-row delta
+Covers the pipeline's invariants end to end: the step_once/serving-loop
+mutual exclusion guard, cross-tick egress ordering under overlap, the
+depth the loop chooses a tick at a time (`choose_depth`) and the wire
+order across its changes, bounded pipeline depth when the device stalls
+(faultinject), dirty-row delta
 control uploads vs the full `_replace` path, and the double-buffered
 ingest staging sets that let stage N+1 overlap device N.
 """
@@ -27,6 +29,30 @@ async def _first_tick(rt, timeout=60.0):
         if asyncio.get_event_loop().time() > deadline:
             raise TimeoutError("first tick never completed")
         await asyncio.sleep(0.02)
+
+
+async def _serve(rt, n_pkts, gap_s, first_sn):
+    """Start the loop, push `n_pkts` one at a time, wait for them all."""
+    rt.set_track(0, 0, published=True, is_video=False)
+    rt.set_subscription(0, 0, 1, subscribed=True)
+    ticks, batches = [], []
+    rt.on_tick(lambda res: (ticks.append(res.tick_index),
+                            batches.append(res.egress_batch)))
+    rt.start()
+    try:
+        await _first_tick(rt)
+        for i in range(n_pkts):
+            rt.ingest.push(PacketIn(room=0, track=0, sn=first_sn + i,
+                                    ts=960 * i, size=40, payload=b"d" * 40))
+            await asyncio.sleep(gap_s)
+        deadline = asyncio.get_event_loop().time() + 5.0
+        while sum(len(b) for b in batches) < n_pkts:
+            if asyncio.get_event_loop().time() > deadline:
+                raise TimeoutError(f"only {sum(len(b) for b in batches)} sends")
+            await asyncio.sleep(0.01)
+    finally:
+        await rt.stop()
+    return ticks, batches
 
 
 # -- step_once vs the serving loop ------------------------------------------
@@ -54,28 +80,9 @@ async def test_pipelined_egress_stays_in_tick_order():
     """With fan-out N-1 overlapping device N, completions must still be
     delivered strictly in tick order and every SN exactly once: the
     pipeline reorders WORK, never egress."""
-    rt = PlaneRuntime(DIMS, tick_ms=10)  # pipelined (low_latency=False)
-    rt.set_track(0, 0, published=True, is_video=False)
-    rt.set_subscription(0, 0, 1, subscribed=True)
-    ticks, batches = [], []
-    rt.on_tick(lambda res: (ticks.append(res.tick_index),
-                            batches.append(res.egress_batch)))
-    rt.start()
-    try:
-        await _first_tick(rt)
-        for i in range(8):
-            rt.ingest.push(PacketIn(room=0, track=0, sn=700 + i, ts=960 * i,
-                                    size=40, payload=b"p" * 40))
-            await asyncio.sleep(0.015)
-        deadline = asyncio.get_event_loop().time() + 5.0
-        while sum(len(b) for b in batches) < 8:
-            if asyncio.get_event_loop().time() > deadline:
-                raise TimeoutError(
-                    f"only {sum(len(b) for b in batches)} sends arrived"
-                )
-            await asyncio.sleep(0.01)
-    finally:
-        await rt.stop()
+    rt = PlaneRuntime(DIMS, tick_ms=10)
+    rt.choose_depth = lambda *a: (1, 0)  # pinned: pipelined, a tick deep
+    ticks, batches = await _serve(rt, 8, 0.015, 700)
     assert ticks == sorted(ticks) and len(set(ticks)) == len(ticks)
     sns = [int(sn) & 0xFFFF for b in batches for sn in np.asarray(b.sn)]
     # In arrival order across callbacks: monotonic, no dupes, no holes.
@@ -92,6 +99,7 @@ async def test_device_stall_degrades_to_sequential_bounded_depth():
     counts the backpressure) rather than queueing stale sends. Every
     delivered SN still appears exactly once, in order."""
     rt = PlaneRuntime(DIMS, tick_ms=10)
+    rt.choose_depth = lambda *a: (1, 0)  # pinned: the depth under test
     rt.fault = FaultInjector(FaultSpec(stall_every=2, stall_s=0.05))
     rt.set_track(0, 0, published=True, is_video=False)
     rt.set_subscription(0, 0, 1, subscribed=True)
@@ -120,6 +128,161 @@ async def test_device_stall_degrades_to_sequential_bounded_depth():
     sns = [int(sn) & 0xFFFF for b in batches for sn in np.asarray(b.sn)]
     assert sns == [900 + i for i in range(len(sns))]
     assert all(rec["depth"] <= 1 for rec in rt.recent_ticks)
+
+
+# -- the depth the loop chooses ----------------------------------------------
+
+P = 0.020   # a 20 ms period, the cells' own
+
+# (chain_s, lag_s, slack_s, depth now, stay, retry) -> (depth, retry)
+CHOICES = [
+    # depth 0 holds while chain + lag fits the period, to a twentieth
+    ((0.012, 0.000, 0.007, 0, 5, 0), (0, 0)),
+    ((0.012, 0.0065, 0.0, 0, 5, 0), (0, 0)),
+    ((0.012, -0.003, 0.007, 0, 5, 0), (0, 0)),   # early at the edge: no credit, no lag
+    ((0.0185, 0.000, 0.001, 0, 5, 64), (0, 64)),  # a try that holds keeps its wait
+    ((0.000, 0.000, 0.0, 0, 0, 0), (0, 0)),       # cold start: nothing measured yet
+    # behind after a hold (the chain fits, the lag does not): pipeline, and
+    # come back as soon as the lag is gone, slept or not
+    ((0.012, 0.009, 0.0, 0, 90, 0), (1, 0)),
+    ((0.012, 0.050, 0.057, 0, 90, 0), (1, 0)),
+    ((0.012, 0.050, 0.0, 0, 3, 256), (1, 0)),     # a hold forgives earlier failures
+    ((0.012, 0.030, 0.0, 1, 1, 0), (1, 0)),
+    ((0.012, 0.0031, 0.0, 1, 3, 0), (1, 0)),
+    ((0.012, 0.0029, 0.0, 1, 4, 0), (0, 0)),
+    ((0.019, 0.001, 0.0, 1, 0, 0), (0, 0)),       # pipelined, the chain is not read
+    # behind because the chain itself did not fit: a try that failed, each
+    # one waits twice as long as the last, up to a limit
+    ((0.0195, 0.000, 0.0, 0, 3, 0), (1, 32)),
+    ((0.018, 0.003, 0.0, 0, 3, 32), (1, 64)),
+    ((0.018, 0.003, 0.0, 0, 3, 512), (1, 1024)),
+    ((0.018, 0.003, 0.0, 0, 3, 1024), (1, 1024)),
+    ((0.018, 0.003, 0.0, 0, 40, 1024), (1, 0)),   # it held 40 ticks: not a failed try
+    # the wait is served pipelined; a try starts only caught up, and from a
+    # tick that slept a quarter of the period
+    ((0.018, 0.000, 0.008, 1, 10, 32), (1, 32)),
+    ((0.018, 0.000, 0.008, 1, 31, 32), (1, 32)),
+    ((0.018, 0.000, 0.008, 1, 32, 32), (0, 32)),
+    ((0.018, 0.004, 0.008, 1, 500, 32), (1, 32)),
+    ((0.018, 0.000, 0.0049, 1, 500, 32), (1, 32)),
+    ((0.018, 0.000, 0.0051, 1, 500, 32), (0, 32)),
+]
+
+
+@pytest.mark.parametrize("given, want", CHOICES)
+def test_choose_depth_table(given, want):
+    chain_s, lag_s, slack_s, depth, stay, retry = given
+    assert PlaneRuntime.choose_depth(
+        chain_s, lag_s, slack_s, P, depth, stay, retry) == want
+
+
+@pytest.mark.parametrize("period", [0.005, 0.010, 0.080])
+def test_choose_depth_thresholds_scale_with_the_period(period):
+    """The thresholds are shares of the period, not milliseconds: the same
+    shares of another period give the same walk 0 -> 1 -> 0 after a hold,
+    and the same doubling wait for a chain that does not fit."""
+    choose = PlaneRuntime.choose_depth
+    depth = stay = retry = 0
+    walk = []
+    for chain, lag in [(0.6, 0.0), (0.6, 0.3), (0.6, 2.5), (0.6, 1.5), (0.6, 0.2),
+                       (0.6, 0.1), (0.9, 0.0), (0.9, 0.1), (0.9, 0.0), (0.9, 0.1)]:
+        want, retry = choose(chain * period, lag * period, 0.3 * period, period,
+                             depth, stay, retry)
+        if want != depth:
+            depth, stay = want, 0
+        stay += 1
+        walk.append((depth, retry))
+    assert walk == [(0, 0), (0, 0), (1, 0), (1, 0), (1, 0), (0, 0),
+                    (0, 0), (1, 32), (1, 32), (1, 32)]
+
+
+def _flipping_chooser(every: int):
+    """1 -> 0 -> 1 -> 0 ...: `every` ticks at a depth, whatever is measured."""
+    calls = [0]
+
+    def choose(*measured):
+        calls[0] += 1
+        return (calls[0] // every + 1) % 2, 0
+    return choose
+
+
+WIDE = plane.PlaneDims(rooms=2, tracks=2, pkts=8, subs=4)
+
+
+@pytest.mark.parametrize("every", [1, 2, 3])
+async def test_depth_changes_keep_the_wire_order(every):
+    """The chooser forced 1 -> 0 -> 1 -> 0 mid-stream with packets in
+    flight: completions strictly in tick order, every SN exactly once and
+    in order, the TS steps kept, and `depth0_ticks` counts the records
+    that say depth 0."""
+    rt = PlaneRuntime(WIDE, tick_ms=10)
+    rt.choose_depth = _flipping_chooser(every)
+    ticks, batches = await _serve(rt, 40, 0.008, 3000)
+    assert ticks == sorted(ticks) and len(set(ticks)) == len(ticks)
+    sns = [int(sn) & 0xFFFF for b in batches for sn in np.asarray(b.sn)]
+    assert sns == [3000 + i for i in range(40)]
+    ts = [int(t) for b in batches for t in np.asarray(b.ts)]
+    assert {(b - a) & 0xFFFFFFFF for a, b in zip(ts, ts[1:])} == {960}
+    assert int(rt.munger.last_sn[0, 0, 1]) == sns[-1]
+    recs = list(rt.recent_ticks)
+    assert len(recs) == rt.stats["ticks"] < rt.recent_ticks.maxlen
+    depths = [r["depth"] for r in recs]
+    assert rt.stats["depth0_ticks"] == depths.count(0) > 0
+    assert depths.count(1) > 0
+    assert sum(a != b for a, b in zip(depths, depths[1:])) >= 4
+
+
+async def test_measured_depth_is_recorded_and_counted():
+    """Nothing pinned: whichever depth the measurement gives on this host,
+    the counter and the records agree, tick for tick."""
+    rt = PlaneRuntime(WIDE, tick_ms=10)
+    ticks, batches = await _serve(rt, 12, 0.01, 5000)
+    sns = [int(sn) & 0xFFFF for b in batches for sn in np.asarray(b.sn)]
+    assert sns == [5000 + i for i in range(12)]
+    recs = list(rt.recent_ticks)
+    assert len(recs) == rt.stats["ticks"]
+    assert rt.stats["depth0_ticks"] == sum(r["depth"] == 0 for r in recs)
+    assert all(r["depth"] in (0, 1) for r in recs)
+
+
+@pytest.mark.parametrize("chooser", ["depth0", "depth1", "flipping"])
+async def test_cancel_inside_complete_never_completes_a_tick_twice(chooser):
+    """A stop() that lands while a delivery callback is awaiting, so inside
+    `_complete`: the drain must not run that tick's fan-out again, at either
+    depth or across a change of depth (double egress would repeat an SN and
+    advance the munger lane twice)."""
+    rt = PlaneRuntime(WIDE, tick_ms=10)
+    rt.choose_depth = {"depth0": lambda *a: (0, 0), "depth1": lambda *a: (1, 0),
+                       "flipping": _flipping_chooser(2)}[chooser]
+    rt.set_track(0, 0, published=True, is_video=False)
+    rt.set_subscription(0, 0, 1, subscribed=True)
+    ticks, batches = [], []
+    inside = asyncio.Event()
+
+    async def deliver(res):
+        ticks.append(res.tick_index)
+        batches.append(res.egress_batch)
+        if sum(len(b) for b in batches) >= 6:
+            inside.set()
+        await asyncio.sleep(0.004)      # the cancel lands here
+
+    rt.on_tick(deliver)
+    rt.start()
+    try:
+        await _first_tick(rt)
+        for i in range(12):
+            rt.ingest.push(PacketIn(room=0, track=0, sn=7000 + i, ts=960 * i,
+                                    size=40, payload=b"c" * 40))
+            await asyncio.sleep(0.008)
+            if inside.is_set():
+                break
+        await asyncio.wait_for(inside.wait(), 5.0)
+    finally:
+        await rt.stop()
+    assert ticks == sorted(ticks) and len(set(ticks)) == len(ticks)
+    sns = [int(sn) & 0xFFFF for b in batches for sn in np.asarray(b.sn)]
+    assert len(sns) >= 6 and sns == [7000 + i for i in range(len(sns))]
+    assert int(rt.munger.last_sn[0, 0, 1]) == sns[-1]
 
 
 # -- dirty-row delta control uploads ----------------------------------------

@@ -636,8 +636,8 @@ async def test_full_grid_burst_forwards_without_caps():
 
 
 async def test_low_latency_loop_delivers_and_stops_clean():
-    """plane.low_latency: the serving loop completes each tick's fan-out
-    in-tick (egress leaves within the period); a stop() issued while
+    """Depth 0 (the chooser pinned there): the serving loop completes each
+    tick's fan-out in-tick (egress leaves within the period); a stop() issued while
     packets are still streaming must not duplicate any send or advance
     host munger offsets twice (the cancellation drain must not
     re-complete a tick whose fan-out already ran). The stop lands
@@ -645,7 +645,8 @@ async def test_low_latency_loop_delivers_and_stops_clean():
     drain path runs with a packet-bearing tick plausibly in flight;
     uniqueness and munger-consistency asserts check whatever arrived."""
     dims = plane.PlaneDims(1, 2, 4, 2)
-    rt = PlaneRuntime(dims, tick_ms=10, low_latency=True)
+    rt = PlaneRuntime(dims, tick_ms=10)
+    rt.choose_depth = lambda *a: (0, 0)
     rt.set_track(0, 0, published=True, is_video=False)
     rt.set_subscription(0, 0, 1, subscribed=True)
     seen = []

@@ -314,7 +314,11 @@ def _runtime(**kw):
     from livekit_server_tpu.runtime.plane_runtime import PlaneRuntime
 
     dims = plane.PlaneDims(rooms=2, tracks=2, pkts=2, subs=2)
-    return PlaneRuntime(dims, tick_ms=5, **kw)
+    rt = PlaneRuntime(dims, tick_ms=5, **kw)
+    # the serving loop's ticks pinned at depth 1: the tests below tell them
+    # from step_once's (depth 0) by it, and read the pipelined waits
+    rt.choose_depth = lambda *a: (1, 0)
+    return rt
 
 
 @pytest.fixture(scope="module")
