@@ -361,7 +361,7 @@ DEFAULT_CONFIG: dict = {
         # outer.inner) may legitimately skip donation: init/restore
         # paths run once and often need the un-donated source intact.
         "allow_missing": [
-            "*restore*", "*init*", "*_build_live_decide*",
+            "*restore*", "*init*",
         ],
     },
     "gc11": {

@@ -495,8 +495,10 @@ def paged_plane_tick_fused(
     interpret: bool = False,
 ):
     """The whole live-extent tick in one trace: phase-0 kernel + live
-    phases 1–2 + scatter. The runtime splits phase 0 into its own
-    dispatch for span timing; tests and bench use this entry."""
+    phases 1–2 + scatter. The served step (`runtime/paged_runtime.py`
+    `_build_live_step`) is this entry between the packed wire's unpack
+    and pack, one program a tick; `live_pages` of the tick record is
+    what its grid ran over."""
     from livekit_server_tpu.ops import paged_kernel
 
     live_rows = jnp.asarray(live_rows, jnp.int32)
@@ -712,7 +714,11 @@ class LayoutXlate:
 
     def _leaf_to_logical(self, kind, pl, fill):
         pl = np.ascontiguousarray(np.asarray(pl))
-        out = np.array(np.asarray(fill), copy=True)
+        # C order, said: a host copy of a TPU array can come back in the
+        # device's own strides, a copy "as laid out" keeps them, and the
+        # views below are views only of a C-contiguous array (of any
+        # other, `reshape` copies and the writes are lost).
+        out = np.array(np.asarray(fill), copy=True, order="C")
         lv, pv = self._views(kind, out, pl)
         if kind == _K_TRACK:
             sel = self.sp0
@@ -727,7 +733,7 @@ class LayoutXlate:
 
     def _leaf_to_pooled(self, kind, lg, pooled_init):
         lg = np.ascontiguousarray(np.asarray(lg))
-        out = np.array(np.asarray(pooled_init), copy=True)
+        out = np.array(np.asarray(pooled_init), copy=True, order="C")
         lv, pv = self._views(kind, lg, out)
         sel = self.occ
         if kind == _K_TRACK:

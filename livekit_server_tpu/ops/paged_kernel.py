@@ -348,6 +348,8 @@ def _pallas_live_call(live_rows, decide_ops, mix_ops, *, TP, K, SP, N, L,
         # K loop keeps many live ranges (cf. ops/selector.py).
         compiler_params=_CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        # for whoever opens the .xplane.pb: the Mosaic call's own name
+        name="paged_decide",
     )(jnp.asarray(live_rows, jnp.int32), *inputs)
 
 

@@ -26,12 +26,18 @@ identical across pool layouts and lets rooms migrate dense↔paged.
 
 Tick variants (`paged_kernel` ctor knob / `plane.paged_kernel`): "off"
 runs the stock full-pool jit tick; "auto" (TPU) / "on" / "interpret"
-run the live-extent path — a timed decide dispatch through the fused
-`ops/paged_kernel.py` grid-over-live-pages kernel (recorded per tick as
-`paged_kernel_ms` + grid steps) and a donated-state rest phase, with
-`live_rows` refreshed in `_sync_pages` under the same epoch pinning as
-`_step_xlate`. Zero live pages short-circuits to a broadcast dead-page
-tick. Forced "off" under a pool mesh (the sharded tick stays stock).
+run the live-extent path: ONE device program a tick, named `tick` like
+the stock one, built on `paged.paged_plane_tick_fused` (unpack, the
+`ops/paged_kernel.py` grid-over-live-pages decide kernel, live phases
+1-2, scatter, pack; state donated). The tick record carries what its
+grid ran over: `live_pages` (mapped pages, unpadded) beside
+`page_live_fraction`, and `stats["paged_kernel_steps"]` counts the
+padded grid steps. The kernel's time is the profiler trace's to give
+(the Mosaic call `paged_decide` inside `jit_tick`), not the host
+clock's. `live_rows` is refreshed in `_sync_pages` under the same epoch
+pinning as `_step_xlate`. Zero live pages short-circuits to a broadcast
+dead-page tick. Forced "off" under a pool mesh (the sharded tick stays
+stock).
 
 Staleness discipline (graftcheck GC08): page indices are only valid
 under the pager epoch they were read at. Everything here that crosses a
@@ -47,14 +53,12 @@ re-initialized (unsubscribed) pages and drop, never misroute.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any
 
 import jax
 import numpy as np
 
 from livekit_server_tpu.models import paged, plane
-from livekit_server_tpu.ops import pacer
 from livekit_server_tpu.runtime.pager import RoomPager
 from livekit_server_tpu.runtime.plane_runtime import (
     PlaneRuntime,
@@ -83,43 +87,23 @@ def _build_paged_step(audio_params, bwe_params, red_enabled=True):
 
 
 @functools.lru_cache(maxsize=None)
-def _build_live_decide(interpret: bool):
-    """Phase 0 of the live-extent tick (ops/paged_kernel.decide_pages) as
-    its OWN dispatch, so the worker thread can time the kernel span
-    (`paged_kernel_ms`) separately from the rest of the device step. The
-    fb/tf operands ride along only to reuse unpack_tick_inputs — the
-    decide algebra reads packet fields, XLA drops the rest."""
-    from livekit_server_tpu.ops import paged_kernel
+def _build_live_step(audio_params, bwe_params, red_enabled, interpret):
+    """The live-extent tick as one program (`paged.paged_plane_tick_fused`
+    between the packed wire's unpack and pack): state donated, table and
+    live-row indices read-only. Named `tick`, as the stock and the dead
+    steps are: the XLA module is `jit_tick` whichever of them serves."""
 
-    def decide(sel, is_svc, is_video, subscribed, sub_muted,
-               published, pub_muted, pkt, fb, tf, tick_ms, roll, live_rows):
-        inp = plane.unpack_tick_inputs(pkt, fb, tf, tick_ms, roll)
-        base = subscribed & ~sub_muted & (published & ~pub_muted)[:, :, None]
-        return paged_kernel.decide_pages(
-            sel, is_svc, is_video, base, inp, live_rows,
-            wire_overhead=pacer.WIRE_OVERHEAD_BYTES,
-            use_pallas=None, interpret=interpret,
-        )
-
-    return jax.jit(decide)
-
-
-@functools.lru_cache(maxsize=None)
-def _build_live_rest(audio_params, bwe_params, red_enabled=True):
-    """Phases 1–2 + scatter of the live-extent tick, consuming the
-    LiveDecide produced by _build_live_decide. State donated, table and
-    live-row indices read-only."""
-
-    def rest(state, table, live_rows, live_inv, dec, pkt, fb, tf,
-             tick_ms, roll_quality):
+    def tick(state, table, live_rows, live_inv, pkt, fb, tf, tick_ms,
+             roll_quality):
         inp = plane.unpack_tick_inputs(pkt, fb, tf, tick_ms, roll_quality)
-        state, out = paged.paged_plane_tick_live(
-            state, inp, table, live_rows, live_inv, dec,
-            audio_params, bwe_params, red_enabled,
+        state, out = paged.paged_plane_tick_fused(
+            state, inp, table, live_rows, live_inv,
+            audio_params, bwe_params, red_enabled=red_enabled,
+            use_pallas=None, interpret=interpret,
         )
         return state, plane.pack_tick_outputs(out)
 
-    return jax.jit(rest, donate_argnums=(0,))
+    return jax.jit(tick, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -209,8 +193,6 @@ class PagedPlaneRuntime(PlaneRuntime):
         self._pk_enabled = paged_kernel in ("on", "interpret") or (
             paged_kernel == "auto" and jax.default_backend() == "tpu"
         )
-        self._kernel_s_scratch = 0.0
-        self._kernel_steps_scratch = 0
         self.pager = RoomPager(
             dims.rooms, dims.tracks, dims.subs,
             tpage=dims.tpage, spage=dims.spage, pool_pages=dims.pool_pages,
@@ -267,6 +249,10 @@ class PagedPlaneRuntime(PlaneRuntime):
             # bucket per tick — the "work ∝ live pages" probe the bench
             # and tier-1 assert against.
             "paged_kernel_ticks": 0, "paged_kernel_steps": 0,
+            # The layout, for whoever reads /debug/rooms `plane` and has
+            # the logical dims only (constants, not counters).
+            "pager_tpage": dims.tpage, "pager_spage": dims.spage,
+            "pager_pool_pages": dims.pool_pages,
         })
 
     # -- seam hooks -------------------------------------------------------
@@ -299,9 +285,8 @@ class PagedPlaneRuntime(PlaneRuntime):
 
         self._step = step
         if self._pk_enabled:
-            self._live_decide = _build_live_decide(self._pk_interpret)
-            self._live_rest = _build_live_rest(
-                self._ap, self._bp, self.red_enabled
+            self._live_tick = _build_live_step(
+                self._ap, self._bp, self.red_enabled, self._pk_interpret
             )
             self._dead_step = _build_dead_step(
                 self._ap, self._bp, self.red_enabled, self.pdims.max_tpages
@@ -309,35 +294,13 @@ class PagedPlaneRuntime(PlaneRuntime):
             self._step = self._live_step
 
     def _live_step(self, state, *packed):
-        """Live-extent device step: phase-0 kernel dispatch timed into
-        `_kernel_s_scratch` (the worker thread copies it onto the
-        StagedTick in `_device_step` — same thread, no race), then the
-        rest of the tick. Live rows read at call time: `_sync_pages` at
-        the preceding upload edge pinned them with the device table."""
-        pkt, fb, tf, tick_ms, roll = packed
-        lr, li = self._live_rows, self._live_inv
+        """Live-extent device step: one dispatch. Live rows read at call
+        time: `_sync_pages` at the preceding upload edge pinned them with
+        the device table."""
+        lr = self._live_rows
         if lr.shape[0] == 0:
-            self._kernel_s_scratch = 0.0
-            self._kernel_steps_scratch = 0
-            return self._dead_step(state, pkt, fb, tf, tick_ms, roll)
-        t0 = time.perf_counter()
-        dec = self._live_decide(
-            state.sel, state.meta.is_svc, state.meta.is_video,
-            state.ctrl.subscribed, state.ctrl.sub_muted,
-            state.meta.published, state.meta.pub_muted,
-            pkt, fb, tf, tick_ms, roll, lr,
-        )
-        rest = self._live_rest(
-            state, self.table, lr, li, dec, pkt, fb, tf, tick_ms, roll
-        )
-        # The span probe blocks AFTER phase 1 is dispatched: the device
-        # queue already holds the rest of the tick, so the wait overlaps
-        # useful work instead of opening a dispatch bubble. The block
-        # itself is the declared kernel-span measurement seam.
-        jax.block_until_ready(dec)  # graftcheck: disable=GC12
-        self._kernel_s_scratch = time.perf_counter() - t0
-        self._kernel_steps_scratch = int(lr.shape[0])
-        return rest
+            return self._dead_step(state, *packed)
+        return self._live_tick(state, self.table, lr, self._live_inv, *packed)
 
     def _pack_inputs(self, inp: plane.TickInputs) -> tuple:
         pkt, fb, tf, tick_ms, roll = plane.pack_tick_inputs(inp)
@@ -586,27 +549,24 @@ class PagedPlaneRuntime(PlaneRuntime):
                 np.zeros((1, P, pool.tracks), np.float32),
                 np.int32(self.tick_ms), np.int32(0),
             )
-            keep = (self._live_rows, self._live_inv, self._kernel_s_scratch,
-                    self._kernel_steps_scratch)
+            keep = (self._live_rows, self._live_inv)
             self._live_inv = np.zeros(P, np.int32)
             for n in self._live_buckets:
                 self._live_rows = np.zeros(n, np.int32)
                 scratch = jax.tree.map(jnp.copy, self.state)
                 jax.block_until_ready(self._live_step(scratch, *packed))
-            (self._live_rows, self._live_inv, self._kernel_s_scratch,
-             self._kernel_steps_scratch) = keep
+            self._live_rows, self._live_inv = keep
 
-    # -- kernel span accounting --------------------------------------------
+    # -- kernel grid accounting --------------------------------------------
 
     def _device_step(self, st):
-        """Stamp the kernel span/grid-steps scratches (written by
-        `_live_step` on this same worker thread) onto the StagedTick
-        before it crosses back to the event loop."""
-        out = super()._device_step(st)
-        if out is not None and self._pk_enabled:
-            st.kernel_s = self._kernel_s_scratch
-            st.kernel_steps = self._kernel_steps_scratch
-        return out
+        """Stamp what this step's grid runs over onto the StagedTick
+        before it crosses back to the event loop: the live rows are
+        pinned while state_lock is held, which this call's caller does;
+        by `_complete` a later upload may have moved them."""
+        if self._pk_enabled:
+            st.live_pages = self._live_n
+        return super()._device_step(st)
 
     def _tick_rec_extras(self, st) -> dict:
         """recent_ticks extras + the per-tick stats fold (runs exactly
@@ -614,11 +574,15 @@ class PagedPlaneRuntime(PlaneRuntime):
         if not self._pk_enabled:
             return {}
         self.stats["paged_kernel_ticks"] += 1
-        self.stats["paged_kernel_steps"] += st.kernel_steps
+        if st.live_pages:
+            # the grid is the live pages padded to their bucket
+            self.stats["paged_kernel_steps"] += _row_bucket(
+                st.live_pages, self._live_buckets
+            )
         return {
-            "paged_kernel_ms": round(st.kernel_s * 1000.0, 3),
+            "live_pages": st.live_pages,
             "page_live_fraction": round(
-                self._live_n / self.pdims.pool_pages, 4
+                st.live_pages / self.pdims.pool_pages, 4
             ),
         }
 

@@ -338,11 +338,9 @@ class StagedTick:
     packed: tuple | None = None
     stage_s: float = 0.0
     device_s: float = 0.0
-    # Paged-kernel slice of device_s (phase-0 decide dispatch when the
-    # live-extent path ran — runtime/paged_runtime.py) and the grid
-    # steps it scheduled (== padded live-page bucket). 0 on the stock tick.
-    kernel_s: float = 0.0
-    kernel_steps: int = 0
+    # The mapped pages the live-extent tick's grid ran over
+    # (runtime/paged_runtime.py); 0 on the stock tick.
+    live_pages: int = 0
     edge: float = 0.0      # scheduled dispatch edge (perf_counter)
     deadline: float = 0.0  # owning-tick egress deadline; 0 = unaccounted
     depth: int = 0         # pipeline depth this tick ran at
@@ -781,7 +779,7 @@ class PlaneRuntime:
     def _tick_rec_extras(self, st: StagedTick) -> dict:
         """Subclass hook: extra fields for this tick's `recent_ticks`
         record (event loop, after the device step committed). The paged
-        runtime adds the kernel span and live-page fraction here."""
+        runtime adds the live pages and their fraction here."""
         return {}
 
     def _device_step(self, st: StagedTick):
@@ -1029,8 +1027,8 @@ class PlaneRuntime:
                 st.idx, st.edge, st.stage_t0, st.stage_s, st.retier_s,
                 st.upload_t0, st.upload_s, st.device_t0, st.device_s,
                 c0, fanout_s, send_s, st.edge_over_us, st.depth, late,
-                kernel_s=st.kernel_s, sleep_t0=st.sleep_t0,
-                sleep_s=st.sleep_s, lock_t0=st.lock_t0, lock_s=st.lock_s,
+                sleep_t0=st.sleep_t0, sleep_s=st.sleep_s,
+                lock_t0=st.lock_t0, lock_s=st.lock_s,
                 dispatch_s=st.dispatch_s,
                 fetch_s=st.fetch_s, mirror_s=st.mirror_s,
                 audit_s=st.audit_s, handoff_s=st.handoff_s,

@@ -223,9 +223,6 @@ class TickTraceRing:
         self.upload_dur = np.zeros(cap, np.float64)
         self.device_t0 = np.zeros(cap, np.float64)
         self.device_dur = np.zeros(cap, np.float64)
-        # Paged-kernel slice of the device span (phase-0 decide dispatch,
-        # runtime/paged_runtime.py); 0 when the stock tick ran.
-        self.kernel_dur = np.zeros(cap, np.float64)
         self.fanout_t0 = np.zeros(cap, np.float64)
         self.fanout_dur = np.zeros(cap, np.float64)
         self.send_dur = np.zeros(cap, np.float64)
@@ -256,9 +253,8 @@ class TickTraceRing:
                     upload_s: float, device_t0: float, device_s: float,
                     fanout_t0: float, fanout_s: float, send_s: float,
                     wake_over_us: float, depth: int, late: bool,
-                    kernel_s: float = 0.0, sleep_t0: float = 0.0,
-                    sleep_s: float = 0.0, lock_t0: float = 0.0,
-                    lock_s: float = 0.0,
+                    sleep_t0: float = 0.0, sleep_s: float = 0.0,
+                    lock_t0: float = 0.0, lock_s: float = 0.0,
                     dispatch_s: float = 0.0, fetch_s: float = 0.0,
                     mirror_s: float = 0.0, audit_s: float = 0.0,
                     handoff_s: float = 0.0) -> int:
@@ -272,7 +268,6 @@ class TickTraceRing:
         self.upload_dur[slot] = upload_s
         self.device_t0[slot] = device_t0
         self.device_dur[slot] = device_s
-        self.kernel_dur[slot] = kernel_s
         self.fanout_t0[slot] = fanout_t0
         self.fanout_dur[slot] = fanout_s
         self.send_dur[slot] = send_s
@@ -349,7 +344,6 @@ class TickTraceRing:
                 "upload_s": float(self.upload_dur[slot]),
                 "device_t0": float(self.device_t0[slot]),
                 "device_s": float(self.device_dur[slot]),
-                "kernel_s": float(self.kernel_dur[slot]),
                 "fanout_t0": float(self.fanout_t0[slot]),
                 "fanout_s": float(self.fanout_dur[slot]),
                 "send_s": float(self.send_dur[slot]),

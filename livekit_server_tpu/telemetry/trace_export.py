@@ -158,13 +158,6 @@ def to_chrome(records: list[dict[str, Any]], tick_ms: int = 0) -> list[dict]:
                       r.get("mirror_s", 0.0), tick, device_end)
             child("device_audit", TID_DEVICE, t,
                   r.get("audit_s", 0.0), tick, device_end)
-            # Paged-kernel slice: the phase-0 decide dispatch, at the
-            # head of the dispatch it is part of (of the device span in
-            # a record without parts; 0 when the stock tick ran).
-            child("paged_kernel", TID_DEVICE, r["device_t0"],
-                  r.get("kernel_s", 0.0), tick,
-                  r["device_t0"] + dispatch_s if dispatch_s > 0.0
-                  else device_end)
             # Device end → the loop's resumption, inside device end →
             # the (deferred) fan-out.
             f0 = r.get("fanout_t0", 0.0)

@@ -343,8 +343,10 @@ async def test_runtime_parity_interpret_vs_stock():
         ik.encode_snapshot(ik.snapshot())
     assert ik.stats["paged_kernel_ticks"] == 8
     assert ik.stats["paged_kernel_steps"] > 0
-    assert ik.recent_ticks[-1]["paged_kernel_ms"] >= 0.0
-    assert 0.0 < ik.recent_ticks[-1]["page_live_fraction"] < 1.0
+    # rooms "a" (1x2 pages after the grow) and "b" (2x2): 6 mapped pages
+    assert ik.recent_ticks[-1]["live_pages"] == ik.pager.pages_mapped == 6
+    assert ik.recent_ticks[-1]["page_live_fraction"] == round(6 / 16, 4)
+    assert "live_pages" not in off.recent_ticks[-1]
     assert off.stats["paged_kernel_ticks"] == 0
 
 

@@ -256,6 +256,33 @@ def test_paged_fused_tick_lowers(one_chip, as_tpu, which):
     assert _custom_calls(compiled) == 4
 
 
+def test_served_paged_step_lowers(one_chip, as_tpu):
+    """The one program `PagedPlaneRuntime` dispatches a live tick (packed
+    upload → `paged_plane_tick_fused` → packed outputs) at the widths of
+    `config-sample.yaml` with `pager_enabled`, at the live bucket a few
+    small rooms fall in (a sixteenth of the pool): its XLA module is
+    `jit_tick`, and it holds the four kernels of the fused tick."""
+    from livekit_server_tpu.models import paged
+    from livekit_server_tpu.ops import audio, bwe
+    from livekit_server_tpu.runtime import paged_runtime
+
+    pd = _paged_dims("config_default")
+    pooled = pd.pooled()
+    state = _on(jax.eval_shape(lambda: plane.init_state(pooled)), one_chip)
+    table = _on(jax.eval_shape(lambda: paged.init_table(pd)), one_chip)
+    packed = _on(_packed_inputs(pooled), one_chip)
+    # the function under the cache: a fresh jit, as in test_served_step_lowers
+    step = paged_runtime._build_live_step.__wrapped__(
+        audio.AudioLevelParams(), bwe.BWEParams(allow_pause=False), True, False)
+    lowered = step.lower(
+        state, table, _sds(one_chip, (pd.pool_pages // 16,), jnp.int32),
+        _sds(one_chip, (pd.pool_pages,), jnp.int32), *packed)
+    assert lowered.as_text().lstrip().startswith("module @jit_tick")
+    compiled = lowered.compile()
+    assert _custom_calls(compiled) == 4
+    assert "paged_decide" in compiled.as_text()
+
+
 def test_paged_decide_mix_lowers(one_chip):
     """Decide + page-local mix as one grid (20 ms of 48 kHz PCM a track)."""
     from livekit_server_tpu.analysis.devicecheck import _zero_inputs

@@ -431,13 +431,15 @@ def test_export_of_late_ticks_still_nests():
             sleep_t0=sleep_t0, sleep_s=lock_t0 - sleep_t0, lock_t0=lock_t0,
             lock_s=lock_s, dispatch_s=0.0005,
             fetch_s=0.0012, mirror_s=0.0001, audit_s=0.0001,
-            handoff_s=0.0003, kernel_s=0.0002,
+            handoff_s=0.0003,
         )
         now = device_t0 + 0.0023                        # resumed after the hand-off
     events = trace_export.to_chrome(ring.snapshot(), tick_ms=5)
     assert trace_export.validate(events) == []
     names = {e["name"] for e in events}
-    assert {"device_mirror", "device_audit", "paged_kernel", "loop_handoff"} <= names
+    assert {"device_dispatch", "device_fetch", "device_mirror", "device_audit",
+            "loop_handoff"} <= names
+    assert "paged_kernel" not in names      # the host-clock kernel lane is gone
     held = next(e for e in events if e["name"] == "dispatch_delay"
                 and e["args"]["tick"] == 2)
     # tick 2's edge lay inside tick 1's wait: drawn from where that ended,
