@@ -324,6 +324,7 @@ async def wire_bench(
     # Flight-recorder attribution: sampled arrival→wire stage split
     # (same wiring as service/server.py start()).
     udp.wire_stages = runtime.wire_stages
+    runtime.attach_rx(udp.rx_schedule)   # reads keep off a tick's chain
     if runtime.express is not None:
         # Two-tier latency plane: eligible rooms forward on arrival.
         udp.attach_express(runtime.express)

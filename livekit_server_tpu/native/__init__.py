@@ -501,7 +501,7 @@ class NativeEgress:
         self.lib.rx_batch.argtypes = [
             ctypes.c_int, ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_int32, ctypes.c_int32,
+            ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p,
         ]
         self.lib.send_raw.restype = ctypes.c_int64
         self.lib.send_raw.argtypes = [
@@ -556,15 +556,23 @@ class NativeEgress:
             raise OSError("egress seal self-test failed")
 
     def rx_batch(self, fd: int, scratch, offsets, lengths, ips, ports,
-                 max_dgram: int = 2048) -> int:
+                 max_dgram: int = 2048, stamps_us=None) -> int:
         """Drain a non-blocking UDP socket with recvmmsg into caller-owned
         arrays; returns datagrams received (the batch ingress twin of
-        send — one native call per event-loop wake)."""
+        send — one native call per read). `stamps_us` (int64, as long as
+        `offsets`) takes each datagram's kernel arrival time in µs of
+        CLOCK_REALTIME where the socket has SO_TIMESTAMP set, 0 where the
+        kernel sent none."""
+        if stamps_us is not None and (
+            stamps_us.dtype != np.int64 or len(stamps_us) < len(offsets)
+        ):
+            raise ValueError("stamps_us must be int64, one slot a datagram")
         return int(self.lib.rx_batch(
             int(fd), scratch.ctypes.data, scratch.nbytes,
             offsets.ctypes.data, lengths.ctypes.data,
             ips.ctypes.data, ports.ctypes.data,
             len(offsets), int(max_dgram),
+            None if stamps_us is None else stamps_us.ctypes.data,
         ))
 
     def open_batch(self, blob, offsets, lengths, key_idx, keys,

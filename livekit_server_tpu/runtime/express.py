@@ -169,6 +169,12 @@ class ExpressLane:
         None = automatic (subscriber-count eligibility)."""
         self.pin[room] = 0 if pin is None else (1 if pin else -1)
 
+    @property
+    def holds_rooms(self) -> bool:
+        """Some room forwards on arrival this window (the receive path
+        then reads on every wake: RxSchedule)."""
+        return self._active_any
+
     def wants_mirror(self) -> bool:
         return bool(self._active_any or self.desired.any())
 

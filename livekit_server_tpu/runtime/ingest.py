@@ -63,7 +63,7 @@ class PayloadSlab:
     dd_off: np.ndarray | None = None   # int64
     dd_len: np.ndarray | None = None   # int32
     dd_ver: np.ndarray | None = None   # int32 — structure version stamp
-    # Arrival stamps (time.perf_counter seconds at batch-receive return;
+    # Arrival stamps (time.perf_counter seconds, the kernel's arrival time;
     # 0 = not stamped): the rx half of the wall-clock packet-in→wire-out
     # forward-latency probe (udp.py observes at sendmmsg-return).
     t_arr: np.ndarray | None = None    # float64
@@ -461,13 +461,16 @@ class IngestBuffer:
         layer_sync, begin_pic, marker, pid, tl0, keyidx, size, frame_ms,
         audio_level, arrival_rtp, pay_start, pay_length, blob,
         dd_start=None, dd_length=None, dd_version=None, end_frame=None,
-        t_rx: float = 0.0,
+        t_rx=0.0,
     ) -> int:
         """Vectorized push: stage a whole receive batch with numpy group
         math instead of one Python call per packet (the batch half of the
         native-parse → tensor-staging path this module documents). All
         args are equal-length arrays; payload bytes are sliced out of
-        `blob` by (pay_start, pay_length). Returns packets staged."""
+        `blob` by (pay_start, pay_length); `t_rx`, the packets' arrival
+        (their `t_arr`), is an array like them or one stamp for all.
+        Returns packets staged."""
+        t_rx = np.broadcast_to(t_rx, (len(room),))
         self.last_put = None
         n = len(room)
         if n == 0:
@@ -497,7 +500,7 @@ class IngestBuffer:
                         arrival_rtp=int(arrival_rtp[i]),
                         ts_aligned=bool(ts_aligned[i]),
                     ),
-                    t_rx,
+                    float(t_rx[i]),
                 )
             return staged
         if dd_start is None:
@@ -544,6 +547,7 @@ class IngestBuffer:
                         pay_start, pay_length, dd_start, dd_length,
                         dd_version, end_frame)
                 )
+                t_rx = t_rx[keep0]
                 n = len(room)
                 if n == 0:
                     return 0
@@ -585,6 +589,7 @@ class IngestBuffer:
                         pay_start, pay_length, dd_start, dd_length,
                         dd_version, end_frame)
                 )
+                t_rx = t_rx[keep1]
                 n = len(room)
                 if n == 0:
                     return 0
@@ -608,6 +613,7 @@ class IngestBuffer:
                     tl0, keyidx, size, frame_ms, audio_level, arrival_rtp,
                     pay_start, pay_length, dd_start, dd_length, dd_version)
             )
+            t_rx = t_rx[keep]
         # else: the common no-overflow tick — no masked copies at all.
         r_, t_, k_ = room, track, k
         # One flat index shared by all the field scatters below — the

@@ -478,6 +478,10 @@ class PlaneRuntime:
         self._last_deficient = np.zeros((R, S), bool)
         self._task: asyncio.Task | None = None
         self._complete_task: asyncio.Task | None = None
+        # The receive path's read schedule (runtime/udp.py RxSchedule;
+        # `attach_rx`): `_run` tells it when a tick's chain begins and
+        # ends, and it reads the socket around them.
+        self.rx = None
         # Bumped by PlaneSupervisor on restart: a device step that started
         # before the bump must not commit its result over restored state
         # (the stale step ran — or is still wedged — on the abandoned
@@ -725,6 +729,18 @@ class PlaneRuntime:
 
     def on_tick(self, cb: Callable[[TickResult], Awaitable[None] | None]) -> None:
         self._on_tick.append(cb)
+
+    def attach_rx(self, schedule) -> None:
+        """Bind the transport's RxSchedule: it reads `serving` and
+        `express` off this plane, `_run` signals it."""
+        self.rx = schedule
+        schedule.plane = self
+
+    @property
+    def serving(self) -> bool:
+        """The serving loop (`_run`) is running, so ticks come at their
+        edges by themselves (not `step_once`-driven)."""
+        return self._task is not None and not self._task.done()
 
     # (The r4 egress-cap auto-widening machinery is gone: the bit-packed
     # mask egress has no capacity to overflow — every send is one bit.)
@@ -1346,9 +1362,11 @@ class PlaneRuntime:
         edge, then a yield loop for the tail. An epoll-backed sleep
         overshoots by the event-loop lag (hundreds of µs under rx load)
         — at a 5 ms tick that alone costs 5-10% of the cadence. The
-        sleep(0) tail keeps rx/feedback callbacks running while landing
-        the dispatch within ~50 µs of the edge; the spin is bounded by
-        the calibrated margin and only burns the window's idle slack.
+        sleep(0) tail lands the dispatch within ~50 µs of the edge and
+        lets the loop's other handlers run (the receive path's reads
+        among them, as far as their own pacing allows: RxSchedule); the
+        spin is bounded by the calibrated margin and only burns the
+        window's idle slack.
         The wake overshoot is recorded (edge_overshoot_us per tick in
         recent_ticks) and a coarse sleep that blows THROUGH the edge
         widens the margin for the next windows (EWMA, capped)."""
@@ -1424,6 +1442,18 @@ class PlaneRuntime:
         this edge is used as it is. 0 → 1: the loop pre-stages after this
         dispatch and holds this tick's fan-out, nothing else.
 
+        Who reads the socket when: nobody from the wake at the edge
+        (`woke`) until the loop goes to sleep again (`sleep_t0`), the
+        window `chain_s` measures. `self.rx` (udp.RxSchedule) takes the
+        reader off the selector at `chain_begin`, after reading what an
+        unfinished pause of its own had left readable, so that a packet
+        in the socket before the edge is in this edge's tick; at
+        `chain_end` it drains what the chain let gather (that read
+        counts in `chain_s`, as everything before the sleep does) and
+        paces its reads through the sleep by their own cost. So no
+        `rx` handler runs beside the awaited device call, between the
+        send callbacks or beside the pre-staging at depth 1.
+
         The completion queue is bounded at 1: if host egress can't keep
         up, the loop degrades to sequential (counted in pipeline_stalls)
         instead of queueing stale sends, and a stalled device future
@@ -1452,6 +1482,8 @@ class PlaneRuntime:
                     # _schedule_probe reads cannot change — leaving the
                     # post-wake path dispatch-only.
                     self._schedule_probe(staged)
+                if self.rx is not None:
+                    self.rx.chain_end()
                 sleep_t0 = time.perf_counter()
                 if woke and not depth:
                     # What the last tick asked of its window; a sample
@@ -1476,6 +1508,8 @@ class PlaneRuntime:
                     await pending_task
                     pending_task = self._complete_task = None
                 woke = time.perf_counter()
+                if self.rx is not None:
+                    self.rx.chain_begin()
                 want, retry = self.choose_depth(
                     chain_s, woke - next_at, sleep_s, period, depth, stay, retry)
                 if want != depth:
@@ -1522,8 +1556,9 @@ class PlaneRuntime:
                         # lock we hold here guards the in-flight donated
                         # state, not this.
                         staged = self._stage_host()
-                    # Fan-out N-1 (the task above) and any arriving-packet
-                    # handlers run on the event loop during this await.
+                    # Fan-out N-1 (the task above) runs on the event loop
+                    # during this await; the receive path does not (its
+                    # reader is off for the chain).
                     out = await fut
                     cur.handoff_s = trace_mod.between(
                         cur.device_t0 + cur.device_s, time.perf_counter())
@@ -1561,6 +1596,9 @@ class PlaneRuntime:
             if pending is not None:
                 await self._complete(pending[0], pending[1])
             raise
+        finally:
+            if self.rx is not None:
+                self.rx.chain_end()    # the reader goes back on the selector
 
     async def stop(self) -> None:
         if self._task is not None:

@@ -90,7 +90,7 @@ class ForwardLatencyProbe:
     The reference's implicit forwarding-latency spec is per-packet and
     measured on the wire (a packet enters `buffer.Buffer.Write` and leaves
     at the pacer's socket write). Here every media datagram is stamped
-    when its receive batch returns from recvmmsg (rx_batch →
+    by the kernel as it arrives (SCM_TIMESTAMP, rx_batch →
     IngestBuffer.t_arr) and observed when the native egress send returns —
     so the recorded latency INCLUDES tick-queueing wait, staging, the
     device step, and the kernel send, with no composed/estimated terms.
@@ -528,8 +528,8 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
         # send_egress_batch routes through the native sharded fan-out
         # (egress_plane_send) instead of the flat n_threads pool.
         self._egress_plane = None
-        # Always-on packet-in→wire-out latency histogram (stamps: rx_batch
-        # return → native egress send return; includes tick-queue wait).
+        # Always-on packet-in→wire-out latency histogram (stamps: kernel
+        # arrival → native egress send return; includes tick-queue wait).
         self.fwd_latency = ForwardLatencyProbe()
         # Express-lane twin: arrival-driven sends skip the tick queue, so
         # their latency distribution answers a different question (decide+
@@ -541,11 +541,11 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
         # egress plane. None = no per-stage attribution.
         self.wire_stages = None
         # Span totals (runtime/trace.py Spans), attached beside it: the
-        # receive path adds one `rx` record a wake-up — a call of
+        # receive path adds one `rx` record a read — a call of
         # feed_batch, or of _flush_rx where the asyncio endpoint carries
-        # the socket — with the datagrams it brought and the time from
-        # the batch's arrival stamp to its last packet staged. No span
-        # object here: this runs thousands of times a second.
+        # the socket — with the datagrams it brought and the call's own
+        # wall, entry to last packet staged. No span object here: this
+        # runs hundreds of times a second.
         self.spans = None
         # Express lane (runtime/express.py): attached by the room manager
         # when plane.express_max_subs > 0; rx_batch hands each receive
@@ -594,7 +594,11 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
             "rtcp_rx": 0, "rtcp_bad": 0, "nacks_rx": 0, "nacks_tx": 0,
             "plis_rx": 0, "plis_tx": 0, "rtx_tx": 0,
             "bad_frame": 0, "plaintext_drop": 0, "session_mismatch": 0,
+            "rx_stamp_fallback": 0,
         }
+        # When the native reader's socket is read (RxSchedule; None where
+        # the asyncio endpoint carries the socket): start_udp_transport.
+        self.rx_schedule = None
 
     # -- control-plane API ------------------------------------------------
     def _new_ssrc(self) -> int:
@@ -974,14 +978,36 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
             self._tuple_code[t] = code
         return t
 
-    def feed_batch(self, blob, offs, lens, ips, ports, n,
-                   t_rx: float = 0.0) -> None:
+    def arrival_times(self, stamps_us: np.ndarray) -> np.ndarray:
+        """The kernel's arrival stamps of one read (rx_batch: µs of
+        CLOCK_REALTIME, 0 where none came) on perf_counter's scale, by
+        one offset taken now: each datagram's age, from now. One without
+        a stamp takes now, the read's time, and is counted; so does what
+        a stepped wall clock would put in the future."""
+        now = time.perf_counter()
+        age = (time.time_ns() // 1000 - stamps_us) * 1e-6
+        unstamped = stamps_us == 0
+        if unstamped.any():
+            self.stats["rx_stamp_fallback"] += int(unstamped.sum())
+            age[unstamped] = 0.0
+        return now - np.maximum(age, 0.0)
+
+    def feed_batch(self, blob, offs, lens, ips, ports, n, t_rx=0.0) -> None:
         """Batch ingress from the native recvmmsg reader: sealed frames are
         opened with ONE native AES-GCM batch call (replay windows and the
         client-active latch stay host-side), datagrams are classified
         vectorized (punch / RTCP / RTP media), and all media goes through
         ONE array demux+stage pass (_process_media_arrays) — no per-packet
-        Python objects on the media path."""
+        Python objects on the media path.
+
+        `t_rx` is when the datagrams arrived, on perf_counter's scale:
+        one stamp a datagram (the kernel's, `arrival_times`: a read may
+        come a whole chain after the arrival, RxSchedule), or one for the
+        batch; 0 = now. It becomes the packets' `t_arr`; the `sfu/rx`
+        span is this call's own wall whatever the stamps say."""
+        t0 = time.perf_counter()
+        if not isinstance(t_rx, np.ndarray):
+            t_rx = np.full(n, t_rx or t0)
         self.stats["rx"] += int(n)
         offs = offs[:n]
         lens = lens[:n]
@@ -995,8 +1021,6 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
         )
         addr_code = (ips.astype(np.int64) << 16) | ports.astype(np.int64)
         now_ms = asyncio.get_event_loop().time() * 1000.0
-        if t_rx == 0.0:
-            t_rx = time.perf_counter()
 
         if sealed.any():
             si = np.nonzero(sealed)[0]
@@ -1045,7 +1069,7 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
                 self._classify_and_process(
                     out, ooff[gi].astype(np.int32), olen[gi],
                     addr_code[si[gi]], scodes[gi], sessions, kid[gi], now_ms,
-                    t_rx,
+                    t_rx[si[gi]],
                 )
 
         clear = valid & ~sealed
@@ -1065,17 +1089,18 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
                 ci = np.nonzero(clear)[0]
                 self._classify_and_process(
                     blob, offs[ci], lens[ci], addr_code[ci],
-                    np.zeros(len(ci), np.int64), None, None, now_ms, t_rx,
+                    np.zeros(len(ci), np.int64), None, None, now_ms, t_rx[ci],
                     gateway_only=self.require_encryption,
                 )
         if self.spans is not None:
-            self.spans.add(SP_RX, time.perf_counter() - t_rx, int(n))
+            self.spans.add(SP_RX, time.perf_counter() - t0, int(n))
 
     def _classify_and_process(self, blob, offs, lens, addr_code, sess_code,
-                              sessions, kid, now_ms, t_rx: float = 0.0,
+                              sessions, kid, now_ms, t_rx,
                               gateway_only: bool = False) -> None:
         """Split one (possibly decrypted) datagram batch into punch / RTCP
         (cold, per-packet) and RTP media (hot, one vectorized pass).
+        `t_rx` is one arrival stamp a datagram, as `offs` is.
         `gateway_only` (require_encryption + gateway): gateway traffic is
         processed, every other cleartext datagram is dropped."""
         b0 = blob[np.minimum(offs.astype(np.int64), len(blob) - 1)]
@@ -1111,7 +1136,9 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
                          int(addr_code[i]))
                         for i in np.nonzero(gw_media)[0]
                     ]
-                    self._gateway_media(pkts, t_rx)
+                    # (unprotect may drop datagrams: the interop lane
+                    # carries one stamp, its earliest arrival)
+                    self._gateway_media(pkts, float(t_rx[gw_media].min()))
             media = media & ~gw_ctl & ~gw_media
             is_rtcp = is_rtcp & ~gw_media
         if gateway_only:
@@ -1139,7 +1166,7 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
         if len(mi):
             self._process_media_arrays(
                 blob, offs[mi], lens[mi], addr_code[mi], sess_code[mi], now_ms,
-                t_rx,
+                t_rx[mi],
             )
 
     def datagram_received(self, data: bytes, addr) -> None:
@@ -1539,14 +1566,16 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
 
     def _process_media_arrays(
         self, blob, offsets, lengths, addr_code, sess_code, now_ms,
-        t_rx: float = 0.0,
+        t_rx=0.0,
     ) -> None:
         """One native parse + one vectorized ingest stage per receive
         batch. Per-PACKET Python is limited to rare paths (RED decap, DD
         descriptors, loss-gap fallback); binding resolution is per UNIQUE
         SSRC; everything else is numpy group math. `blob` is one
         contiguous uint8 array; `addr_code`/`sess_code` are the integer
-        identities from _addr_code_of / key_id + 1 (0 = plaintext)."""
+        identities from _addr_code_of / key_id + 1 (0 = plaintext);
+        `t_rx` the arrival on perf_counter's scale, one stamp a datagram
+        or one for all (0 = now)."""
         if not isinstance(blob, np.ndarray):
             blob = np.frombuffer(blob, np.uint8)
         parsed = rtp.parse_batch(
@@ -1813,7 +1842,8 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
                 dd_start=dd_start,
                 dd_length=dd_length,
                 dd_version=dd_ver,
-                t_rx=t_rx if t_rx else time.perf_counter(),
+                t_rx=(t_rx[idx] if isinstance(t_rx, np.ndarray)
+                      else t_rx or time.perf_counter()),
             )
             # (Express lane hand-off happens inside push_batch via
             # ingest.on_put — active rooms' arrivals are decided/munged/
@@ -2793,14 +2823,139 @@ class UDPMediaTransport(asyncio.DatagramProtocol):
             self._send_srs(now_ms)
 
 
+# A read's own cost, times this, passes before the next read. A share of
+# the algorithm, not configuration; settled on the chip (PERF.md, PR 31):
+# at 4 the pause covers the edge in half of a wide room's ticks and the
+# edge read costs its p50 0.75 ms, for nothing gained in rooms of 4.
+RX_PACE = 2.0
+SO_TIMESTAMP = 29   # Linux <asm-generic/socket.h>; `socket` does not name it
+
+
+class RxSchedule:
+    """When the native reader's socket is read: on the serving loop's
+    schedule where one runs, on every wake of the socket where none does.
+
+    A read is one `rx_batch` that drains the socket whole and one
+    `feed_batch` of what it brought, and costs ~0.75 ms whatever it
+    brings (PERF.md 7.8): read on every wake, a busy socket takes
+    whatever time the loop leaves, in calls of a few datagrams, and
+    beside a tick's awaited device call each takes the GIL from the
+    worker thread. So while `PlaneRuntime._run` serves, three rules:
+
+      1. Never beside a tick's chain. From `chain_begin` (the loop's
+         wake at the edge) to `chain_end` (it goes to sleep again) the
+         reader is off the selector (level-triggered, it would spin) and
+         nothing reads; the kernel holds what arrives, stamped.
+      2. Whole drains, paced by their own cost. `chain_end` reads at once
+         what the chain let gather; after any read that brought
+         something the reader stays off until RX_PACE times what that
+         read cost has passed, so `rx` is at most 1 / (1 + RX_PACE) of
+         the loop's free time however the datagrams are spread.
+      3. The edge leaves nothing readable behind. A pause still pending
+         at `chain_begin` is cut short by a read before the tick is
+         staged: a packet in the socket before the edge is in that
+         edge's tick.
+
+    With no serving loop (`plane` None or not `serving`: step_once-driven
+    tests, the gateway's) and while the express lane holds a room
+    (`ingest.on_put` decides on arrival there) every wake reads, as the
+    per-wake reader did. Both are read off the plane, not set.
+
+    Counters, in the transport's `stats` (/debug/rooms `udp`): `rx_reads`
+    (reads that brought datagrams), and of those `rx_held_chain` (what a
+    chain had held), `rx_held_pause` (what a pause had held),
+    `rx_edge_reads` (rule 3). With the reader off there is no wake to
+    count: these count the reads that found something held."""
+
+    def __init__(self, loop, fd: int, read, stats: dict,
+                 clock=time.perf_counter):
+        self.loop, self.fd, self.clock = loop, fd, clock
+        self.read = read            # () -> datagrams read and fed
+        self.stats = stats
+        self.plane = None           # PlaneRuntime.attach_rx
+        self._on = self._in_chain = self._closed = False
+        self._pause = None          # TimerHandle of rule 2's wait
+        for k in ("rx_reads", "rx_held_chain", "rx_held_pause", "rx_edge_reads"):
+            stats.setdefault(k, 0)
+        self._reader(True)
+
+    def _scheduled(self) -> bool:
+        p = self.plane
+        return p is not None and p.serving and not (
+            p.express is not None and p.express.holds_rooms)
+
+    def _reader(self, on: bool) -> None:
+        if on != self._on and not self._closed:
+            if on:
+                self.loop.add_reader(self.fd, self._read)
+            else:
+                self.loop.remove_reader(self.fd)
+            self._on = on
+
+    def _read(self, held: str | None = None) -> None:
+        """One whole drain, then rule 2: off for RX_PACE times its cost
+        if it brought anything, else back on the selector."""
+        if self._closed:
+            return
+        t0 = self.clock()
+        try:
+            n = self.read()
+        except Exception as e:
+            # As the selector would for a reader callback: report it and
+            # go on. A read runs inside `_run`'s own calls now, and the
+            # serving loop must not die of a datagram.
+            self.loop.call_exception_handler(
+                {"message": "udp read failed", "exception": e})
+            n = 0
+        if n > 0:
+            self.stats["rx_reads"] += 1
+            if held is not None:
+                self.stats[held] += 1
+        if n > 0 and not self._in_chain and self._scheduled():
+            self._reader(False)
+            self._pause = self.loop.call_later(
+                RX_PACE * (self.clock() - t0), self._resume)
+        else:
+            self._reader(not self._in_chain)
+
+    def _resume(self) -> None:
+        self._pause = None
+        self._read("rx_held_pause")
+
+    def chain_begin(self) -> None:
+        """The serving loop woke at its edge: rule 3, then rule 1."""
+        if not self._scheduled():
+            return
+        self._in_chain = True
+        if self._pause is not None:
+            self._pause.cancel()
+            self._pause = None
+            self._read("rx_edge_reads")
+        self._reader(False)
+
+    def chain_end(self) -> None:
+        """The serving loop is about to sleep (or is ending): the read
+        that takes up what the chain held, at once."""
+        if self._in_chain:
+            self._in_chain = False
+            self._read("rx_held_chain")
+
+    def close(self) -> None:
+        self._reader(False)
+        if self._pause is not None:
+            self._pause.cancel()
+            self._pause = None
+        self._closed = True
+
+
 class _RawDatagramTransport:
     """Minimal DatagramTransport stand-in over a raw non-blocking socket
-    (the native batch-receive path owns reads via loop.add_reader)."""
+    (the native batch-receive path owns reads: `rx`, its RxSchedule)."""
 
-    def __init__(self, sock, loop):
+    def __init__(self, sock):
         self._sock = sock
-        self._loop = loop
         self._closed = False
+        self.rx: RxSchedule | None = None
 
     def sendto(self, data, addr) -> None:
         try:
@@ -2813,7 +2968,8 @@ class _RawDatagramTransport:
             return
         self._closed = True
         try:
-            self._loop.remove_reader(self._sock.fileno())
+            if self.rx is not None:
+                self.rx.close()
         except (OSError, ValueError):
             pass
         self._sock.close()
@@ -2840,15 +2996,23 @@ async def start_udp_transport(
     protocol = UDPMediaTransport(ingest, crypto, require_encryption, nack_resolver)
     is_v4 = ":" not in host  # rx_batch parses sockaddr_in (IPv4) only
     if native_egress is not None and is_v4:
-        # Native batch-receive path: raw socket + recvmmsg per event-loop
-        # wake + one batch AEAD open, instead of one asyncio protocol
-        # callback (and one Python AES call) per datagram.
+        # Native batch-receive path: raw socket + one recvmmsg drain and
+        # one batch AEAD open a read, instead of one asyncio protocol
+        # callback (and one Python AES call) per datagram. When it is
+        # read is `protocol.rx_schedule`'s to say.
         sock = _socket.socket(_socket.AF_INET, _socket.SOCK_DGRAM)
         sock.setsockopt(_socket.SOL_SOCKET, _socket.SO_RCVBUF, 4 << 20)
         sock.setsockopt(_socket.SOL_SOCKET, _socket.SO_SNDBUF, 4 << 20)
+        try:
+            # The kernel stamps each datagram's arrival (SCM_TIMESTAMP):
+            # a read may come a whole chain after it. gVisor has this
+            # option and not SO_TIMESTAMPNS.
+            sock.setsockopt(_socket.SOL_SOCKET, SO_TIMESTAMP, 1)
+        except OSError:
+            pass  # every datagram then takes its read's time, counted
         sock.bind((host, port))
         sock.setblocking(False)
-        tr = _RawDatagramTransport(sock, loop)
+        tr = _RawDatagramTransport(sock)
         protocol.connection_made(tr)
         MAXN, MAXD = 1024, 2048
         scratch = np.zeros(MAXN * MAXD, np.uint8)
@@ -2856,21 +3020,23 @@ async def start_udp_transport(
         lens = np.zeros(MAXN, np.int32)
         ips = np.zeros(MAXN, np.uint32)
         ports_a = np.zeros(MAXN, np.uint16)
+        stamps = np.zeros(MAXN, np.int64)
         fd = sock.fileno()
 
-        def on_readable():
-            # ONE batch per wake: the reader is level-triggered, so a
-            # still-full socket re-fires immediately — but other event-loop
-            # work (ticks, flushes, timers) gets to run in between instead
-            # of being starved by a sustained flood.
-            nn = native_egress.rx_batch(fd, scratch, offs, lens, ips, ports_a, MAXD)
-            if nn > 0:
-                protocol.feed_batch(
-                    scratch, offs, lens, ips, ports_a, nn,
-                    t_rx=time.perf_counter(),
-                )
+        def read() -> int:
+            # ONE drain a read (rx_batch loops recvmmsg until the socket
+            # is empty, up to MAXN) and one feed_batch of all it brought.
+            nn = native_egress.rx_batch(
+                fd, scratch, offs, lens, ips, ports_a, MAXD, stamps)
+            if nn <= 0:
+                return 0
+            protocol.feed_batch(
+                scratch, offs, lens, ips, ports_a, nn,
+                t_rx=protocol.arrival_times(stamps[:nn]),
+            )
+            return nn
 
-        loop.add_reader(fd, on_readable)
+        protocol.rx_schedule = tr.rx = RxSchedule(loop, fd, read, protocol.stats)
         return protocol
     transport, _ = await loop.create_datagram_endpoint(
         lambda: protocol, local_addr=(host, port)

@@ -472,6 +472,12 @@ class LivekitServer:
                 },
                 "plane": rm.runtime.stats,
                 "ingest_dropped": rm.runtime.ingest.dropped,
+                # The receive path's reads (udp.RxSchedule), cumulative:
+                # rx_reads and what held them, rx_stamp_fallback.
+                "udp": {
+                    k: v for k, v in (rm.udp.stats if rm.udp else {}).items()
+                    if k == "rx" or k.startswith("rx_")
+                },
                 # Cumulative, for readers by difference over a window:
                 # the host spans' totals and the sampled wire-latency
                 # stages' sums (both empty with trace.enabled false).
@@ -549,6 +555,12 @@ class LivekitServer:
                     self.room_manager.runtime.wire_stages
                 )
                 self.room_manager.udp.spans = self.room_manager.runtime.spans
+                # The serving loop tells the receive path when a tick's
+                # chain begins and ends (udp.RxSchedule reads round them).
+                if self.room_manager.udp.rx_schedule is not None:
+                    self.room_manager.runtime.attach_rx(
+                        self.room_manager.udp.rx_schedule
+                    )
                 # Express lane (plane.express_max_subs > 0): interactive
                 # rooms forward on packet arrival through this transport
                 # instead of the batched tick (runtime/express.py).

@@ -35,6 +35,7 @@
 #include <errno.h>
 #include <netinet/in.h>
 #include <sys/socket.h>
+#include <sys/time.h>
 #include <time.h>
 #include <unistd.h>
 
@@ -811,16 +812,20 @@ extern "C" {
 
 // Batch receive: drain up to max_n datagrams from a non-blocking UDP
 // socket with recvmmsg (the ingress twin of the batch sender — replaces
-// one Python callback per datagram with one native call per wake).
+// one Python callback per datagram with one native call per read).
 // Returns the number received; fills per-datagram offsets/lengths into
-// `buf` (caller-sized) and source ip/port (host byte order).
+// `buf` (caller-sized) and source ip/port (host byte order). With
+// `stamps_us` (may be null) each datagram also gets the kernel's arrival
+// time, CLOCK_REALTIME in microseconds, from the SCM_TIMESTAMP control
+// message of a socket that has SO_TIMESTAMP set; 0 where none came.
 int32_t rx_batch(int fd, uint8_t* buf, int64_t cap, int32_t* offsets,
                  int32_t* lengths, uint32_t* ips, uint16_t* ports,
-                 int32_t max_n, int32_t max_dgram) {
+                 int32_t max_n, int32_t max_dgram, int64_t* stamps_us) {
   constexpr int CHUNK = 64;
   mmsghdr msgs[CHUNK];
   iovec iovs[CHUNK];
   sockaddr_in sas[CHUNK];
+  alignas(cmsghdr) char ctl[CHUNK][CMSG_SPACE(sizeof(timeval))];
   int32_t n = 0;
   int64_t off = 0;
   while (n < max_n && off + (int64_t)CHUNK * max_dgram <= cap) {
@@ -833,10 +838,25 @@ int32_t rx_batch(int fd, uint8_t* buf, int64_t cap, int32_t* offsets,
       msgs[j].msg_hdr.msg_iovlen = 1;
       msgs[j].msg_hdr.msg_name = &sas[j];
       msgs[j].msg_hdr.msg_namelen = sizeof(sockaddr_in);
+      if (stamps_us) {
+        msgs[j].msg_hdr.msg_control = ctl[j];
+        msgs[j].msg_hdr.msg_controllen = sizeof(ctl[j]);
+      }
     }
     int r = recvmmsg(fd, msgs, want, MSG_DONTWAIT, nullptr);
     if (r <= 0) break;
     for (int j = 0; j < r; j++) {
+      if (stamps_us) {
+        stamps_us[n] = 0;
+        for (cmsghdr* c = CMSG_FIRSTHDR(&msgs[j].msg_hdr); c;
+             c = CMSG_NXTHDR(&msgs[j].msg_hdr, c)) {
+          if (c->cmsg_level == SOL_SOCKET && c->cmsg_type == SCM_TIMESTAMP) {
+            timeval tv;
+            std::memcpy(&tv, CMSG_DATA(c), sizeof(tv));
+            stamps_us[n] = (int64_t)tv.tv_sec * 1000000 + tv.tv_usec;
+          }
+        }
+      }
       if (msgs[j].msg_hdr.msg_flags & MSG_TRUNC) {
         // Oversized datagram: delivering the truncated prefix as if
         // complete would feed corrupt payloads downstream — drop it
